@@ -106,21 +106,33 @@ def _pop_backend_flag(argv):
 
 
 def _apply_backend_env():
-    """Honor KEYSTONE_BACKEND/KEYSTONE_CPU_DEVICES programmatically.
+    """Honor KEYSTONE_BACKEND/KEYSTONE_CPU_DEVICES.
 
-    jax.config updates are applied before any backend initializes, which
-    keeps working even in environments where plugin site hooks consume
-    or interfere with JAX_PLATFORMS/XLA_FLAGS env vars (the conftest
-    uses the same pattern for the test mesh)."""
+    ``cpu`` is applied through jax.config before any backend initializes
+    (the conftest uses the same pattern for the test mesh). ``tpu``
+    changes nothing and checks: the run fails unless the device jax
+    finds is a TPU, so a run that asked for the chip never carries on
+    somewhere else."""
     import os
 
-    if os.environ.get("KEYSTONE_BACKEND") == "cpu":
-        import jax
+    backend = os.environ.get("KEYSTONE_BACKEND")
+    if backend is None:
+        return
+    import jax
 
+    if backend == "cpu":
         jax.config.update("jax_platforms", "cpu")
         n = os.environ.get("KEYSTONE_CPU_DEVICES")
         if n:
             jax.config.update("jax_num_cpu_devices", int(n))
+    elif backend == "tpu":
+        found = jax.devices()[0].platform
+        if found != "tpu":
+            raise SystemExit(
+                f"--backend tpu: jax found platform {found!r}, not a TPU")
+    else:
+        raise SystemExit(
+            f"--backend must be tpu or cpu, got {backend!r}")
 
 
 def main(argv=None):

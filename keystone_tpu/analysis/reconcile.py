@@ -644,7 +644,7 @@ def cost_model_drift(trace: Dict[str, Any]) -> Dict[str, Any]:
     by ``cost = cpu_weight·flops + mem_weight·bytes +
     network_weight·collective_bytes``; a run's node spans carry
     ``seconds`` and ``out_bytes``, so the observed seconds-per-byte over
-    the run bounds the effective ``mem_weight`` (HBM + transport) the
+    the run bounds the effective ``mem_weight`` (HBM + transfer) the
     plan actually experienced. When the trace additionally carries the
     static roofline metadata (``keystone.roofline``, PR 12), the
     per-stage FLOP counts join the same spans and imply a

@@ -24,7 +24,7 @@ device-count-multiple and a ragged example count. A host-bucketed
 chunking workload is measured alongside, since the example pipelines'
 device datasets never exercise the ragged-tail path.
 
-Used by ``bench.py --child`` (the ``compile_count`` tier) and
+Used by ``bench.py`` (the ``compile_count`` tier) and
 tests/test_compile.py (the acceptance gate).
 """
 

@@ -160,12 +160,6 @@ def _tsqr_r(X, n_shards: int):
         if n_shards == 1:
             return jnp.linalg.qr(X, mode="r")
 
-        try:
-            from jax import shard_map
-            kw = {"check_vma": False}
-        except ImportError:  # pragma: no cover
-            from jax.experimental.shard_map import shard_map
-            kw = {"check_rep": False}
         from jax.sharding import PartitionSpec as P
 
         mesh = meshlib.current_mesh()
@@ -174,10 +168,10 @@ def _tsqr_r(X, n_shards: int):
             r = jnp.linalg.qr(xs, mode="r")  # (d, d)
             return r[None]
 
-        rs = shard_map(
+        rs = jax.shard_map(
             local_qr, mesh=mesh,
             in_specs=(P(meshlib.DATA_AXIS),), out_specs=P(meshlib.DATA_AXIS),
-            **kw,
+            check_vma=False,
         )(X)  # (n_shards, d, d), sharded; gather is d² per shard — tiny
         stacked = rs.reshape(-1, X.shape[1])
         return jnp.linalg.qr(stacked, mode="r")

@@ -69,8 +69,16 @@ def _bwls_fit(X, Y, mask, lam, mixture_weight, block_size, num_blocks, num_iter)
             C = jnp.einsum("cnb,nc->cb", XW, R1)
             rbar = jnp.sum(Wts * R1, axis=0)  # (k,)
             C = C - xbar_b * rbar[:, None]
+            # LU, not Cholesky (`assume_a="pos"`): inside this program the
+            # batched Cholesky solve came back wrong on every multi-chip
+            # v5e mesh (max |Δ| 0.17 of 0.38 from the one-device answer,
+            # with G and C themselves right to 4e-7) and right on one
+            # chip and on virtual CPU devices; the LU solve was right
+            # everywhere (PERF.md, PR 22). The factorizations are k·B³
+            # against the Gram's k·n·B², so the form of the solve is not
+            # where this solver's time goes.
             Wb_new = jax.vmap(
-                lambda Gc, Cc: jax.scipy.linalg.solve(Gc + eye, Cc, assume_a="pos")
+                lambda Gc, Cc: jnp.linalg.solve(Gc + eye, Cc)
             )(G, C).T  # (B, k)
             R2 = R1 - Xb @ Wb_new
             return (W.at[b_idx].set(Wb_new), R2), None

@@ -706,20 +706,10 @@ class FusedBatchTransformer(Transformer):
         if shards > 1:
             spec = P(meshlib.DATA_AXIS)
             flat_specs = [P()] * treedef.num_leaves
-            try:
-                from jax import shard_map
-
-                fn = shard_map(
-                    per_shard, mesh=mesh, in_specs=(flat_specs, spec, spec),
-                    out_specs=spec, check_vma=False,
-                )
-            except ImportError:  # older jax: experimental API, check_rep kwarg
-                from jax.experimental.shard_map import shard_map
-
-                fn = shard_map(
-                    per_shard, mesh=mesh, in_specs=(flat_specs, spec, spec),
-                    out_specs=spec, check_rep=False,
-                )
+            fn = jax.shard_map(
+                per_shard, mesh=mesh, in_specs=(flat_specs, spec, spec),
+                out_specs=spec, check_vma=False,
+            )
         else:
             fn = per_shard
         planned = self.planned_out_spec

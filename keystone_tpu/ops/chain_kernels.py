@@ -49,13 +49,16 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .pallas_kernels import (
     _rectify_pool_kernel,
     _round_up,
+    canary_verdict,
     rectify_pool_reference,
+    run_outside_trace,
 )
 
 #: the fused-conv budget discipline: leave ~6 MB of the 16 MB VMEM for
@@ -110,10 +113,7 @@ def use_chain_kernels() -> bool:
         return False
     if chain_interpret_forced():
         return True
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def chain_interpret_forced() -> bool:
@@ -126,10 +126,7 @@ def chain_interpret() -> bool:
     """Interpret off-TPU (validated emulation), native on TPU."""
     if chain_interpret_forced():
         return True
-    try:
-        return jax.default_backend() != "tpu"
-    except Exception:
-        return True
+    return jax.default_backend() != "tpu"
 
 
 # ---------------------------------------------------------------------------
@@ -385,17 +382,20 @@ def rectify_pool_vectorize(x, alpha, max_val, pool, stride, *,
                            interpret=None):
     """Dispatcher: the chain kernel when the gate and geometry allow,
     the XLA oracle otherwise. A canary (the fused-conv discipline)
-    settles native-compile eligibility per geometry so a Mosaic reject
-    demotes instead of crashing the enclosing program."""
+    compiles and runs the kernel once per geometry, eagerly, at the
+    block the real program will use (one row more than a block, so a
+    padded tail too): what Mosaic refuses fails there, by name, and not
+    in the middle of the enclosing program's compile."""
     if use_chain_kernels():
         n, h, w, k = x.shape
         interp = chain_interpret() if interpret is None else interpret
         bn = _rectify_pool_vectorize_block(h, w, k, pool, stride)
         if bn > 0 and (interp or _canary_ok(
             ("rectify_pool_vectorize", h, w, k, pool, stride),
-            lambda: rectify_pool_vectorize_pallas(
-                jnp.zeros((1, h, w, k), jnp.float32),
-                0.1, 0.0, pool, stride),
+            lambda: run_outside_trace(
+                lambda xc: rectify_pool_vectorize_pallas(
+                    xc, 0.1, 0.0, pool, stride),
+                np.zeros((bn + 1, h, w, k), np.float32)),
         )):
             try:
                 return rectify_pool_vectorize_pallas(
@@ -538,7 +538,7 @@ def elementwise_chain_pallas(
         x_refs = refs[: 2 if needs_mask else 1]
         p_refs = refs[len(x_refs):-1]
         o_ref = refs[-1]
-        xb = x_refs[0][...]
+        xb = x_refs[0][...].reshape((bn,) + x_item)
         mb = x_refs[1][...] if needs_mask else None
         idx = 0
         for (masked, _, body), stage in zip(bodies, ops):
@@ -548,33 +548,47 @@ def elementwise_chain_pallas(
             if masked:
                 xb = xb * mb.reshape(
                     (-1,) + (1,) * (xb.ndim - 1)).astype(xb.dtype)
-        o_ref[...] = xb.astype(o_ref.dtype)
+        o_ref[...] = xb.astype(o_ref.dtype).reshape(o_ref.shape)
 
     def _block(shape, ndim=None):
         nd = len(shape) if ndim is None else ndim
         return pl.BlockSpec(shape, lambda i, nd=nd: (i,) + (0,) * (nd - 1),
                             memory_space=pltpu.VMEM)
 
-    in_specs = [_block((bn,) + x.shape[1:])]
-    operands = [x]
+    def streamed(item):
+        """Item shape under which Mosaic takes a streamed (bn, *item)
+        block. A (bn, width) block has the batch as its sublane
+        dimension and is taken only in whole 8-row tiles or covering
+        the array: the v5e's compiler refused (4, 1024) of (2052, 1024),
+        the LinearPixels trail's output. A unit dimension in between
+        makes the last two dimensions the array's own; the reshape
+        outside the kernel is a bitcast."""
+        if len(item) == 1 and bn % 8 and bn != n_pad:
+            return (1,) + item
+        return item
+
+    x_item = tuple(x.shape[1:])
+    in_specs = [_block((bn,) + streamed(x_item))]
+    operands = [x.reshape((n_pad,) + streamed(x_item))]
     if needs_mask:
-        in_specs.append(_block((bn, 1)))
-        operands.append(m)
+        in_specs.append(_block((bn,) + streamed((1,))))
+        operands.append(m.reshape((n_pad,) + streamed((1,))))
     for a in flat_ops:
         in_specs.append(pl.BlockSpec(
             a.shape, lambda i, nd=a.ndim: (0,) * nd,
             memory_space=pltpu.VMEM))
         operands.append(a)
+    out_item = tuple(out_aval.shape[1:])
     out = pl.pallas_call(
         kernel,
         grid=(n_pad // bn,),
         in_specs=in_specs,
-        out_specs=_block((bn,) + out_aval.shape[1:]),
-        out_shape=jax.ShapeDtypeStruct((n_pad,) + out_aval.shape[1:],
+        out_specs=_block((bn,) + streamed(out_item)),
+        out_shape=jax.ShapeDtypeStruct((n_pad,) + streamed(out_item),
                                        out_aval.dtype),
         interpret=interpret,
     )(*operands)
-    return out[:n]
+    return out.reshape((n_pad,) + out_item)[:n]
 
 
 def elementwise_chain(statics, params, x, mask=None, *, interpret=None):
@@ -591,17 +605,21 @@ def elementwise_chain(statics, params, x, mask=None, *, interpret=None):
             # canary operands are rebuilt from STATIC shapes (params may
             # be tracers inside the enclosing program trace) and filled
             # with ones, not zeros — a zero std/eps would NaN the probe
-            # and falsely demote a working geometry
+            # and fail a working geometry
             canary_params = [
                 jax.tree_util.tree_map(
-                    lambda a: jnp.ones(jnp.shape(a), jnp.result_type(a)), p)
+                    lambda a: np.ones(jnp.shape(a), jnp.result_type(a)), p)
                 for p in params
             ]
             if bn > 0 and (interp or _canary_ok(
                 geo,
-                lambda: elementwise_chain_pallas(
-                    statics, canary_params,
-                    jnp.zeros((1,) + tuple(x.shape[1:]), x.dtype)),
+                # one row more than a block: the block geometry of the
+                # real program and a padded tail, not the whole-array
+                # block a single row would get
+                lambda: run_outside_trace(
+                    lambda ps, xc: elementwise_chain_pallas(statics, ps, xc),
+                    canary_params,
+                    np.zeros((bn + 1,) + tuple(x.shape[1:]), x.dtype)),
             )):
                 try:
                     return elementwise_chain_pallas(
@@ -619,40 +637,10 @@ _chain_canary: dict = {}
 
 
 def _canary_ok(key, thunk) -> bool:
-    """Compile-and-run a chain kernel ONCE per geometry on tiny data,
-    eagerly — the fused-conv canary discipline: the dispatcher's
-    trace-time try/except cannot see compile-time failures (scoped-vmem
-    OOM, a Mosaic reject on an in-kernel reshape/reduce) when the call
-    sits inside an outer jit. States: True/False permanent, 1 = one
-    failed attempt (retried once, so a transient device blip doesn't
-    demote a working geometry for the whole process). Multihost: every
-    process adopts process 0's verdict so collective launches stay
-    aligned (the `_fused_conv_canary_ok` broadcast)."""
-    state = _chain_canary.get(key)
-    if state is True or state is False:
-        return state
-    multihost = jax.process_count() > 1
-    try:
-        import numpy as np
-
-        got = thunk()
-        ok = bool(np.isfinite(np.asarray(got)).all())
-    except ChainKernelIneligibleError:
-        ok = False
-    except Exception as e:
-        import logging
-
-        logging.getLogger(__name__).warning(
-            "chain kernel canary failed at geometry %s (%s: %s); "
-            "using the XLA path for it", key, type(e).__name__, e)
-        ok = False if (multihost or state == 1) else 1
-    if multihost:
-        import numpy as np
-        from jax.experimental import multihost_utils
-
-        ok = bool(multihost_utils.broadcast_one_to_all(np.asarray(bool(ok))))
-    _chain_canary[key] = ok
-    return ok is True
+    """The chain kernels' canary: `pallas_kernels.canary_verdict` over
+    `_chain_canary`, demoting only on `ChainKernelIneligibleError`."""
+    return canary_verdict(_chain_canary, key, thunk,
+                          ChainKernelIneligibleError, "chain kernel")
 
 
 def build_chain_fn(statics, family=None, interpret=None):
@@ -683,6 +671,13 @@ def build_chain_fn(statics, family=None, interpret=None):
     return fn
 
 
+#: what a stage or a shape that no chain kernel takes can raise from
+#: `chain_feasible`'s static probes (nothing there compiles or runs);
+#: an error from the backend is none of these and propagates
+_STATIC_PROBE_ERRORS = (TypeError, ValueError, AttributeError,
+                        NotImplementedError, IndexError, KeyError)
+
+
 def chain_feasible(stages, item_shape, dtype=jnp.float32):
     """(ok, reason): probe the chain kernel's VMEM geometry at the
     per-item input shape without compiling anything. Used by the
@@ -693,7 +688,7 @@ def chain_feasible(stages, item_shape, dtype=jnp.float32):
 
     try:
         fused = [_stage_fuse(s) for s in _peephole(list(stages))]
-    except Exception as e:
+    except _STATIC_PROBE_ERRORS as e:
         return False, f"stage decomposition failed: {type(e).__name__}"
     statics = tuple(f[0] for f in fused)
     params = [f[1] for f in fused]
@@ -718,7 +713,7 @@ def chain_feasible(stages, item_shape, dtype=jnp.float32):
         x = jax.ShapeDtypeStruct((8,) + tuple(item_shape), dtype)
         ops = [prep(p) for (_, prep, _), p in zip(bodies, params)]
         bn = _elementwise_geometry(bodies, ops, x)
-    except Exception as e:
+    except _STATIC_PROBE_ERRORS as e:
         return False, f"geometry probe failed: {type(e).__name__}"
     if bn <= 0:
         return False, f"VMEM: no feasible block at item shape {item_shape}"

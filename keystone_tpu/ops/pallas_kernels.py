@@ -20,16 +20,16 @@ Every op has `*_reference` (pure jnp — the XLA path, also the CPU/test
 oracle) and a dispatcher. Kernels are runnable in interpret mode on CPU
 for unit tests.
 
-**Measured on v5e (1 chip, round 4, 2026-07-30; fresh-valued chained
-timing — the transport memoizes byte-identical executions, so earlier
-repeat-same-values timings were unreliable):**
+**Measured on a v5e (1 chip, round 4, 2026-07-30, an earlier machine;
+fresh-valued chained timing; not comparable with the machine builders
+reach now, to be re-measured):**
 
 - rectify+pool: Pallas wins at EVERY measured shape —
   (2048,27,27,256): 23.2 vs 25.4 ms; (512,27,27,512): 8.3 vs 12.8 ms
   (1.54×); (4096,13,13,128): 6.3 vs 7.9 ms; (1024,54,54,64): 11.2 vs
   12.4 ms. → **default-ON on TPU** (`KEYSTONE_DISABLE_PALLAS_RECTIFY=1`
-  reverts). Round 2's parity readings came from the memo-tainted
-  methodology.
+  reverts). Round 2's parity readings repeated the same values and
+  were unreliable.
 - RBF block: parity across shapes — (8192×2048,d=1024): 5.36 vs
   5.13 ms; (32768×1024,d=256): 4.85 vs 4.75; (4096×4096,d=2048): 10.4
   vs 11.0; (16384×512,d=64): 2.10 vs 2.12. → stays opt-in
@@ -74,10 +74,7 @@ def use_pallas() -> bool:
         return False
     if os.environ.get("KEYSTONE_ENABLE_PALLAS") != "1":
         return False
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def use_rectify_pallas() -> bool:
@@ -89,10 +86,7 @@ def use_rectify_pallas() -> bool:
         return False
     if os.environ.get("KEYSTONE_DISABLE_PALLAS_RECTIFY") == "1":
         return False
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 # ---------------------------------------------------------------------------
@@ -129,14 +123,29 @@ def _rectify_pool_kernel(x_ref, o_ref, *, alpha, max_val, pool, stride, gy, gx, 
             o_ref[:, iy, ix, k : 2 * k] = neg
 
 
+def _rectify_pool_block(h: int, w: int, k: int) -> int:
+    """Images per block of the standalone rectify+pool kernel. VMEM
+    budget: the pipelined input block is double-buffered, and tiling
+    pads the sublane dim (W) to 8 and the lane dim (K) to 128 — keep the
+    nominal input block under ~3 MB of the 16 MB VMEM. The working set
+    is input-only (the pooled output is negligible), so the
+    2x-double-buffer chain formula would over-reserve; the chain path's
+    chooser covers the fused RectifyPool>>Vectorizer form instead. A
+    fixed block of 8 is refused by the v5e's compiler at the CIFAR
+    conv-output geometry (27x27x256: 16.19M of scoped VMEM against a
+    16.00M limit), so there is no fixed default."""
+    per_img = h * _round_up(w, 8) * _round_up(k, 128) * 4
+    return max(1, min(8, (3 << 20) // max(per_img, 1)))  # keystone: ignore[KJ017]
+
+
 def rectify_pool_pallas(
     x, alpha: float, max_val: float, pool: int, stride: int,
-    *, block_n: int = 8, interpret: bool = False,
+    *, block_n: "int | None" = None, interpret: bool = False,
 ):
     n, h, w, k = x.shape
     gy = (h - pool) // stride + 1
     gx = (w - pool) // stride + 1
-    bn = min(block_n, n)
+    bn = min(block_n or _rectify_pool_block(h, w, k), n)
     n_pad = _round_up(n, bn)
     if n_pad != n:
         x = jnp.pad(x, ((0, n_pad - n), (0, 0), (0, 0), (0, 0)))
@@ -162,16 +171,7 @@ def rectify_pool_pallas(
 def rectify_pool(x, alpha: float, max_val: float, pool: int, stride: int):
     """Dispatcher: Pallas on TPU (default-on), XLA elsewhere."""
     if use_rectify_pallas():
-        # VMEM budget: the pipelined input block is double-buffered, and
-        # tiling pads the sublane dim (W) to 8 and the lane dim (K) to
-        # 128 — keep the nominal input block under ~3 MB of the 16 MB VMEM
-        per_img = x.shape[1] * _round_up(x.shape[2], 8) * _round_up(x.shape[3], 128) * 4
-        # conv-era standalone kernel: its working set is input-only (the
-        # pooled output is negligible), so the 2x-double-buffer chain
-        # formula over-reserves; the chain path's chooser covers the
-        # fused RectifyPool>>Vectorizer form instead
-        block_n = max(1, min(8, (3 << 20) // max(per_img, 1)))  # keystone: ignore[KJ017]
-        return rectify_pool_pallas(x, alpha, max_val, pool, stride, block_n=block_n)
+        return rectify_pool_pallas(x, alpha, max_val, pool, stride)
     return rectify_pool_reference(x, alpha, max_val, pool, stride)
 
 
@@ -285,10 +285,7 @@ def use_fused_conv() -> bool:
         return False
     if os.environ.get("KEYSTONE_DISABLE_FUSED_CONV") == "1":
         return False
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 class FusedConvIneligibleError(ValueError):
@@ -346,66 +343,85 @@ def hwio_to_cmajor(kernel_hwio):
     return kernel_hwio.transpose(2, 0, 1, 3).reshape(-1, kernel_hwio.shape[3])
 
 
+def run_outside_trace(fn, *args):
+    """Compile ``fn`` for the numpy ``args`` (arrays or pytrees of them)
+    and run it once, whatever trace the caller is inside; returns the
+    result as numpy. The dispatchers consult their canary at trace time,
+    inside the enclosing program's trace, where an eager call is only
+    staged into that program: its result is a tracer, and reading it
+    raises. Lowering ahead of time from shapes starts a trace of its own
+    and leaves the caller's alone, and the compiled executable takes
+    numpy arrays as they are."""
+    import numpy as np
+
+    avals = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(np.shape(a), np.asarray(a).dtype), args)
+    compiled = jax.jit(fn).lower(*avals).compile()
+    return np.asarray(compiled(*args))
+
+
+def canary_verdict(verdicts: dict, key, thunk, ineligible, what: str) -> bool:
+    """THE canary rule, shared by the fused conv and the chain kernels:
+    ``thunk`` compiles and runs a kernel once for geometry ``key``
+    (through `run_outside_trace`) and the verdict stays in ``verdicts``
+    for anyone to read. True when it ran and gave finite values; False
+    for the one designed demotion, ``ineligible`` (a block geometry
+    that cannot fit VMEM, deterministic in the geometry). Anything else
+    the compile or the run raises (a scoped-vmem OOM, a Mosaic lowering
+    reject, a backend that is not there) propagates and leaves no
+    verdict: it would hit the enclosing program anyway, and a kernel
+    that silently became the XLA path is a measurement of something
+    else."""
+    import numpy as np
+
+    if key in verdicts:
+        return verdicts[key]
+    try:
+        got = thunk()
+    except ineligible:
+        ok = False
+    else:
+        if not np.isfinite(got).all():
+            raise FloatingPointError(
+                f"{what} canary at geometry {key} returned non-finite "
+                "values")
+        ok = True
+    if jax.process_count() > 1:
+        # Every process must compile the SAME program for the collective
+        # launch (fused on one, XLA on the rest → a wedged collective).
+        # Adopt process 0's verdict everywhere: the canary runs at the
+        # same SPMD program point on every process (same geometry key,
+        # same call site), so this broadcast lines up like
+        # parallel.multihost.barrier() does.
+        from jax.experimental import multihost_utils
+
+        ok = bool(multihost_utils.broadcast_one_to_all(np.asarray(ok)))
+    verdicts[key] = ok
+    return ok
+
+
 _fused_conv_canary: dict = {}
 
 
 def _fused_conv_canary_ok(h: int, w: int, c: int, k: int, pool: int,
                           stride: int, normalize: bool, patch: int) -> bool:
-    """Compile-and-run the fused kernel ONCE per geometry on tiny data,
-    eagerly. The dispatcher's trace-time try/except cannot see
-    COMPILE-time failures (a scoped-vmem OOM, a Mosaic lowering reject)
-    when the call sits inside an outer jit — they would surface when the
-    enclosing program compiles and hard-fail the pipeline. The canary
-    compiles the same kernel geometry (one n=1 call pads to one full
-    image block) outside any enclosing trace, so a bad geometry demotes
-    to the XLA path instead of crashing the run."""
-    key = (h, w, c, k, pool, stride, bool(normalize), patch)
-    # cached states: True (passed, permanent), False (failed,
-    # permanent), 1 (one failed attempt — retried once on the next
-    # call, so a transient device blip at first-trace time doesn't
-    # demote a working geometry for the whole process)
-    state = _fused_conv_canary.get(key)
-    if state is True or state is False:
-        return state
-    multihost = jax.process_count() > 1
-    try:
-        import numpy as np
+    """The fused kernel's canary (`canary_verdict`): one n=1 call, which
+    pads to one full image block, on all-zero inputs."""
+    import numpy as np
 
-        got = conv_rectify_pool_pallas(
-            jnp.zeros((1, h, w, c), jnp.float32),
-            jnp.zeros((c * patch * patch, k), jnp.float32),
-            jnp.zeros((k,), jnp.float32),
-            jnp.zeros((k,), jnp.float32),
-            0.1, 0.0, pool, stride, normalize, patch,
-        )
-        ok = bool(np.isfinite(np.asarray(got)).all())
-    except FusedConvIneligibleError:
-        ok = False  # designed, silent fallback: the block geometry
-        # cannot fit VMEM (deterministic in the geometry)
-    except Exception as e:
-        import logging
-
-        logging.getLogger(__name__).warning(
-            "fused conv canary failed at geometry %s (%s: %s); "
-            "using the XLA path for it", key, type(e).__name__, e)
-        # Single-host: retry once (a transient device blip must not
-        # demote a working geometry for the whole process). Multi-host:
-        # no retry marker — the verdict is settled collectively below.
-        ok = False if (multihost or state == 1) else 1
-    if multihost:
-        # Every process must compile the SAME program for the collective
-        # launch, but a transient blip can hit only SOME hosts, leaving
-        # them with different local verdicts (fused on one, XLA on the
-        # rest → a wedged collective). Adopt process 0's verdict
-        # everywhere: the canary runs at the same SPMD program point on
-        # every process (same geometry key, same call site), so this
-        # broadcast lines up like parallel.multihost.barrier() does.
-        import numpy as np
-        from jax.experimental import multihost_utils
-
-        ok = bool(multihost_utils.broadcast_one_to_all(np.asarray(bool(ok))))
-    _fused_conv_canary[key] = ok
-    return ok is True
+    return canary_verdict(
+        _fused_conv_canary,
+        (h, w, c, k, pool, stride, bool(normalize), patch),
+        lambda: run_outside_trace(
+            lambda images, g, colsum, bias: conv_rectify_pool_pallas(
+                images, g, colsum, bias,
+                0.1, 0.0, pool, stride, normalize, patch),
+            np.zeros((1, h, w, c), np.float32),
+            np.zeros((c * patch * patch, k), np.float32),
+            np.zeros((k,), np.float32),
+            np.zeros((k,), np.float32),
+        ),
+        FusedConvIneligibleError, "fused conv")
 
 
 def conv_rectify_pool(
@@ -413,8 +429,8 @@ def conv_rectify_pool(
     pool: int, stride: int, normalize: bool,
 ):
     """Dispatcher: fused Pallas kernel on TPU (default on), XLA
-    elsewhere or when the block geometry cannot fit VMEM or fails its
-    canary compile. The single entry point for
+    elsewhere or when the block geometry cannot fit VMEM (the canary's
+    one designed demotion). The single entry point for
     Convolver>>Rectifier>>Pooler semantics — the fusion peephole and
     the driver graft entry both route through it."""
     # precision-planner boundaries may hand bf16 activations to an f32
@@ -437,14 +453,6 @@ def conv_rectify_pool(
             )
         except FusedConvIneligibleError:
             pass
-        except Exception as e:  # trace failure on an unanticipated
-            # geometry: degrade to the XLA path rather than hard-fail
-            # the pipeline (compile-time failures are the canary's job)
-            import logging
-
-            logging.getLogger(__name__).warning(
-                "fused conv Pallas path failed (%s: %s); falling back "
-                "to XLA", type(e).__name__, e)
     return conv_rectify_pool_reference(
         images, kernel_hwio, colsum, bias, alpha, max_val, pool, stride,
         normalize,

@@ -31,16 +31,8 @@ from . import mesh as meshlib
 
 
 def _shard_map(fn, mesh, in_specs, out_specs):
-    try:
-        from jax import shard_map
-
-        return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                         check_vma=False)
-    except ImportError:  # older jax spells it differently
-        from jax.experimental.shard_map import shard_map
-
-        return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                         check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 # jitted programs keyed on (kind, mesh, axis[, seq_op]) — rebuilding the

@@ -170,10 +170,10 @@ def run_header() -> Dict[str, Any]:
         # the platform the run's measurements were taken on — what a
         # later --emit-calibration must stamp into provenance (emitting
         # from a different host must not relabel TPU-implied weights
-        # as CPU ones). Never initializes a backend.
-        from ..nodes.learning.cost_model import _live_platform_no_init
+        # as CPU ones)
+        from ..nodes.learning.cost_model import live_platform
 
-        platform = _live_platform_no_init()
+        platform = live_platform()
     except Exception:
         pass
     return {

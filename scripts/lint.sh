@@ -371,7 +371,7 @@ echo "== compile smoke (warm second run performs 0 cold compiles) =="
 COMPILE_CACHE="$(mktemp -d /tmp/keystone_compile_smoke.XXXXXX)"
 COMPILE_TRACE="$(mktemp /tmp/keystone_compile_smoke.XXXXXX.json)"
 trap 'rm -f "$SHARDING_JSON" "$PLANNER_JSON" "$PRECISION_JSON" "$ROOFLINE_JSON" "$UNIFIED_JSON" "$SERVING_JSON" "$TRACE_TMP" "$DISPATCH_TRACE" "$COMPILE_TRACE"; rm -rf "$COMPILE_CACHE"' EXIT
-JAX_PLATFORMS=cpu KEYSTONE_COMPILE_CACHE="$COMPILE_CACHE" \
+JAX_PLATFORMS=cpu JAX_COMPILATION_CACHE_DIR="$COMPILE_CACHE" \
 KEYSTONE_TRACE="$COMPILE_TRACE" python - <<'PY'
 # One example pipeline run TWICE against a fresh persistent-cache dir
 # with tracing armed: the second (rebuilt-from-scratch) run must perform
@@ -415,7 +415,7 @@ echo "== megafusion smoke (1-program apply run; warm repeat stays 0-cold) =="
 MEGA_CACHE="$(mktemp -d /tmp/keystone_mega_smoke.XXXXXX)"
 MEGA_TRACE="$(mktemp /tmp/keystone_mega_smoke.XXXXXX.json)"
 trap 'rm -f "$SHARDING_JSON" "$PLANNER_JSON" "$PRECISION_JSON" "$ROOFLINE_JSON" "$UNIFIED_JSON" "$SERVING_JSON" "$TRACE_TMP" "$DISPATCH_TRACE" "$COMPILE_TRACE" "$MEGA_TRACE"; rm -rf "$COMPILE_CACHE" "$MEGA_CACHE"' EXIT
-JAX_PLATFORMS=cpu KEYSTONE_MEGAFUSION=1 KEYSTONE_COMPILE_CACHE="$MEGA_CACHE" \
+JAX_PLATFORMS=cpu KEYSTONE_MEGAFUSION=1 JAX_COMPILATION_CACHE_DIR="$MEGA_CACHE" \
 KEYSTONE_TRACE="$MEGA_TRACE" python - <<'PY'
 # One example apply run TWICE under megafusion against a fresh
 # persistent-cache dir with tracing armed: each apply run must execute
@@ -722,7 +722,7 @@ echo "== out-of-core smoke (dataset 8x budget: windowed peak under budget, warm 
 OOC_LEDGER="$(mktemp /tmp/keystone_ooc_smoke.XXXXXX.jsonl)"
 OOC_CACHE="$(mktemp -d /tmp/keystone_ooc_cache.XXXXXX)"
 JAX_PLATFORMS=cpu KEYSTONE_LEDGER="$OOC_LEDGER" \
-KEYSTONE_COMPILE_CACHE="$OOC_CACHE" python - <<'PY'
+JAX_COMPILATION_CACHE_DIR="$OOC_CACHE" python - <<'PY'
 # Two halves of the out-of-core contract. (1) Streaming: a synthetic
 # dataset 8x a synthetic HBM budget streams through the windowed spill
 # prefetcher into normal-equation accumulators — the warm second pass

@@ -1,8 +1,7 @@
 """Render the stage/roofline/flagship tables from a bench record
-(BENCH_LAST_GOOD.json or a bench.py output line) as markdown for
-PERF.md.
+(a file holding one bench.py output line) as markdown for PERF.md.
 
-Usage: python scripts/perf_table.py [path=BENCH_LAST_GOOD.json]
+Usage: python scripts/perf_table.py <path>
        python scripts/perf_table.py --trace run.json [--top N]
        python scripts/perf_table.py --ledger run.ledger.jsonl
        python scripts/perf_table.py --roofline [EXAMPLE ...]
@@ -320,19 +319,13 @@ def main():
         top = (int(sys.argv[sys.argv.index("--top") + 1])
                if "--top" in sys.argv else 15)
         return trace_table(path, top)
-    path = sys.argv[1] if len(sys.argv) > 1 else "BENCH_LAST_GOOD.json"
-    with open(path) as f:
-        text = f.read().strip()
-    if text.startswith("BENCH_DETAIL "):
-        text = text[len("BENCH_DETAIL "):]
-    rec = json.loads(text)
+    if len(sys.argv) < 2:
+        raise SystemExit(__doc__)
+    with open(sys.argv[1]) as f:
+        rec = json.loads(f.read().strip())
     d = rec.get("detail", rec)
-    # Incomplete / stale / error records must not render as clean results
+    # Error records must not render as clean results
     flags = []
-    if rec.get("partial"):
-        flags.append(f"PARTIAL ({rec['partial']})")
-    if d.get("stale"):
-        flags.append("STALE carry-over")
     if rec.get("error"):
         flags.append(f"ERROR: {rec['error']}")
     if flags:

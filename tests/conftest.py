@@ -21,16 +21,7 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 import jax
 
 jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_num_cpu_devices", 8)
-except AttributeError:
-    # Older jaxlib (< 0.5) has no jax_num_cpu_devices config knob; the
-    # XLA flag is read at backend initialization, which hasn't happened
-    # yet if the session fixture below can still assert the mesh.
-    os.environ["XLA_FLAGS"] = (
-        os.environ.get("XLA_FLAGS", "")
-        + " --xla_force_host_platform_device_count=8"
-    )
+jax.config.update("jax_num_cpu_devices", 8)
 
 import pytest  # noqa: E402
 

@@ -233,8 +233,8 @@ def _load_bench():
 
 def test_bench_band_gate():
     """bench.py's record gate: out-of-band accuracy is marked as an
-    error and never persists as the stale-fallback record; in-band TPU
-    runs persist; CPU runs never persist."""
+    error and is never a clean record; in-band TPU runs are clean; CPU
+    runs never are."""
     bench = _load_bench()
 
     base = {"images_per_sec": 1000.0, "test_accuracy": 0.85,
@@ -270,7 +270,7 @@ def test_bench_band_gate():
 
 
 def test_bench_partial_record_ranking():
-    """The parent's best-partial selection across retry attempts: a
+    """Best-partial selection across checkpoints: a
     later-tier checkpoint (e.g. krr_tier, everything measured except the
     fused tier) must beat an earlier-tier one from another attempt, ties
     go to the newer attempt, and unknown progress values rank lowest."""
@@ -302,9 +302,9 @@ def test_bench_partial_record_ranking():
 
 
 def test_bench_tier_errors_surface_and_never_persist():
-    """A record whose tier payload carries {"error": ...} (the child's
+    """A record whose tier payload carries {"error": ...} (the
     failure-isolated tiers) must surface the failure top-level and never
-    persist as the stale-fallback record, even in-band on TPU."""
+    count as clean, even in-band on TPU."""
     bench = _load_bench()
 
     base = {"images_per_sec": 1000.0, "test_accuracy": 0.85,
@@ -332,7 +332,7 @@ def test_bench_tier_error_scan_ignores_informational_payloads():
             "accuracy_band": [0.72, 0.96], "platform": "tpu",
             "accuracy_in_band": True,
             # informational payloads with an embedded "error" field
-            "tunnel_diagnostics": {"error": "transient wedge at 03:12"},
+            "link_diagnostics": {"error": "transient stall at 03:12"},
             "north_star": {"target_accuracy": 0.84, "accuracy_ok": True,
                            "error": "informational only"},
             # healthy real tiers

@@ -310,3 +310,51 @@ def test_chunk_size_config_reaches_batching_and_memory_model():
         assert resolve_chunk_rows(None) == 4
         assert resolve_chunk_rows(64) == 64
     assert execution_config().chunk_size == 256  # override scoped
+
+
+def test_compile_cache_is_placed_from_outside(monkeypatch, tmp_path):
+    """`JAX_COMPILATION_CACHE_DIR` names the cache directory and
+    `_sync_compile_cache` never sets one that jax already has;
+    `KEYSTONE_COMPILE_CACHE=0` turns the cache off and names nothing;
+    with neither, the fixed repo-local directory."""
+    import jax
+
+    from keystone_tpu.workflow import env
+
+    outside = str(tmp_path / "outside")
+    monkeypatch.delenv("KEYSTONE_COMPILE_CACHE", raising=False)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    assert env._env_compile_cache_dir() == env._default_compile_cache_dir()
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", outside)
+    assert env._env_compile_cache_dir() == outside
+    monkeypatch.setenv("KEYSTONE_COMPILE_CACHE", "0")
+    assert env._env_compile_cache_dir() is None
+    # a directory is not a value of the off switch any more
+    monkeypatch.setenv("KEYSTONE_COMPILE_CACHE", str(tmp_path / "named"))
+    assert env._env_compile_cache_dir() == outside
+
+    updates = []
+    real_update = jax.config.update
+
+    def recording_update(name, value):
+        updates.append(name)
+        real_update(name, value)
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        # jax holds the outside directory already, as it does when the
+        # variable was set before the process started
+        real_update("jax_compilation_cache_dir", outside)
+        monkeypatch.setattr(jax.config, "update", recording_update)
+        with config_override(compile_cache_dir=outside):
+            assert "jax_compilation_cache_dir" not in updates
+            assert jax.config.jax_enable_compilation_cache
+            with config_override(compile_cache_dir=None):
+                assert not jax.config.jax_enable_compilation_cache
+                assert jax.config.jax_compilation_cache_dir == outside
+            assert "jax_compilation_cache_dir" not in updates
+            assert jax.config.jax_enable_compilation_cache
+    finally:
+        monkeypatch.setattr(jax.config, "update", real_update)
+        real_update("jax_compilation_cache_dir", before)
+        env._sync_compile_cache(execution_config())

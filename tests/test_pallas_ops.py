@@ -233,20 +233,24 @@ def test_conv_fused_stage_ineligible_fallback_reconstructs_hwio(monkeypatch):
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
 
 
-def test_fused_conv_canary_demotes_compile_failures(monkeypatch):
-    """A kernel geometry whose COMPILE fails (the class a trace-time
-    try/except inside an outer jit cannot see) must be demoted to the
-    XLA path by the eager per-geometry canary — retried once (transient
-    device blips must not demote a geometry forever), then cached as a
-    permanent verdict."""
-    import keystone_tpu.ops.pallas_kernels as pk
-
-    rng = np.random.default_rng(5)
-    imgs = jnp.asarray(rng.random(size=(3, 16, 16, 3)).astype(np.float32))
+def _canary_case(seed, n):
+    rng = np.random.default_rng(seed)
+    imgs = jnp.asarray(rng.random(size=(n, 16, 16, 3)).astype(np.float32))
     kern = jnp.asarray(rng.normal(size=(5, 5, 3, 8)).astype(np.float32))
     colsum = jnp.asarray(rng.normal(size=(8,)).astype(np.float32))
     bias = jnp.asarray(rng.normal(size=(8,)).astype(np.float32))
+    return imgs, kern, colsum, bias
 
+
+def test_fused_conv_canary_raises_what_it_did_not_design(monkeypatch):
+    """A kernel geometry whose COMPILE fails for a reason nobody
+    designed (a scoped-vmem OOM, a Mosaic reject, a backend that is not
+    there) must fail the caller, not become the XLA path in silence:
+    the eager per-geometry canary lets the exception through and keeps
+    no verdict and no retry marker, so every later call asks again."""
+    import keystone_tpu.ops.pallas_kernels as pk
+
+    imgs, kern, colsum, bias = _canary_case(5, 3)
     calls = {"n": 0}
 
     def boom(*a, **k):
@@ -257,73 +261,89 @@ def test_fused_conv_canary_demotes_compile_failures(monkeypatch):
     monkeypatch.setattr(pk, "conv_rectify_pool_pallas", boom)
     monkeypatch.setattr(pk, "_fused_conv_canary", {})
 
+    for _ in range(3):
+        with pytest.raises(RuntimeError, match="scoped-vmem OOM"):
+            pk.conv_rectify_pool(
+                imgs, kern, colsum, bias, 0.1, 0.0, 5, 4, True)
+    assert calls["n"] == 3, calls["n"]
+    assert pk._fused_conv_canary == {}
+
+    # a failure, then a kernel that works: nothing was remembered
+    # against the geometry, so the next call records a pass
     want = np.asarray(pk.conv_rectify_pool_reference(
         imgs, kern, colsum, bias, 0.1, 0.0, 5, 4, True))
-    # call 1: attempt; call 2: retry-once; call 3: cached permanent False
+    monkeypatch.setattr(pk, "conv_rectify_pool_pallas",
+                        lambda *a, **kw: jnp.asarray(want))
+    got = np.asarray(pk.conv_rectify_pool(
+        imgs, kern, colsum, bias, 0.1, 0.0, 5, 4, True))
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+    assert list(pk._fused_conv_canary.values()) == [True]
+
+    # a canary that runs and returns garbage is a failure too
+    pk._fused_conv_canary.clear()
+    monkeypatch.setattr(pk, "conv_rectify_pool_pallas",
+                        lambda *a, **kw: jnp.full((1, 3, 3, 16), jnp.nan))
+    with pytest.raises(FloatingPointError):
+        pk.conv_rectify_pool(imgs, kern, colsum, bias, 0.1, 0.0, 5, 4, True)
+    assert pk._fused_conv_canary == {}
+
+
+def test_fused_conv_canary_records_the_designed_demotion(monkeypatch):
+    """The one designed demotion — a block geometry that cannot fit VMEM
+    (`FusedConvIneligibleError`) — takes the XLA path, is tried once,
+    and stays readable as False in the verdict dict."""
+    import keystone_tpu.ops.pallas_kernels as pk
+
+    imgs, kern, colsum, bias = _canary_case(5, 3)
+    calls = {"n": 0}
+
+    def ineligible(*a, **k):
+        calls["n"] += 1
+        raise pk.FusedConvIneligibleError("no block fits VMEM (simulated)")
+
+    monkeypatch.setattr(pk, "use_fused_conv", lambda: True)
+    monkeypatch.setattr(pk, "conv_rectify_pool_pallas", ineligible)
+    monkeypatch.setattr(pk, "_fused_conv_canary", {})
+
+    want = np.asarray(pk.conv_rectify_pool_reference(
+        imgs, kern, colsum, bias, 0.1, 0.0, 5, 4, True))
     for _ in range(3):
         got = np.asarray(pk.conv_rectify_pool(
             imgs, kern, colsum, bias, 0.1, 0.0, 5, 4, True))
         np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
-    assert calls["n"] == 2, calls["n"]
-
-    # a transient failure then a recovery: second attempt enables the path
-    pk._fused_conv_canary.clear()
-    calls["n"] = 0
-    real_pallas = [boom]
-
-    def flaky(*a, **kw):
-        fn, real_pallas[0] = real_pallas[0], ok_pallas
-        return fn(*a, **kw)
-
-    def ok_pallas(*a, **kw):
-        calls["n"] += 1
-        return jnp.asarray(want)
-
-    monkeypatch.setattr(pk, "conv_rectify_pool_pallas", flaky)
-    got = np.asarray(pk.conv_rectify_pool(
-        imgs, kern, colsum, bias, 0.1, 0.0, 5, 4, True))  # canary fails
-    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
-    got = np.asarray(pk.conv_rectify_pool(
-        imgs, kern, colsum, bias, 0.1, 0.0, 5, 4, True))  # retry passes
-    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
-    assert pk._fused_conv_canary and list(
-        pk._fused_conv_canary.values()) == [True]
+    assert calls["n"] == 1, calls["n"]
+    assert list(pk._fused_conv_canary.items()) == [
+        ((16, 16, 3, 8, 5, 4, True, 5), False)]
 
 
 def test_fused_conv_canary_multihost_verdict_is_broadcast(monkeypatch):
-    """In a multi-process job a transient blip can hit only SOME hosts,
-    leaving them with different local canary verdicts and therefore
-    divergent compiled programs in a collective launch. With
-    process_count > 1 every process must adopt process 0's verdict
-    (broadcast), with no per-process transient-retry marker. (The
-    single-process retry fallback is covered by
-    test_fused_conv_canary_demotes_compile_failures.)"""
+    """In a multi-process job processes with different local canary
+    verdicts would compile divergent programs for a collective launch.
+    With process_count > 1 every process must adopt process 0's verdict
+    (broadcast); a failure nobody designed still raises, before any
+    broadcast. (The single-process rules are covered by the two tests
+    above.)"""
     import jax
     from jax.experimental import multihost_utils
 
     import keystone_tpu.ops.pallas_kernels as pk
 
-    rng = np.random.default_rng(6)
-    imgs = jnp.asarray(rng.random(size=(2, 16, 16, 3)).astype(np.float32))
-    kern = jnp.asarray(rng.normal(size=(5, 5, 3, 8)).astype(np.float32))
-    colsum = jnp.asarray(rng.normal(size=(8,)).astype(np.float32))
-    bias = jnp.asarray(rng.normal(size=(8,)).astype(np.float32))
-
+    imgs, kern, colsum, bias = _canary_case(6, 2)
     calls = {"n": 0}
     broadcasts = []
 
-    def boom(*a, **k):
+    def ineligible(*a, **k):
         calls["n"] += 1
-        raise RuntimeError("transient blip (simulated)")
+        raise pk.FusedConvIneligibleError("no block fits VMEM (simulated)")
 
     def fake_broadcast(x):
         # this process plays the non-0 host: process 0's verdict (False
-        # here — it also failed) comes back regardless of local state
+        # here — it demoted too) comes back regardless of local state
         broadcasts.append(bool(np.asarray(x)))
         return np.asarray(False)
 
     monkeypatch.setattr(pk, "use_fused_conv", lambda: True)
-    monkeypatch.setattr(pk, "conv_rectify_pool_pallas", boom)
+    monkeypatch.setattr(pk, "conv_rectify_pool_pallas", ineligible)
     monkeypatch.setattr(pk, "_fused_conv_canary", {})
     monkeypatch.setattr(jax, "process_count", lambda: 2)
     monkeypatch.setattr(
@@ -336,13 +356,12 @@ def test_fused_conv_canary_multihost_verdict_is_broadcast(monkeypatch):
             imgs, kern, colsum, bias, 0.1, 0.0, 5, 4, True))
         np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
     # ONE local attempt, ONE broadcast, then a permanent cached verdict
-    # — never the transient retry marker that made verdicts process-local
     assert calls["n"] == 1, calls["n"]
     assert broadcasts == [False]
     assert list(pk._fused_conv_canary.values()) == [False]
 
     # a host whose local canary PASSES must still adopt process 0's
-    # failing verdict (the divergence the broadcast exists to close)
+    # demoting verdict (the divergence the broadcast exists to close)
     pk._fused_conv_canary.clear()
     monkeypatch.setattr(pk, "conv_rectify_pool_pallas",
                         lambda *a, **k: jnp.zeros((2, 2, 2, 8)))
@@ -351,3 +370,94 @@ def test_fused_conv_canary_multihost_verdict_is_broadcast(monkeypatch):
     np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
     assert broadcasts[-1] is True  # local verdict was pass...
     assert list(pk._fused_conv_canary.values()) == [False]  # ...p0 wins
+
+    # a failure nobody designed raises here as it does on one host:
+    # nothing is broadcast and nothing is remembered
+    pk._fused_conv_canary.clear()
+    n_broadcasts = len(broadcasts)
+
+    def boom(*a, **k):
+        raise RuntimeError("Mosaic reject (simulated)")
+
+    monkeypatch.setattr(pk, "conv_rectify_pool_pallas", boom)
+    with pytest.raises(RuntimeError, match="Mosaic reject"):
+        pk.conv_rectify_pool(imgs, kern, colsum, bias, 0.1, 0.0, 5, 4, True)
+    assert len(broadcasts) == n_broadcasts
+    assert pk._fused_conv_canary == {}
+
+
+def test_chain_canary_raises_or_records(monkeypatch):
+    """The chain kernels' canary follows the same rule as the fused
+    conv's: the designed `ChainKernelIneligibleError` demotes once and
+    is recorded; anything else propagates and leaves no verdict."""
+    import keystone_tpu.ops.chain_kernels as ck
+
+    monkeypatch.setattr(ck, "_chain_canary", {})
+    calls = {"n": 0}
+
+    def ineligible():
+        calls["n"] += 1
+        raise ck.ChainKernelIneligibleError("no block fits VMEM (simulated)")
+
+    assert ck._canary_ok("geo-a", ineligible) is False
+    assert ck._canary_ok("geo-a", ineligible) is False
+    assert calls["n"] == 1 and ck._chain_canary == {"geo-a": False}
+
+    def boom():
+        raise RuntimeError("Mosaic reject (simulated)")
+
+    for _ in range(2):
+        with pytest.raises(RuntimeError, match="Mosaic reject"):
+            ck._canary_ok("geo-b", boom)
+    assert "geo-b" not in ck._chain_canary
+
+    with pytest.raises(FloatingPointError):
+        ck._canary_ok("geo-b", lambda: jnp.full((1, 4), jnp.inf))
+    assert ck._canary_ok("geo-b", lambda: jnp.ones((1, 4))) is True
+    assert ck._chain_canary == {"geo-a": False, "geo-b": True}
+
+
+
+
+def test_canaries_run_inside_an_enclosing_trace(monkeypatch):
+    """The dispatchers consult their canary at TRACE time, inside the
+    enclosing program's `jit`. An eager canary there is staged into the
+    enclosing program and reading its result raises
+    (TracerArrayConversionError), which the old catch-all turned into
+    the XLA path for every geometry; the canary now compiles and runs
+    outside the caller's trace."""
+    import jax
+
+    import keystone_tpu.ops.chain_kernels as ck
+    import keystone_tpu.ops.pallas_kernels as pk
+
+    imgs, kern, colsum, bias = _canary_case(7, 3)
+    real = pk.conv_rectify_pool_pallas
+    monkeypatch.setattr(pk, "use_fused_conv", lambda: True)
+    monkeypatch.setattr(
+        pk, "conv_rectify_pool_pallas",
+        lambda *a, **kw: real(*a, **dict(kw, interpret=True)))
+    monkeypatch.setattr(pk, "_fused_conv_canary", {})
+
+    got = jax.jit(lambda x, g, cs, b: pk.conv_rectify_pool(
+        x, g, cs, b, 0.1, 0.0, 5, 4, True))(imgs, kern, colsum, bias)
+    want = pk.conv_rectify_pool_reference(
+        imgs, kern, colsum, bias, 0.1, 0.0, 5, 4, True)
+    assert list(pk._fused_conv_canary.values()) == [True]
+    # the kernel's answer (bf16 patch feed), not the reference's own
+    want = np.asarray(want)
+    err = np.abs(np.asarray(got) - want).max() / np.abs(want).max()
+    assert 0.0 < err < 5e-3, err
+
+    monkeypatch.setattr(ck, "_chain_canary", {})
+
+    @jax.jit
+    def traced(x):
+        assert ck._canary_ok("geo", lambda: ck.run_outside_trace(
+            lambda xc: ck.rectify_pool_vectorize_pallas(
+                xc, 0.1, 0.0, 4, 4, interpret=True),
+            np.zeros((3, 8, 8, 4), np.float32)))
+        return x + 1.0
+
+    traced(jnp.ones(3))
+    assert ck._chain_canary == {"geo": True}

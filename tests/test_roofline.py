@@ -213,7 +213,7 @@ def test_machine_rates_honest_on_cpu_backend():
     )
 
     pf, pb = machine_rates()
-    if cost_model._live_platform_no_init() == "cpu" and (
+    if cost_model.live_platform() == "cpu" and (
             float(cost_model.CPU_WEIGHT)
             == cost_model.ANALYTIC_CPU_WEIGHT):
         assert (pf, pb) == (CPU_PEAK_FLOPS, CPU_PEAK_BW)

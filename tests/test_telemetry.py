@@ -303,7 +303,7 @@ def test_producer_exception_still_records_metrics_and_raises():
     with overlap_override(True, prefetch_depth=2):
         with pytest.raises(RuntimeError, match="device fell over"):
             map_host_batched(items, exploding, chunk=4)
-    # gauges exist and the failure did not wedge accounting below zero
+    # gauges exist and the failure did not leave accounting below zero
     assert registry().gauge("prefetch.queue_depth").max >= 0
 
 

@@ -1,0 +1,239 @@
+"""What the v5e's compiler says of the main path's kernels, asked without
+the chip (the `on-chip-measurement` guide, section 2): each test lowers a
+kernel at the RandomPatchCifar widths for a DESCRIBED TPU v5e and compiles
+it, so a kernel that interpret mode accepts and Mosaic refuses (a block
+past the scoped-VMEM limit, a slice off the tiling, a kernel XLA is asked
+to partition) fails here and costs no chip time. Nothing runs: these say
+nothing about results or times.
+
+The topology is described inside a module-scoped fixture and nowhere
+else: only one process may hold the TPU's library, so the call must not
+happen while any worker merely imports this file. All such tests stay in
+this one file, so one worker gets them all.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from keystone_tpu.workflow.env import config_override
+
+# RandomPatchCifar at its defaults (RandomPatchCifarConfig): 32x32x3
+# images, 256 filters of 6x6, pool 14 stride 13, microbatch 2048.
+H = W = 32
+C = 3
+K = 256
+PATCH = 6
+POOL, STRIDE, ALPHA = 14, 13, 0.25
+POS = H - PATCH + 1  # 27x27 conv positions
+MICROBATCH = 2048
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def mesh4(topo):
+    return Mesh(np.asarray(topo.devices).reshape(4), ("data",))
+
+
+@pytest.fixture(autouse=True)
+def no_compile_cache():
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: the next run would warn
+    with config_override(compile_cache_dir=None):
+        yield
+
+
+def _compile(fn, *avals):
+    compiled = jax.jit(fn).lower(*avals).compile()
+    return compiled.as_text()
+
+
+def _aval(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _conv_avals(n, image_dtype, sharding):
+    return (
+        _aval((n, H, W, C), image_dtype, sharding),
+        _aval((C * PATCH * PATCH, K), jnp.float32, sharding),
+        _aval((K,), jnp.float32, sharding),
+        _aval((K,), jnp.float32, sharding),
+    )
+
+
+@pytest.mark.parametrize("n,image_dtype", [
+    (MICROBATCH, jnp.float32),
+    (MICROBATCH + 3, jnp.float32),  # ragged: a padded tail block
+    (MICROBATCH, jnp.bfloat16),     # the precision planner's boundary
+], ids=["f32", "ragged", "bf16"])
+def test_fused_conv_compiles_at_the_cifar_geometry(one_chip, n, image_dtype):
+    from keystone_tpu.ops import conv_rectify_pool_pallas
+
+    def fn(images, g, colsum, bias):
+        return conv_rectify_pool_pallas(
+            images, g, colsum, bias, ALPHA, 0.0, POOL, STRIDE, True, PATCH)
+
+    hlo = _compile(fn, *_conv_avals(n, image_dtype, one_chip))
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("block_n", [3, None],
+                         ids=["dispatcher_block", "default_block"])
+def test_rectify_pool_compiles_at_the_conv_output(one_chip, block_n):
+    """The standalone kernel at the block the dispatcher used to hand it
+    and at its own default. A fixed default of 8 was refused here:
+    RESOURCE_EXHAUSTED, 16.19M of scoped vmem against a 16.00M limit."""
+    from keystone_tpu.ops import rectify_pool_pallas
+    from keystone_tpu.ops.pallas_kernels import _rectify_pool_block
+
+    assert _rectify_pool_block(POS, POS, K) == 3
+
+    def fn(x):
+        return rectify_pool_pallas(
+            x, ALPHA, 0.0, POOL, STRIDE, block_n=block_n)
+
+    hlo = _compile(fn, _aval((MICROBATCH, POS, POS, K), jnp.float32, one_chip))
+    assert "tpu_custom_call" in hlo
+
+
+def test_rectify_pool_vectorize_compiles(one_chip):
+    from keystone_tpu.ops.chain_kernels import rectify_pool_vectorize_pallas
+
+    def fn(x):
+        return rectify_pool_vectorize_pallas(x, ALPHA, 0.0, POOL, STRIDE)
+
+    hlo = _compile(fn, _aval((MICROBATCH, POS, POS, K), jnp.float32, one_chip))
+    assert "tpu_custom_call" in hlo
+
+
+def test_rbf_block_compiles(one_chip):
+    """The KRR flagship's widths (bench.py: d=440) against one column
+    block."""
+    from keystone_tpu.ops import rbf_block_pallas
+
+    def fn(x, yb):
+        return rbf_block_pallas(x, yb, 0.01)
+
+    hlo = _compile(fn, _aval((8192, 440), jnp.float32, one_chip),
+                   _aval((2048, 440), jnp.float32, one_chip))
+    assert "tpu_custom_call" in hlo
+
+
+def test_elementwise_chain_compiles(one_chip):
+    """`scripts/kernel_live_check.py`'s elementwise geometry: the
+    LinearPixels trail PixelScaler >> GrayScaler >> ImageVectorizer on
+    32x32x3, at a ragged count."""
+    from keystone_tpu.nodes.images import (
+        GrayScaler,
+        ImageVectorizer,
+        PixelScaler,
+    )
+    from keystone_tpu.nodes.util.fusion import _peephole, _stage_fuse
+    from keystone_tpu.ops.chain_kernels import elementwise_chain_pallas
+
+    fused = [_stage_fuse(s) for s in _peephole(
+        [PixelScaler(), GrayScaler(), ImageVectorizer()])]
+    statics = tuple(f[0] for f in fused)
+    params = [f[1] for f in fused]
+
+    def fn(x):
+        return elementwise_chain_pallas(statics, params, x)
+
+    hlo = _compile(fn, _aval((MICROBATCH + 3, H, W, C), jnp.float32, one_chip))
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.fixture
+def kernels_as_on_the_chip(monkeypatch):
+    """The dispatchers ask `jax.default_backend()`, which is the CPU
+    here, and their canary runs the kernel: steer both from the test, so
+    the program that is lowered is the one the chip would build."""
+    import keystone_tpu.ops.pallas_kernels as pk
+
+    monkeypatch.setattr(pk, "use_fused_conv", lambda: True)
+    monkeypatch.setattr(pk, "_fused_conv_canary_ok", lambda *a: True)
+
+
+def test_run_fused_featurize_compiles_on_four_chips(
+        mesh4, kernels_as_on_the_chip):
+    """`run_fused`'s featurize step with the images sharded over `data`.
+    Called bare inside `lax.map` it was refused: "Mosaic kernels cannot
+    be automatically partitioned. Please wrap the call in a
+    shard_map." """
+    from keystone_tpu.pipelines.random_patch_cifar import (
+        RandomPatchCifarConfig,
+        _featurize_chunked,
+    )
+
+    config = RandomPatchCifarConfig()
+    rows = NamedSharding(mesh4, P("data"))
+    whole = NamedSharding(mesh4, P())
+
+    def fn(images, kern, colsum, bias):
+        return _featurize_chunked(images, kern, colsum, bias,
+                                  config=config, mesh=mesh4)
+
+    hlo = _compile(
+        fn,
+        _aval((4 * MICROBATCH + 512, H, W, C), jnp.float32, rows),
+        _aval((PATCH, PATCH, C, K), jnp.float32, whole),
+        _aval((K,), jnp.float32, whole),
+        _aval((K,), jnp.float32, whole),
+    )
+    assert "tpu_custom_call" in hlo
+
+
+def test_fused_operator_compiles_per_shard_on_four_chips(
+        mesh4, kernels_as_on_the_chip):
+    """The pipeline's own path: the fused featurizer operator
+    (Convolver >> SymmetricRectifier >> Pooler >> ImageVectorizer, which
+    the peephole turns into the fused conv stage) through its AOT warmup
+    on the described mesh, where `per_shard` runs inside `shard_map`."""
+    from keystone_tpu.nodes.images.core import (
+        Convolver,
+        ImageVectorizer,
+        Pooler,
+        SymmetricRectifier,
+    )
+    from keystone_tpu.nodes.util.fusion import (
+        _PROGRAM_CACHE,
+        FusedBatchTransformer,
+    )
+
+    filters = np.zeros((K, PATCH * PATCH * C), np.float32)
+    op = FusedBatchTransformer([
+        Convolver(filters, H, W, C, normalize_patches=True),
+        SymmetricRectifier(alpha=ALPHA),
+        Pooler(STRIDE, POOL, None, "sum"),
+        ImageVectorizer(),
+    ], microbatch=MICROBATCH)
+    before = set(_PROGRAM_CACHE)
+    try:
+        verdict = op.warmup(
+            jax.ShapeDtypeStruct((H, W, C), jnp.float32),
+            4 * MICROBATCH, mesh=mesh4)
+        assert verdict == "compiled"
+        (key,) = set(_PROGRAM_CACHE) - before
+        hlo = _PROGRAM_CACHE[key]._compiled.as_text()
+        assert "tpu_custom_call" in hlo
+    finally:
+        for key in set(_PROGRAM_CACHE) - before:
+            del _PROGRAM_CACHE[key]
