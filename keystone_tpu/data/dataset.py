@@ -85,11 +85,14 @@ def sync_pull(leaf) -> None:
     In a multi-process job a cross-host global array's element-0 slice is
     not addressable from every host, so np.asarray would raise; those
     leaves fall back to block_until_ready."""
+    from ..telemetry import span
+
     if hasattr(leaf, "ndim") and hasattr(leaf, "dtype") and leaf.ndim > 0:
-        if getattr(leaf, "is_fully_addressable", True):
-            np.asarray(leaf[(0,) * leaf.ndim])
-        else:
-            jax.block_until_ready(leaf)
+        with span("sync_pull", cat="sync", layer="sync"):
+            if getattr(leaf, "is_fully_addressable", True):
+                np.asarray(leaf[(0,) * leaf.ndim])
+            else:
+                jax.block_until_ready(leaf)
 
 
 class Dataset:
@@ -176,9 +179,22 @@ class Dataset:
             self.__dict__["_mask_cache"] = m
         return m
 
+    def mask_as(self, dtype):
+        """The validity mask as ``dtype``, for programs that multiply by
+        it. The conversion is a launched program of its own, so it is
+        counted and timed as one (`telemetry.dispatch`)."""
+        from ..telemetry import dispatch
+
+        with dispatch("mask.astype"):
+            return self.mask.astype(dtype)
+
     def numpy(self):
         """Unpadded host copy (≈ `collect`)."""
-        return jax.tree_util.tree_map(lambda x: np.asarray(x)[: self.count], self.data)
+        from ..telemetry import span
+
+        with span("Dataset.numpy", cat="sync", layer="sync"):
+            return jax.tree_util.tree_map(
+                lambda x: np.asarray(x)[: self.count], self.data)
 
     def __len__(self) -> int:
         return self.count
@@ -196,12 +212,13 @@ class Dataset:
         result keeps the leading axis and sharding. One call = one
         executed XLA program — THE library-wide jitted call boundary, so
         it feeds the ``dispatch.programs_executed`` budget."""
-        from ..telemetry import record_dispatch
+        from ..telemetry import dispatch, fn_label
 
+        label = fn_label(fn)
         if jitted:
             fn = jax.jit(fn)
-        record_dispatch()
-        out = fn(self.data)
+        with dispatch(label):
+            out = fn(self.data)
         return Dataset(out, count=count if count is not None else self.count,
                        mesh=self.mesh, _placed=True)
 
@@ -231,7 +248,10 @@ class Dataset:
         run, and a host round trip here would defeat async dispatch
         overlap at every cache boundary; timing paths (autocache
         profiling, calibration) must use `sync()` instead."""
-        jax.block_until_ready(self.data)
+        from ..telemetry import span
+
+        with span("Dataset.cache", cat="sync", layer="sync"):
+            jax.block_until_ready(self.data)
         return self
 
     def sync(self) -> "Dataset":
@@ -239,8 +259,11 @@ class Dataset:
         Honest wall-clock timing — autocache profiling, calibration —
         fences on a value that has arrived; a single-element device
         slice keeps the transfer tiny."""
-        for leaf in jax.tree_util.tree_leaves(self.data):
-            sync_pull(leaf)
+        from ..telemetry import span
+
+        with span("Dataset.sync", cat="sync", layer="sync"):
+            for leaf in jax.tree_util.tree_leaves(self.data):
+                sync_pull(leaf)
         return self
 
     def spread_take(self, m: int):

@@ -131,13 +131,16 @@ class MulticlassClassifierEvaluator:
         if isinstance(actuals, PipelineResult):
             actuals = actuals.get()
         if isinstance(predictions, Dataset) and isinstance(actuals, Dataset):
-            cm = _confusion(
-                predictions.array,
-                actuals.array,
-                predictions.mask.astype(jnp.float32),
-                self.num_classes,
-            )
-            return MulticlassMetrics(np.asarray(cm))
+            from ..telemetry import dispatch, span
+
+            mask = predictions.mask_as(jnp.float32)
+            with dispatch("_confusion"):
+                cm = _confusion(predictions.array, actuals.array, mask,
+                                self.num_classes)
+            # the pull that ends a fit: the host waits here for every
+            # program still queued behind the predictions
+            with span("confusion_pull", cat="sync", layer="sync"):
+                return MulticlassMetrics(np.asarray(cm))
 
         def to_host(x):
             if isinstance(x, Dataset):

@@ -41,6 +41,29 @@ def _convolve(images, kernel, colsum, bias, normalize: bool):
     return folded_conv_reference(images, kernel, colsum, bias, normalize)
 
 
+@partial(jax.jit, static_argnames=("patch", "channels"))
+@jax.named_scope("ks.Convolver.fold")
+def _fold_filters(filters, whitener, means, patch: int, channels: int):
+    """The folded conv kernel (HWIO), its column sums and its bias from
+    a filter bank (K, D) or (K, patch, patch, C) and an optional ZCA
+    whitener: one program for every Convolver of a shape. HIGHEST
+    precision: the fold feeds every downstream conv, and bf16
+    default-precision folding would corrupt the whitened kernel."""
+    filters = jnp.asarray(filters, jnp.float32)
+    K = filters.shape[0]
+    F = filters.reshape(K, patch * patch * channels).T  # (D, K)
+    if whitener is not None:
+        G = jnp.matmul(jnp.asarray(whitener, jnp.float32), F,
+                       precision=lax.Precision.HIGHEST)  # (D, K)
+        bias = -jnp.matmul(jnp.asarray(means, jnp.float32), G,
+                           precision=lax.Precision.HIGHEST)
+    else:
+        G = F
+        bias = jnp.zeros(K, jnp.float32)
+    kernel = G.T.reshape(K, patch, patch, channels).transpose(1, 2, 3, 0)
+    return kernel, G.sum(axis=0), bias
+
+
 class Convolver(Transformer):
     """Valid-mode convolution of a filter bank over image batches
     (Convolver.scala:20-221), with optional folded patch-mean
@@ -65,42 +88,27 @@ class Convolver(Transformer):
         normalize_patches: bool = True,
         patch_size: Optional[int] = None,
     ):
-        # All folding math in jnp: when filters/whitener live on device
-        # (the fused filter-learning program returns device arrays), the
-        # fold is an async device dispatch — no blocking host round trip
-        # per Convolver construction. HIGHEST precision: the fold feeds
-        # every downstream conv; bf16 default-precision folding would
-        # corrupt the whitened kernel.
-        filters = jnp.asarray(filters, jnp.float32)
-        if filters.ndim == 2:
-            if patch_size is None:
-                patch_size = int(round((filters.shape[1] / img_channels) ** 0.5))
-            filters = filters.reshape(-1, patch_size, patch_size, img_channels)
-        self.patch = filters.shape[1]
-        self.num_filters = filters.shape[0]
+        # The fold is one jitted program (`_fold_filters`): when
+        # filters/whitener live on device (the filter-learning program
+        # returns device arrays) it is an async dispatch, with no
+        # blocking host round trip per Convolver construction.
+        shape = np.shape(filters)
+        if len(shape) == 2 and patch_size is None:
+            patch_size = int(round((shape[1] / img_channels) ** 0.5))
+        self.patch = patch_size if len(shape) == 2 else shape[1]
+        self.num_filters = shape[0]
         self.img_shape = (img_height, img_width, img_channels)
         self.whitener = whitener
         self.normalize_patches = normalize_patches
 
-        D = self.patch * self.patch * img_channels
-        F = filters.reshape(self.num_filters, D).T  # (D, K)
-        if whitener is not None:
-            G = jnp.matmul(
-                jnp.asarray(whitener.whitener, jnp.float32), F,
-                precision=lax.Precision.HIGHEST,
-            )  # (D, K)
-            zca_mean = jnp.asarray(whitener.means, jnp.float32)  # (D,)
-            bias = -jnp.matmul(zca_mean, G, precision=lax.Precision.HIGHEST)
-        else:
-            G = F
-            bias = jnp.zeros(self.num_filters, jnp.float32)
-        # folded conv kernel, HWIO
-        self.kernel = (
-            G.T.reshape(self.num_filters, self.patch, self.patch, img_channels)
-            .transpose(1, 2, 3, 0)
-        )
-        self.colsum = G.sum(axis=0)  # (K,)
-        self.bias = bias
+        from ...telemetry import dispatch
+
+        with dispatch("Convolver.fold"):
+            self.kernel, self.colsum, self.bias = _fold_filters(
+                filters,
+                None if whitener is None else whitener.whitener,
+                None if whitener is None else whitener.means,
+                patch=self.patch, channels=img_channels)
 
     def apply(self, image):
         return _convolve(
@@ -342,20 +350,18 @@ class Windower(Transformer):
         return flat.reshape(-1, self.window_size, self.window_size, image.shape[-1])
 
     def apply_batch(self, data: Dataset):
-        from ...telemetry import record_dispatch
+        from ...telemetry import dispatch
         from ...utils.images import extract_patches_device
 
-        record_dispatch()
         h, w = data.array.shape[1], data.array.shape[2]
         gy = (h - self.window_size) // self.stride + 1
         gx = (w - self.window_size) // self.stride + 1
+        with dispatch(self.label):
+            patches = extract_patches_device(
+                data.array, self.window_size, self.stride)
         # padding rows' windows land at the tail (image-major order), so
         # an explicit count keeps exactly the valid windows
-        return Dataset(
-            extract_patches_device(data.array, self.window_size, self.stride),
-            count=data.count * gy * gx,
-            mesh=data.mesh,
-        )
+        return Dataset(patches, count=data.count * gy * gx, mesh=data.mesh)
 
 
 class RandomPatcher(Transformer):
@@ -383,10 +389,10 @@ class RandomPatcher(Transformer):
         col0 = jnp.asarray(xs.reshape(-1))
         rows = row0[:, None, None] + jnp.arange(self.patch_h)[None, :, None]
         cols = col0[:, None, None] + jnp.arange(self.patch_w)[None, None, :]
-        from ...telemetry import record_dispatch
+        from ...telemetry import dispatch
 
-        record_dispatch()
-        out = data.array[img_idx[:, None, None], rows, cols, :]     # one gather
+        with dispatch(self.label):
+            out = data.array[img_idx[:, None, None], rows, cols, :]  # one gather
         return Dataset(out, count=n * ppi, mesh=data.mesh)
 
     def apply(self, image):
@@ -426,17 +432,18 @@ class CenterCornerPatcher(Transformer):
 
     def apply_batch(self, data: Dataset):
         # five static slices (+flips) on device, image-major output order
-        from ...telemetry import record_dispatch
+        from ...telemetry import dispatch
 
-        record_dispatch()
         imgs = data.array
         ph, pw = self.patch_h, self.patch_w
         starts = self._starts(imgs.shape[1], imgs.shape[2])
-        crops = [imgs[:, y : y + ph, x : x + pw] for y, x in starts]
-        if self.with_flips:
-            crops += [c[:, :, ::-1] for c in crops]
-        k = len(crops)
-        out = jnp.stack(crops, axis=1).reshape(-1, ph, pw, imgs.shape[-1])
+        with dispatch(self.label):
+            crops = [imgs[:, y : y + ph, x : x + pw] for y, x in starts]
+            if self.with_flips:
+                crops += [c[:, :, ::-1] for c in crops]
+            k = len(crops)
+            out = jnp.stack(crops, axis=1).reshape(
+                -1, ph, pw, imgs.shape[-1])
         return Dataset(out, count=data.count * k, mesh=data.mesh)
 
 

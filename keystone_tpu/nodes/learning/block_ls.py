@@ -117,17 +117,18 @@ def _bcd_prepare(X, Y, mask, block_size: int, num_blocks: int, center: bool,
         d_pad = X.shape[1]
         k = Y.shape[1]
         dtype = X.dtype
-        count = jnp.sum(mask)
-        if center:
-            xm = jnp.sum(X, axis=0) / count
-            ym = jnp.sum(Y, axis=0) / count
-            Xc = (X - xm) * mask[:, None]
-            Yc = (Y - ym) * mask[:, None]
-        else:
-            xm = jnp.zeros((d_pad,), dtype)
-            ym = jnp.zeros((k,), dtype)
-            Xc = X * mask[:, None]
-            Yc = Y * mask[:, None]
+        with jax.named_scope("ks.bcd.centre"):
+            count = jnp.sum(mask)
+            if center:
+                xm = jnp.sum(X, axis=0) / count
+                ym = jnp.sum(Y, axis=0) / count
+                Xc = (X - xm) * mask[:, None]
+                Yc = (Y - ym) * mask[:, None]
+            else:
+                xm = jnp.zeros((d_pad,), dtype)
+                ym = jnp.zeros((k,), dtype)
+                Xc = X * mask[:, None]
+                Yc = Y * mask[:, None]
         if x_sharding is not None:
             Xc = jax.lax.with_sharding_constraint(Xc, x_sharding)
         W0 = jnp.zeros((num_blocks, block_size, k), dtype)
@@ -154,11 +155,15 @@ def _bcd_epoch(W, R, Xc, lam, block_size: int, num_blocks: int):
             Xb = jax.lax.dynamic_slice_in_dim(
                 Xc, b_idx * block_size, block_size, axis=1)
             Wb = W[b_idx]
-            R1 = R + Xb @ Wb
-            G = Xb.T @ Xb + eye          # all-reduce over the data axis
-            C = Xb.T @ R1                # all-reduce over the data axis
-            Wb_new = jax.scipy.linalg.solve(G, C, assume_a="pos")
-            R2 = R1 - Xb @ Wb_new
+            with jax.named_scope("ks.bcd.residual"):
+                R1 = R + Xb @ Wb
+            with jax.named_scope("ks.bcd.gram"):
+                G = Xb.T @ Xb + eye      # all-reduce over the data axis
+                C = Xb.T @ R1            # all-reduce over the data axis
+            with jax.named_scope("ks.bcd.solve"):
+                Wb_new = jax.scipy.linalg.solve(G, C, assume_a="pos")
+            with jax.named_scope("ks.bcd.residual"):
+                R2 = R1 - Xb @ Wb_new
             return (W.at[b_idx].set(Wb_new), R2), None
 
         (W, R), _ = jax.lax.scan(block_step, (W, R), jnp.arange(num_blocks))
@@ -167,7 +172,8 @@ def _bcd_epoch(W, R, Xc, lam, block_size: int, num_blocks: int):
 
 @jax.jit
 def _bcd_finalize(W, xm, ym):
-    with jax.default_matmul_precision("highest"):
+    with jax.default_matmul_precision("highest"), \
+            jax.named_scope("ks.bcd.intercept"):
         W_full = W.reshape(-1, ym.shape[0])
         return W_full, ym - xm @ W_full
 
@@ -325,13 +331,13 @@ class BlockLeastSquaresEstimator(LabelEstimator):
     def fit(self, data: Dataset, labels: Dataset) -> BlockLinearMapper:
         from ...parallel import mesh as meshlib
 
+        from ...telemetry import counter, dispatch, span
+
         X, Y = data.array, labels.array
         d = X.shape[1]
         bs = min(self.block_size, d)
         num_blocks = -(-d // bs)
         d_pad = num_blocks * bs
-        if d_pad != d:
-            X = jnp.pad(X, [(0, 0), (0, d_pad - d)])
         # Donated-buffer epoch loop: prepare once, then each sweep
         # updates (W, R) IN PLACE via donate_argnums — no fresh
         # model/residual allocation per epoch, and the host loop's
@@ -339,27 +345,33 @@ class BlockLeastSquaresEstimator(LabelEstimator):
         # the caller pulls the model). `_bcd_fit`/_bcd_fit_impl (the
         # single-program scan form) remains the fused-pipeline path and
         # the numerics reference for these steps.
-        Xc, R, xm, ym, W = _bcd_prepare(
-            X,
-            Y,
-            data.mask.astype(X.dtype),
-            bs,
-            num_blocks,
-            self.fit_intercept,
-            x_sharding=meshlib.feature_sharding(data.mesh, d_pad),
-        )
-        lam = jnp.asarray(self.lam, X.dtype)
-        from ...telemetry import counter, record_dispatch, span
-
-        record_dispatch()  # _bcd_prepare
-        for i in range(self.num_iter):
-            # span measures the host-side dispatch of one donated-buffer
-            # sweep; device time pipelines asynchronously and lands on
-            # whoever pulls the model (see OBSERVABILITY.md)
-            with span("bcd_epoch", cat="step", iter=i, blocks=num_blocks):
-                W, R = _bcd_epoch(W, R, Xc, lam, bs, num_blocks)
-            counter("solver.steps").inc()
-            record_dispatch()
-        W, b = _bcd_finalize(W, xm, ym)
-        record_dispatch()  # _bcd_finalize
+        with span(self.label, cat="solver", layer="solver",
+                  blocks=num_blocks):
+            if d_pad != d:
+                with dispatch("pad"):  # `jnp.pad` is a program of its own
+                    X = jnp.pad(X, [(0, 0), (0, d_pad - d)])
+            mask = data.mask_as(X.dtype)
+            with dispatch("_bcd_prepare"):
+                Xc, R, xm, ym, W = _bcd_prepare(
+                    X,
+                    Y,
+                    mask,
+                    bs,
+                    num_blocks,
+                    self.fit_intercept,
+                    x_sharding=meshlib.feature_sharding(data.mesh, d_pad),
+                )
+            # a host scalar: `jnp.asarray` would launch a convert program
+            lam = np.asarray(self.lam, X.dtype)
+            for i in range(self.num_iter):
+                # the spans measure the host-side dispatch of one
+                # donated-buffer sweep; device time pipelines
+                # asynchronously and lands on whoever pulls the model
+                # (see OBSERVABILITY.md)
+                with span("bcd_epoch", cat="step", layer="solver", iter=i,
+                          blocks=num_blocks), dispatch("_bcd_epoch"):
+                    W, R = _bcd_epoch(W, R, Xc, lam, bs, num_blocks)
+                counter("solver.steps").inc()
+            with dispatch("_bcd_finalize"):
+                W, b = _bcd_finalize(W, xm, ym)
         return BlockLinearMapper(W, b if self.fit_intercept else None, self.block_size)

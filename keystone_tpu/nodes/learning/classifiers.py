@@ -81,7 +81,7 @@ class NaiveBayesEstimator(LabelEstimator):
             class_counts = onehot.sum(axis=0)
             feat_counts = onehot.T @ X
         else:
-            X, mask = data.array, data.mask.astype(jnp.float32)
+            X, mask = data.array, data.mask_as(jnp.float32)
             y = labels.array
             onehot = jax.nn.one_hot(y, self.num_classes) * mask[:, None]
             class_counts = jnp.sum(onehot, axis=0)
@@ -161,7 +161,7 @@ class LogisticRegressionEstimator(LabelEstimator):
         W = _logreg_fit(
             data.array,
             labels.array if isinstance(labels, Dataset) else jnp.asarray(labels),
-            data.mask.astype(data.array.dtype),
+            data.mask_as(data.array.dtype),
             jnp.float32(self.lam),
             self.num_classes,
             self.num_iters,

@@ -282,12 +282,18 @@ class KernelRidgeRegression(LabelEstimator):
         return os.path.join(self.checkpoint_dir, tag + ".npz")
 
     def fit(self, data: Dataset, labels: Dataset) -> KernelBlockLinearMapper:
+        from ...telemetry import span
+
+        with span(self.label, cat="solver", layer="solver"):
+            return self._fit(data, labels)
+
+    def _fit(self, data: Dataset, labels: Dataset) -> KernelBlockLinearMapper:
         import os
 
         X = data.array
         Y = labels.array * data.mask[:, None]
         n_pad = X.shape[0]
-        mask = data.mask.astype(X.dtype)
+        mask = data.mask_as(X.dtype)
         B = min(self.block_size, n_pad)
         # permutable blocks over VALID rows only; padded rows keep alpha=0
         n_blocks = -(-data.count // B)
@@ -303,7 +309,7 @@ class KernelRidgeRegression(LabelEstimator):
         lam = jnp.asarray(self.lam, X.dtype)
         gamma = float(self.gamma)
         done = 0
-        from ...telemetry import counter, record_dispatch, span
+        from ...telemetry import counter, dispatch, span
         for epoch in range(start_epoch, self.num_epochs):
             # per-epoch seed so a resumed run replays identical block orders
             perm = np.random.default_rng(self.seed + epoch).permutation(data.count)
@@ -312,13 +318,13 @@ class KernelRidgeRegression(LabelEstimator):
             first = start_block if epoch == start_epoch else 0
             for b in range(first, n_blocks):
                 block_ids = jnp.asarray(ids[b * B : (b + 1) * B], jnp.int32)
-                with span("krr_step", cat="step", epoch=epoch, block=b):
+                with span("krr_step", cat="step", layer="solver",
+                          epoch=epoch, block=b), dispatch("_krr_step"):
                     alpha, KA = _krr_step(
                         X, Y, mask, alpha, KA, lam, gamma, block_ids,
                         use_pal=_use_pallas_now(),
                     )
                 counter("solver.steps").inc()
-                record_dispatch()
                 done += 1
                 if ckpt and done % self.blocks_before_checkpoint == 0:
                     # atomic write: a crash mid-save must not corrupt the

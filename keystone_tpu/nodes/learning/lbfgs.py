@@ -170,6 +170,12 @@ class DenseLBFGSwithL2(LabelEstimator):
         return fit_sharding_demands(2)
 
     def fit(self, data: Dataset, labels: Dataset) -> LinearMapper:
+        from ...telemetry import span
+
+        with span(self.label, cat="solver", layer="solver"):
+            return self._fit(data, labels)
+
+    def _fit(self, data: Dataset, labels: Dataset) -> LinearMapper:
         from ...parallel import mesh as meshlib
 
         X, Y = data.array, labels.array
@@ -181,7 +187,7 @@ class DenseLBFGSwithL2(LabelEstimator):
         Xc, Yc, xm, ym = _lbfgs_prepare(
             X,
             Y,
-            data.mask.astype(X.dtype),
+            data.mask_as(X.dtype),
             jnp.asarray(data.count, X.dtype),
             self.fit_intercept,
             x_sharding=meshlib.feature_sharding(data.mesh, X.shape[1]),
@@ -189,14 +195,14 @@ class DenseLBFGSwithL2(LabelEstimator):
         lam = jnp.asarray(self.lam, X.dtype)
         W, state = _lbfgs_init(Xc, Yc, self.memory_size)
         values = []
-        from ...telemetry import counter, record_dispatch, span
+        from ...telemetry import counter, dispatch, span
 
         for i in range(self.num_iters):
-            with span("lbfgs_step", cat="step", iter=i):
+            with span("lbfgs_step", cat="step", layer="solver", iter=i), \
+                    dispatch("_lbfgs_step"):
                 W, state, value = _lbfgs_step(
                     W, state, Xc, Yc, lam, self.memory_size)
             counter("solver.steps").inc()
-            record_dispatch()
             if values:
                 # Wait for the step before this one, so one step runs
                 # while the next is queued and no more. XLA:CPU's
@@ -206,7 +212,8 @@ class DenseLBFGSwithL2(LabelEstimator):
                 # from a handful, on a loaded host), and XLA aborts the
                 # process 40 s later. A deeper queue buys nothing on any
                 # backend: the device already has its next step.
-                jax.block_until_ready(values[-1])  # keystone: ignore[KJ005]
+                with span("lbfgs_fence", cat="sync", layer="sync"):
+                    jax.block_until_ready(values[-1])  # keystone: ignore[KJ005]
             values.append(value)
         self.loss_history = jnp.stack(values) if values else jnp.zeros((0,))
         if not self.fit_intercept:

@@ -146,17 +146,19 @@ class LinearMapEstimator(LabelEstimator):
 
     def fit(self, data: Dataset, labels: Dataset) -> LinearMapper:
         from ...parallel import mesh as meshlib
-        from ...telemetry import record_dispatch
+        from ...telemetry import dispatch, span
 
-        record_dispatch()
-        W, b = _normal_equations(
-            data.array,
-            labels.array,
-            jnp.float32(data.count),
-            jnp.float32(self.lam),
-            self.fit_intercept,
-            x_sharding=meshlib.feature_sharding(data.mesh, data.array.shape[1]),
-        )
+        with span(self.label, cat="solver", layer="solver"), \
+                dispatch("_normal_equations"):
+            W, b = _normal_equations(
+                data.array,
+                labels.array,
+                jnp.float32(data.count),
+                jnp.float32(self.lam),
+                self.fit_intercept,
+                x_sharding=meshlib.feature_sharding(
+                    data.mesh, data.array.shape[1]),
+            )
         return LinearMapper(W, b if self.fit_intercept else None)
 
     @staticmethod
@@ -245,11 +247,11 @@ class LocalLeastSquaresEstimator(LabelEstimator):
         return supervised_fit_spec(in_specs, self.label)
 
     def fit(self, data: Dataset, labels: Dataset) -> LinearMapper:
-        from ...telemetry import record_dispatch
+        from ...telemetry import dispatch, span
 
-        record_dispatch()
-        W = _dual_solve(
-            data.array, labels.array, data.mask.astype(data.array.dtype),
-            jnp.float32(self.lam),
-        )
+        with span(self.label, cat="solver", layer="solver"):
+            mask = data.mask_as(data.array.dtype)
+            with dispatch("_dual_solve"):
+                W = _dual_solve(data.array, labels.array, mask,
+                                jnp.float32(self.lam))
         return LinearMapper(W)

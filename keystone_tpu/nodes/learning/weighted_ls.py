@@ -119,7 +119,7 @@ class BlockWeightedLeastSquaresEstimator(LabelEstimator):
         if num_blocks * bs != d:
             X = jnp.pad(X, [(0, 0), (0, num_blocks * bs - d)])
         W, b = _bwls_fit(
-            X, Y, data.mask.astype(X.dtype),
+            X, Y, data.mask_as(X.dtype),
             jnp.asarray(self.lam, X.dtype),
             jnp.asarray(self.mixture_weight, X.dtype),
             bs, num_blocks, self.num_iter,

@@ -77,12 +77,12 @@ class CosineRandomFeatures(Transformer):
     def apply_batch(self, data):
         if not isinstance(data, Dataset):
             return super().apply_batch(data)  # host chunks: per-item path
-        from ...telemetry import record_dispatch
+        from ...telemetry import dispatch
 
-        record_dispatch()
         # module-level jit: W/b are traced args, so rebuilding a pipeline
         # (fresh weights, same shapes) reuses the compiled program
-        return data.with_data(_cosine_rf(data.array, self.W, self.b))
+        with dispatch(self.label):
+            return data.with_data(_cosine_rf(data.array, self.W, self.b))
 
 
 class RandomSignNode(Transformer):

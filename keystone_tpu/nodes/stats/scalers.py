@@ -14,12 +14,14 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ...data.dataset import Dataset
 from ...workflow.pipeline import Estimator, Transformer
 
 
 @partial(jax.jit, static_argnames=("normalize_std",))
+@jax.named_scope("ks.StandardScaler.moments")
 def _moments(X, count, normalize_std: bool):
     s = jnp.sum(X, axis=0)
     s2 = jnp.sum(X * X, axis=0)
@@ -35,6 +37,7 @@ def _moments(X, count, normalize_std: bool):
 
 
 @jax.jit
+@jax.named_scope("ks.StandardScaler.scale")
 def _scale(X, mean, std, mask):
     return (X - mean) / std * mask[:, None]
 
@@ -78,10 +81,11 @@ class StandardScalerModel(Transformer):
         if not isinstance(data, Dataset):
             return super().apply_batch(data)  # host chunks: per-item path
         std = self.std if self.std is not None else jnp.ones_like(self.mean)
-        from ...telemetry import record_dispatch
+        from ...telemetry import dispatch
 
-        record_dispatch()
-        return data.with_data(_scale(data.array, self.mean, std, data.mask))
+        with dispatch(self.label):
+            return data.with_data(
+                _scale(data.array, self.mean, std, data.mask))
 
 
 class StandardScaler(Estimator):
@@ -119,10 +123,12 @@ class StandardScaler(Estimator):
         return TransformerSpec(elem_fn, label=self.label, chunkable=True)
 
     def fit(self, data: Dataset) -> StandardScalerModel:
-        from ...telemetry import record_dispatch
+        from ...telemetry import dispatch
 
-        record_dispatch()
-        mean, std = _moments(
-            data.array, jnp.float32(data.count), self.normalize_std_dev
-        )
+        with dispatch(self.label):
+            # a host scalar: `jnp.float32(...)` would launch a program
+            # of its own to convert it
+            mean, std = _moments(
+                data.array, np.float32(data.count), self.normalize_std_dev
+            )
         return StandardScalerModel(mean, std if self.normalize_std_dev else None)

@@ -80,10 +80,11 @@ class ClassLabelIndicatorsFromInt(Transformer):
     def apply_batch(self, data):
         if not isinstance(data, Dataset):
             return super().apply_batch(data)
-        from ...telemetry import record_dispatch
+        from ...telemetry import dispatch
 
-        record_dispatch()
-        return data.with_data(_int_indicators(data.array, data.mask, k=self.num_classes))
+        with dispatch(self.label):
+            return data.with_data(_int_indicators(
+                data.array, data.mask, k=self.num_classes))
 
 
 class ClassLabelIndicatorsFromIntArray(Transformer):
@@ -114,12 +115,11 @@ class ClassLabelIndicatorsFromIntArray(Transformer):
     def apply_batch(self, data):
         if not isinstance(data, Dataset):
             return super().apply_batch(data)
-        from ...telemetry import record_dispatch
+        from ...telemetry import dispatch
 
-        record_dispatch()
-        return data.with_data(
-            _int_array_indicators(data.array, data.mask, k=self.num_classes)
-        )
+        with dispatch(self.label):
+            return data.with_data(_int_array_indicators(
+                data.array, data.mask, k=self.num_classes))
 
 
 class MaxClassifier(Transformer):
@@ -147,10 +147,10 @@ class MaxClassifier(Transformer):
 
     def apply_batch(self, data):
         if isinstance(data, Dataset):
-            from ...telemetry import record_dispatch
+            from ...telemetry import dispatch
 
-            record_dispatch()
-            return data.with_data(_argmax_last(data.array))
+            with dispatch(self.label):
+                return data.with_data(_argmax_last(data.array))
         return super().apply_batch(data)
 
 
@@ -175,10 +175,10 @@ class VectorCombiner(Transformer):
 
     def apply_batch(self, data):
         if isinstance(data, Dataset) and isinstance(data.data, tuple):
-            from ...telemetry import record_dispatch
+            from ...telemetry import dispatch
 
-            record_dispatch()
-            return data.with_data(_concat_last(data.data))
+            with dispatch(self.label):
+                return data.with_data(_concat_last(data.data))
         return super().apply_batch(data)
 
 

@@ -26,6 +26,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ...data.dataset import Dataset
 from ...parallel import mesh as meshlib
+from ...telemetry import scope_name
 from ...workflow.pipeline import Transformer
 
 
@@ -221,7 +222,10 @@ def _stage_fuse(stage: Transformer):
         inner = fn
         fn = (lambda p, xb, mb, inner=inner: _mask_rows(inner(p, xb, mb), mb))
         key = (key, "masked")
-    return key, params, fn
+    # the stage's ops carry its label in their names, in the compiled
+    # program's metadata and so in a device trace. Metadata only: the
+    # jaxpr, the compiled code and the program cache keys are as without
+    return key, params, jax.named_scope(scope_name(stage.label))(fn)
 
 
 # (structure key) -> jitted program. Programs take (flat_params, xs) so
@@ -532,27 +536,27 @@ class FusedBatchTransformer(Transformer):
                 data.mesh, data.n_shards, data.padded_count,
                 treedef, fns, statics=statics)
             cache[key] = program
-        from ...telemetry import record_dispatch
+        from ...telemetry import counter, dispatch, span
 
-        record_dispatch()  # the whole chain is ONE executed program
         swap = self._kernel_swap(statics)
         if swap is not None:
             # the planned chain megakernel is live in this program:
             # span-visible so reconcile_roofline can join the planner's
             # predicted seconds against the observed wall span
-            from ...telemetry import counter, span
-
             start, stop, _ = swap
             with span("chain_kernel", cat="node", label=self.label,
                       family=self.planned_kernel[2], stages=stop - start,
                       rows=data.count,
                       predicted_seconds=self.planned_kernel_seconds,
                       statically_verified=(
-                          self.planned_kernel_statically_verified)):
+                          self.planned_kernel_statically_verified)), \
+                    dispatch(self.label, rows=data.count):
                 out = data.with_data(program(flat, data.array, data.mask))
             counter("pallas.chain_programs").inc()
             return out
-        return data.with_data(program(flat, data.array, data.mask))
+        # the whole chain is ONE executed program
+        with dispatch(self.label, rows=data.count):
+            return data.with_data(program(flat, data.array, data.mask))
 
     def warmup(self, element, count: int, mesh=None) -> Optional[str]:
         """AOT-compile this chain's batch program from a static spec —
@@ -594,8 +598,8 @@ class FusedBatchTransformer(Transformer):
             from ...data.dataset import leaf_sharding
             from ...telemetry import span
 
-            with span("aot_warmup", cat="compile", label=self.label,
-                      rows=padded):
+            with span("aot_warmup", cat="compile", layer="compile",
+                      label=self.label, rows=padded):
                 jitted = self._build_program(mesh, shards, padded,
                                              treedef, fns, statics=statics)
                 xs_aval = jax.ShapeDtypeStruct(
@@ -670,7 +674,9 @@ class FusedBatchTransformer(Transformer):
                     # [kstart, kstop) stays in VMEM, so the planner's
                     # intra-slice storage casts are subsumed — only the
                     # slice-end cast below still applies
-                    xb = kern_fn(tuple(params[kstart:kstop]), xb, mb)
+                    with jax.named_scope(scope_name(
+                            f"chain_kernel.{self.planned_kernel[2]}")):
+                        xb = kern_fn(tuple(params[kstart:kstop]), xb, mb)
                     i = kstop - 1
                 else:
                     xb = fns[i](params[i], xb, mb)

@@ -340,6 +340,7 @@ def _rectify_pool_vectorize_block(h, w, k, pool, stride) -> int:
     return chain_block_rows(io_bytes, inter, param_bytes, ladder=ladder)
 
 
+@jax.named_scope("ks.rectify_pool_vectorize_pallas")
 def rectify_pool_vectorize_pallas(
     x, alpha, max_val, pool, stride, *, block_n=None, interpret=False,
 ):
@@ -374,6 +375,7 @@ def rectify_pool_vectorize_pallas(
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((n_pad, gy, gx, 2 * k), x.dtype),
         interpret=interpret,
+        name="ks_rectify_pool_vectorize",
     )(x)
     return out[:n].reshape(n, gy * gx * 2 * k)
 
@@ -437,6 +439,7 @@ def _run_bodies(bodies, ops, x, mask):
     return x
 
 
+@jax.named_scope("ks.elementwise_chain")
 def elementwise_chain_reference(statics, params, x, mask=None):
     """Pure-jnp oracle: the SAME stage bodies the kernel traces,
     applied outside Pallas. ``params``: one pytree per stage (the
@@ -501,6 +504,7 @@ def _elementwise_geometry(bodies, ops, x) -> int:
     return chain_block_rows(io_bytes, inter, param_bytes, ladder=ladder)
 
 
+@jax.named_scope("ks.elementwise_chain_pallas")
 def elementwise_chain_pallas(
     statics, params, x, mask=None, *, block_n=None, interpret=False,
 ):
@@ -587,6 +591,7 @@ def elementwise_chain_pallas(
         out_shape=jax.ShapeDtypeStruct((n_pad,) + streamed(out_item),
                                        out_aval.dtype),
         interpret=interpret,
+        name="ks_elementwise_chain",
     )(*operands)
     return out.reshape((n_pad,) + out_item)[:n]
 

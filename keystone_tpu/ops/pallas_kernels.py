@@ -97,16 +97,19 @@ def use_rectify_pallas() -> bool:
 def rectify_pool_reference(x, alpha, max_val, pool: int, stride: int):
     """XLA path: SymmetricRectifier >> Pooler(sum) exactly as the
     unfused stages compute it. x: (N, H, W, K) → (N, GY, GX, 2K)."""
-    cat = jnp.concatenate(
-        [jnp.maximum(max_val, x - alpha), jnp.maximum(max_val, -x - alpha)],
-        axis=-1,
-    )
-    return lax.reduce_window(
-        cat, 0.0, lax.add,
-        window_dimensions=(1, pool, pool, 1),
-        window_strides=(1, stride, stride, 1),
-        padding="VALID",
-    )
+    with jax.named_scope("ks.rectify"):
+        cat = jnp.concatenate(
+            [jnp.maximum(max_val, x - alpha),
+             jnp.maximum(max_val, -x - alpha)],
+            axis=-1,
+        )
+    with jax.named_scope("ks.pool"):
+        return lax.reduce_window(
+            cat, 0.0, lax.add,
+            window_dimensions=(1, pool, pool, 1),
+            window_strides=(1, stride, stride, 1),
+            padding="VALID",
+        )
 
 
 def _rectify_pool_kernel(x_ref, o_ref, *, alpha, max_val, pool, stride, gy, gx, k):
@@ -138,6 +141,7 @@ def _rectify_pool_block(h: int, w: int, k: int) -> int:
     return max(1, min(8, (3 << 20) // max(per_img, 1)))  # keystone: ignore[KJ017]
 
 
+@jax.named_scope("ks.rectify_pool_pallas")
 def rectify_pool_pallas(
     x, alpha: float, max_val: float, pool: int, stride: int,
     *, block_n: "int | None" = None, interpret: bool = False,
@@ -164,6 +168,7 @@ def rectify_pool_pallas(
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((n_pad, gy, gx, 2 * k), x.dtype),
         interpret=interpret,
+        name="ks_rectify_pool",
     )(x)
     return out[:n]
 
@@ -180,6 +185,7 @@ def rectify_pool(x, alpha: float, max_val: float, pool: int, stride: int):
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("ks.rbf_block")
 def rbf_block_reference(X, Yb, gamma):
     """XLA path — the dot-product trick at full f32 precision."""
     with jax.default_matmul_precision("highest"):
@@ -209,6 +215,7 @@ def _rbf_kernel(x_ref, y_ref, x2_ref, y2_ref, o_ref, acc_ref, *, gamma, k_steps)
         o_ref[:] = jnp.exp(-gamma * jnp.maximum(d2, 0.0)).astype(o_ref.dtype)
 
 
+@jax.named_scope("ks.rbf_block_pallas")
 def rbf_block_pallas(
     X, Yb, gamma, *, bm: int = 512, bn: int = 512, bk: int = 512,
     interpret: bool = False,
@@ -242,6 +249,7 @@ def rbf_block_pallas(
         out_shape=jax.ShapeDtypeStruct((mp, np_), X.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
+        name="ks_rbf_block",
     )(Xp, Yp, x2p, y2p)
     return out[:m, :n]
 
@@ -292,6 +300,7 @@ class FusedConvIneligibleError(ValueError):
     """The fused conv kernel's block geometry cannot fit VMEM."""
 
 
+@jax.named_scope("ks.conv")
 def folded_conv_reference(images, kernel_hwio, colsum, bias, normalize: bool):
     """The folded conv: filter bank with ZCA pre-applied, patch-mean
     subtraction as a rank-1 correction via a uniform conv, plus bias.
@@ -596,6 +605,7 @@ def _fused_conv_block_images(posp: int, dp: int, k: int, cells: int) -> int:
     return _fused_conv_geometry(posp, dp, k, cells)[0]
 
 
+@jax.named_scope("ks.conv_rectify_pool_pallas")
 def conv_rectify_pool_pallas(
     images, G_cmajor, colsum, bias, alpha, max_val,
     pool: int, stride: int, normalize: bool, patch: int,
@@ -661,6 +671,7 @@ def conv_rectify_pool_pallas(
         out_shape=jax.ShapeDtypeStruct((grid * b * r_img, 2 * k),
                                        jnp.float32),
         interpret=interpret,
+        name="ks_conv_rectify_pool",
     )(pat, Gp, pmat, cs, bs)
     # tight grouping: r_img == cells and the slice below is a no-op
     return (out.reshape(n_pad, r_img, 2 * k)[:n, :cells]

@@ -23,6 +23,7 @@ import time
 from dataclasses import dataclass
 from typing import Optional
 
+import jax
 import numpy as np
 
 from ..data.dataset import Dataset
@@ -103,6 +104,7 @@ class RandomPatchCifarConfig:
     synth_test: int = 500
 
 
+@jax.named_scope("ks.learn_filters")
 def _learn_filters_device(images, key, eps, patch: int, step: int,
                           n_valid: int, n_sample: int, m: int,
                           num_filters: int):
@@ -174,23 +176,38 @@ def _learn_filters_device(images, key, eps, patch: int, step: int,
     return jnp.concatenate([filters.ravel(), W.ravel(), mu])
 
 
-_learn_filters_device_jit = None
+def _learn_filters_parts(images, seed, eps, patch: int, step: int,
+                         n_valid: int, n_sample: int, m: int,
+                         num_filters: int):
+    """`_learn_filters_device` as `learn_filters` launches it: the PRNG
+    key made from ``seed`` and the packed result taken apart inside the
+    program, so filter learning is one launch and not nine (the key's
+    two, this one, and six slices and reshapes: the device trace of
+    PR 25)."""
+    import jax.numpy as jnp
+
+    packed = _learn_filters_device(
+        images, jax.random.PRNGKey(seed), eps, patch, step, n_valid,
+        n_sample, m, num_filters)
+    D = patch * patch * images.shape[-1]
+    K = num_filters
+    return (packed[: K * D].reshape(K, D),
+            packed[K * D : K * D + D * D].reshape(D, D),
+            packed[K * D + D * D :])
+
+
+_learn_filters_jit = jax.jit(
+    _learn_filters_parts,
+    static_argnames=("patch", "step", "n_valid", "n_sample", "m",
+                     "num_filters"))
 
 
 def learn_filters(train_data: Dataset, config) -> tuple:
     """Whitened random-patch filter learning (reference :45-57), fully
-    on-device — only the packed (filters, whitener, means) result crosses
-    the device boundary."""
-    global _learn_filters_device_jit
-    import jax
-    import jax.numpy as jnp
-
-    if _learn_filters_device_jit is None:
-        _learn_filters_device_jit = jax.jit(
-            _learn_filters_device,
-            static_argnames=("patch", "step", "n_valid", "n_sample", "m",
-                             "num_filters"),
-        )
+    on-device: one program, whose three results stay on the device (the
+    Convolver folds the whitener into its kernel there too), so pipeline
+    construction never blocks on a host round trip."""
+    from ..telemetry import dispatch
 
     n = train_data.count
     n_sample = min(n, max(config.sample_patches // 100, 64))
@@ -200,22 +217,15 @@ def learn_filters(train_data: Dataset, config) -> tuple:
     total = n_sample * gy * gx
     m = min(total, config.sample_patches)
 
-    # only the 8-byte PRNG key crosses host->device: the index draws
-    # happen inside the program
-    packed = _learn_filters_device_jit(
-        train_data.array, jax.random.PRNGKey(config.seed),
-        jnp.float32(0.1),
-        patch=config.patch_size, step=config.patch_steps,
-        n_valid=n, n_sample=n_sample, m=m, num_filters=config.num_filters,
-    )
-    # stay on device: slicing the packed result is an async dispatch, so
-    # pipeline construction never blocks on a host round trip (the
-    # Convolver folds the whitener into its kernel in jnp too)
-    D = config.patch_size * config.patch_size * c
-    K = config.num_filters
-    filters = packed[: K * D].reshape(K, D)
-    W = packed[K * D : K * D + D * D].reshape(D, D)
-    mu = packed[K * D + D * D :]
+    # only the seed crosses host->device: the key and the index draws
+    # are made inside the program
+    with dispatch("_learn_filters_parts"):
+        filters, W, mu = _learn_filters_jit(
+            train_data.array, np.int32(config.seed), np.float32(0.1),
+            patch=config.patch_size, step=config.patch_steps,
+            n_valid=n, n_sample=n_sample, m=m,
+            num_filters=config.num_filters,
+        )
     return filters, ZCAWhitener(W, mu)
 
 

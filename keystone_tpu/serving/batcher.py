@@ -37,6 +37,7 @@ import numpy as np
 
 from ..telemetry.flight import flight_snapshot
 from ..telemetry.metrics import counter, gauge, histogram
+from ..telemetry.spans import span
 from ..workflow.env import execution_config
 
 
@@ -47,16 +48,20 @@ class ShedError(RuntimeError):
 
 
 class _Pending:
-    """One in-flight request: the validated ingress row, and an event
-    the dispatcher fires once the per-row result (or error) lands."""
+    """One in-flight request: the validated ingress row, an event the
+    dispatcher fires once the per-row result (or error) lands, and the
+    batcher's clock at `submit` and when the dispatcher thread took the
+    request off the queue (its two waits end at the dispatch)."""
 
-    __slots__ = ("row", "done", "result", "error")
+    __slots__ = ("row", "done", "result", "error", "t_submit", "t_taken")
 
-    def __init__(self, row: np.ndarray):
+    def __init__(self, row: np.ndarray, t_submit: float):
         self.row = row
         self.done = threading.Event()
         self.result: Optional[np.ndarray] = None
         self.error: Optional[BaseException] = None
+        self.t_submit = t_submit
+        self.t_taken = t_submit
 
 
 class MicroBatcher:
@@ -65,9 +70,11 @@ class MicroBatcher:
     batch to an ``(n, ...)`` result)."""
 
     def __init__(self, apply_fn: Callable[[np.ndarray], np.ndarray], *,
-                 max_batch: int, name: str = "serving"):
+                 max_batch: int, name: str = "serving",
+                 clock: Callable[[], float] = time.perf_counter):
         cfg = execution_config()
         self.apply_fn = apply_fn
+        self._clock = clock  # a test hands in a fake
         self.max_batch = max(1, int(max_batch))
         self.coalesce = bool(cfg.serving_coalesce)
         self.window_s = float(cfg.serving_window_ms) / 1e3
@@ -80,6 +87,12 @@ class MicroBatcher:
         self._depth_gauge = gauge("serving.queue_depth")
         self._coalesced = histogram("serving.coalesced_batch")
         self._dispatched = counter("serving.dispatches")
+        # a request's two waits, summed over requests: submit to the
+        # dispatcher taking it off the queue, and from there to the call
+        # of `apply_fn` on its batch (the coalescing window)
+        self._rows = counter("serving.rows_dispatched")
+        self._queue_wait = counter("serving.queue_wait_seconds")
+        self._coalesce_wait = counter("serving.coalesce_wait_seconds")
         self._lock = threading.Lock()
         self._thread: Optional[threading.Thread] = None
         self._stopping = False
@@ -117,11 +130,13 @@ class MicroBatcher:
         if not self.coalesce or self._thread is None:
             # kill-switch path: per-request dispatch on the caller's
             # thread — identical to direct FittedPipeline.apply
-            out = self.apply_fn(row[np.newaxis, ...])
+            with span("dispatch", cat="serve", layer="serve", rows=1):
+                out = self.apply_fn(row[np.newaxis, ...])
             self._dispatched.inc()
+            self._rows.inc()
             self._coalesced.observe(1)
             return np.asarray(out)[0]
-        pending = _Pending(row)
+        pending = _Pending(row, self._clock())
         try:
             self._queue.put_nowait(pending)
         except queue.Full:
@@ -146,6 +161,7 @@ class MicroBatcher:
         batch: List[Optional[_Pending]] = [first]
         if first is None:
             return batch
+        first.t_taken = self._clock()
         deadline = time.monotonic() + self.window_s
         while len(batch) < self.max_batch:
             remaining = deadline - time.monotonic()
@@ -159,6 +175,7 @@ class MicroBatcher:
             batch.append(item)
             if item is None:
                 break
+            item.t_taken = self._clock()
         return batch
 
     def _run(self) -> None:
@@ -176,8 +193,14 @@ class MicroBatcher:
         stacked = np.stack([p.row for p in requests])
         self._coalesced.observe(len(requests))
         self._dispatched.inc()
+        self._rows.inc(len(requests))
+        now = self._clock()
+        self._queue_wait.inc(sum(p.t_taken - p.t_submit for p in requests))
+        self._coalesce_wait.inc(sum(now - p.t_taken for p in requests))
         try:
-            out = np.asarray(self.apply_fn(stacked))
+            with span("dispatch", cat="serve", layer="serve",
+                      rows=len(requests)):
+                out = np.asarray(self.apply_fn(stacked))
             if out.shape[0] < len(requests):
                 raise RuntimeError(
                     f"apply returned {out.shape[0]} rows for a batch of "

@@ -1,11 +1,15 @@
-"""Unified runtime telemetry: hierarchical spans, a process-wide metrics
-registry, Chrome trace-event export, and the instrumentation hooks the
-executor / overlap engine / solver loops report through.
+"""Unified runtime telemetry: one span primitive (`span`, and `dispatch`
+around program calls) that feeds the profiler's trace, an always-on
+clock per layer and the host tracer; a process-wide metrics registry;
+Chrome trace-event export; a reader for `jax.profiler` traces
+(`python -m keystone_tpu.telemetry device <dir>`).
 
 Span hierarchy (structural, via per-thread stacks):
 
-    pipeline run → optimizer phase → node force → stream chunk
-                                                → solver iteration
+    pipeline run → optimizer phase → node force → program dispatch
+                                                → blocking pull (sync)
+                                                → stream chunk
+                                                → solver fit → iteration
 
 Quick start:
 
@@ -35,11 +39,13 @@ from .metrics import (
 )
 from . import ledger
 from .spans import (
+    LAYERS,
     SpanRecord,
     Tracer,
     capabilities,
     current_tracer,
     record_capability,
+    scope_name,
     set_tracer,
     span,
     telemetry_active,
@@ -56,7 +62,13 @@ from .export import (
     to_chrome_trace,
     write_trace,
 )
-from .instrument import estimate_bytes, instrument_node_force, record_dispatch
+from .instrument import (
+    dispatch,
+    estimate_bytes,
+    fn_label,
+    instrument_node_force,
+    record_dispatch,
+)
 from .compile_events import compiles_snapshot, install_compile_listeners
 from .flight import (
     FlightRecorder,
@@ -78,20 +90,21 @@ from .watchdog import (
 # passive (they fire only inside jax's own compile path), and installing
 # here means no compile anywhere in the process escapes
 # `dispatch.programs_compiled` — the same always-on discipline as
-# `record_dispatch`.
+# `dispatch`.
 install_compile_listeners()
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsDelta", "MetricsRegistry",
     "counter", "gauge", "histogram", "ledger", "metrics_delta",
     "registry",
-    "SpanRecord", "Tracer", "capabilities", "current_tracer",
-    "record_capability", "set_tracer", "span", "telemetry_active",
-    "trace_run",
+    "LAYERS", "SpanRecord", "Tracer", "capabilities", "current_tracer",
+    "record_capability", "scope_name", "set_tracer", "span",
+    "telemetry_active", "trace_run",
     "aggregate_spans", "compile_summary", "dispatch_plan_breakdown",
     "dispatch_summary", "load_trace", "self_times",
     "summarize", "to_chrome_trace", "write_trace",
-    "estimate_bytes", "instrument_node_force", "record_dispatch",
+    "dispatch", "estimate_bytes", "fn_label", "instrument_node_force",
+    "record_dispatch",
     "compiles_snapshot", "install_compile_listeners",
     "FlightRecorder", "ensure_flight", "flight_recorder",
     "flight_snapshot", "reset_flight",

@@ -6,6 +6,7 @@
     python -m keystone_tpu.telemetry --diff <run_a> <run_b> [--json]
     python -m keystone_tpu.telemetry --flight <dump> [--top N] [--json]
     python -m keystone_tpu.telemetry --live [--json]
+    python -m keystone_tpu.telemetry device <trace dir or .xplane.pb>
 
 The trace form prints the span digest (top nodes by self-time, solver
 iteration and stream-chunk totals), overlap queue-stall totals, bytes
@@ -42,6 +43,11 @@ percentiles from the streaming sketches, throughput, in-flight depth,
 conformance check/breach counters, and the armed watchdog's
 certificate digest. (Meaningful in-process — e.g. from a serving
 wrapper's debug hook; a fresh CLI process reports an empty table.)
+
+``device`` reads a `jax.profiler` trace (not a Chrome trace of the host
+tracer) by the program's own names: ``ks:`` spans with the device time
+under each, device seconds per ``ks.`` scope, and the longest idle gaps
+named by the span open in them (`telemetry.device`).
 
 ``--diff`` is run-over-run regression detection between two runs'
 ledgers: config kill-switch flips are named by env var (an injected
@@ -217,6 +223,11 @@ def _live_main(as_json: bool) -> int:
 
 
 def main(argv=None) -> int:
+    argv = sys.argv[1:] if argv is None else list(argv)
+    if argv[:1] == ["device"]:
+        from .device import main as device_main
+
+        return device_main(argv[1:])
     p = argparse.ArgumentParser(
         prog="python -m keystone_tpu.telemetry",
         description=__doc__.splitlines()[0],

@@ -22,8 +22,11 @@ counted):
                                compiles — the win the persistent cache
                                and AOT warmup buy).
 
-With a tracer active every compile additionally records a closed
-``cat="compile"`` span (``cold``/``warm`` in args), so traces show
+Every compile's seconds also go to the ``compile`` layer's clock
+(``host.compile.seconds``, `spans.record_layer_complete`), out of the
+self time of the dispatch span the compile ran inside. With a tracer
+active every compile additionally records a closed ``cat="compile"``
+span (``cold``/``warm`` in args), so traces show
 exactly WHERE compile time lands — including the AOT warmup pool's
 background compiles, which appear on their own thread lane.
 
@@ -42,7 +45,7 @@ from __future__ import annotations
 import threading
 
 from .metrics import counter, histogram
-from .spans import current_tracer
+from .spans import current_tracer, record_layer_complete
 
 #: duration-event suffix jax records around every backend compile
 #: (cache hit or miss) — jax 0.4.x name: /jax/core/compile/...
@@ -78,6 +81,10 @@ def _on_duration(event: str, duration: float, **kwargs) -> None:
             # pod-level compile accounting carries a per-process axis
             counter(f"dispatch.programs_compiled.{dim}").inc()
         histogram("compile.cold_secs").observe(duration)
+    # the event arrives once the compile (or the cache load) is over, so
+    # it is counted as a closed span of the `compile` layer and leaves
+    # the self time of the dispatch or warmup span it ran inside
+    record_layer_complete("compile", duration)
     tracer = current_tracer()
     if tracer is not None:
         now = tracer.now()

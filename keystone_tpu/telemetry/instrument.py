@@ -3,11 +3,11 @@ shares.
 
 `GraphExecutor` wraps each node's lazy Expression through
 `instrument_node_force`; the wrapper times the real force (try/finally,
-so a thunk that raises still reports its elapsed time and bumps the
-failure counter), estimates output bytes ONCE per force with the
+so a thunk that raises still reports its elapsed time, and its span is
+marked ``error``), estimates output bytes ONCE per force with the
 module-level `estimate_bytes` (no per-force import — the old
 `ExecutionProfiler.wrap` re-imported it inside the thunk on every
-force), opens a ``cat="node"`` span under the active tracer, feeds the
+force), opens a ``cat="node"`` span of the ``force`` layer, feeds the
 observed live-set accounting, and notifies the attached profiler.
 Streaming expressions — which downstream consumers drain through
 ``iter_chunks()`` without ever running the memoized thunk — are
@@ -32,7 +32,7 @@ from time import perf_counter
 from typing import Optional
 
 from .metrics import counter, gauge
-from .spans import current_tracer
+from .spans import _Span, current_tracer, span
 
 
 #: cached per-process metric dimension: "" on single-process jobs (no
@@ -65,16 +65,11 @@ def record_dispatch(n: int = 1) -> None:
     """Count ``n`` executed XLA programs against
     ``dispatch.programs_executed`` — the per-run dispatch budget: every
     executed program pays a fixed launch cost whatever its size, so
-    trivial stages are bounded by their count, not their bytes.
-
-    Call sites are the library's jitted call boundaries: every
-    `Dataset.map_batches`, every fused-chain program launch
-    (`FusedBatchTransformer.apply_batch`), every solver step
-    (`_bcd_epoch` / `_krr_step` / `_lbfgs_step`), every overlap-engine
-    chunk dispatch, and the node-level module jits that bypass
-    `map_batches` (scalers, label indicators, random features, normal
-    equations). Always on (not gated on tracing): the `dispatch_count`
-    bench tier and the scheduler tests read the counter directly.
+    trivial stages are bounded by their count, not their bytes. The
+    counting half of `dispatch`, which is what the library's call sites
+    use. Always on (not gated on tracing): the `dispatch_count` bench
+    tier, the benchmark's `programs_per_fit` and the scheduler tests
+    read the counter directly.
 
     Under a multi-host mesh each count also lands on
     ``dispatch.programs_executed.p<i>`` — every host dispatches its own
@@ -85,6 +80,52 @@ def record_dispatch(n: int = 1) -> None:
     dim = process_dim()
     if dim is not None:
         counter(f"dispatch.programs_executed.{dim}").inc(n)
+
+
+def fn_label(fn) -> str:
+    """The label of a dispatch that has no node behind it: the name of
+    the function it calls."""
+    return getattr(fn, "__name__", None) or type(fn).__name__
+
+
+class _Dispatch(_Span):
+    """The span `dispatch` returns: a ``dispatch`` layer span that counts
+    its programs when the call has returned."""
+
+    __slots__ = ("_n",)
+
+    def __init__(self, label: str, n: int, args: dict):
+        super().__init__(current_tracer(), label, "dispatch", "dispatch",
+                         args)
+        self._n = n
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        if exc_type is None:
+            record_dispatch(self._n)
+        return super().__exit__(exc_type, exc, tb)
+
+
+def dispatch(label: str, n: int = 1, **args) -> _Dispatch:
+    """The span around one call of a jitted program (``n`` where one
+    call launches several): times from the call to its return under the
+    ``dispatch`` layer, so the enqueue shows on the profiler's host
+    plane as ``ks:dispatch:<label>`` and its seconds in
+    ``host.dispatch.seconds``, and counts the call as `record_dispatch`
+    does once it has returned. A call that raises launched nothing and
+    is not counted, so a fallback that dispatches again counts once.
+    ``label`` is the node's label or the program's name.
+
+        with dispatch(self.label):
+            out = program(flat, data.array, data.mask)
+
+    Call sites are the library's jitted call boundaries: every
+    `Dataset.map_batches`, every fused-chain program launch
+    (`FusedBatchTransformer.apply_batch`), every solver step
+    (`_bcd_epoch` / `_krr_step` / `_lbfgs_step`), every overlap-engine
+    chunk dispatch, and the node-level module jits that bypass
+    `map_batches` (scalers, label indicators, random features, normal
+    equations, the evaluator's confusion matrix)."""
+    return _Dispatch(label, n, args)
 
 
 def estimate_bytes(value) -> float:
@@ -117,9 +158,7 @@ def _record_node(label, vertex, profiler, dt, nbytes, failed,
                  t0_rel=None, streamed=False):
     """Shared completion bookkeeping for both force paths."""
     counter("executor.node_forces").inc()
-    if failed:
-        counter("executor.node_failures").inc()
-    elif nbytes:
+    if nbytes and not failed:
         # memoized outputs stay live for the executor's lifetime: the
         # running sum's high-water mark is the observed live-set peak
         # the static KP2xx model reconciles against (per-run copy on the
@@ -215,10 +254,9 @@ def instrument_node_force(
         return expr
 
     def forced():
-        tracer = current_tracer()
-        rec = None
-        if tracer is not None:
-            rec = tracer.start(f"force {label}", cat="node", vertex=vertex)
+        ctx = span(f"force {label}", cat="node", layer="force",
+                   vertex=vertex)
+        ctx.__enter__()
         t0 = perf_counter()
         value = None
         failed = False
@@ -240,9 +278,7 @@ def instrument_node_force(
                     nbytes = estimate_bytes(value)
                 except Exception:
                     nbytes = 0.0
-            if rec is not None:
-                tracer.end(rec, error=failed, out_bytes=nbytes,
-                           seconds=round(dt, 6))
+            ctx.end(error=failed, out_bytes=nbytes, seconds=round(dt, 6))
             _record_node(label, vertex, profiler, dt, nbytes, failed)
 
     expr._thunk = forced

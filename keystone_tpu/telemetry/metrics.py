@@ -9,8 +9,11 @@ disagree about what was observed.
 Metric names are dotted and stable — they are part of the telemetry
 contract documented in OBSERVABILITY.md:
 
-  executor.node_forces / node_failures / memo_hits /
-  executor.prefix_saves / prefix_reuse      (counters)
+  executor.node_forces                      (counter)
+  host.<layer>.seconds / host.<layer>.spans (counters; the layer clock
+                                             of `spans.span`: self time
+                                             and count of each layer's
+                                             spans, always on)
   executor.live_bytes                       (gauge; .max = observed peak)
   prefetch.queue_depth                      (gauge)
   prefetch.producer_stall_s / consumer_wait_s   (histograms, seconds)
@@ -19,7 +22,7 @@ contract documented in OBSERVABILITY.md:
   solver.steps                              (counter)
   dispatch.programs_executed                (counter; one per jitted
                                              call boundary — see
-                                             instrument.record_dispatch)
+                                             instrument.dispatch)
   dispatch.scheduler_runs / scheduled_tasks (counters; concurrent DAG
                                              scheduler activity)
   dispatch.programs_compiled                (counter; one per COLD XLA

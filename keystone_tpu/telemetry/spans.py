@@ -1,44 +1,56 @@
-"""Hierarchical span tracer.
+"""The one span primitive, and the host tracer behind it.
 
-A `Tracer` collects closed `SpanRecord`s — named, categorized intervals
-with parent attribution — from every layer of a run:
+`span(name, cat, layer=..., rid=..., **args)` is the only way the program
+opens a span. A live span feeds three sinks:
+
+  - the profiler: a `jax.profiler.TraceAnnotation` named
+    ``ks:<layer or cat>:<name>``. The annotation checks the profiler's
+    own flag, so it costs under a microsecond with no session and lands
+    on the host plane of the session's ``.xplane.pb``, on the device's
+    clock, whenever one runs (`jax.profiler.trace`, the benchmark's
+    ``--trace 1``). There is no switch of the program's own;
+  - the layer clock (``layer=`` one of `LAYERS`, always on): the span's
+    self time, its length less the layer spans opened inside it on the
+    same thread, goes to the counter ``host.<layer>.seconds`` and 1 to
+    ``host.<layer>.spans``;
+  - the host tracer (`Tracer`, on under `trace_run` / ``KEYSTONE_TRACE``):
+    a closed `SpanRecord` whose parent is the span that was open on the
+    thread, written as Chrome trace JSON by `export.write_trace`.
+
+A span with no ``layer`` and no tracer installed is the shared no-op
+(one global read, no allocation), so ``cat="chunk"`` and per-row spans
+cost nothing in an untraced run.
 
     pipeline run (trace_run)          cat="pipeline"
-      optimizer phase                 cat="phase"
-        node force (executor)         cat="node"
+      optimizer phase                 cat="phase"   layer optimize
+        node force (executor)         cat="node"    layer force
+          program call                cat="dispatch" layer dispatch
           stream chunk (batching)     cat="chunk"
-          solver iteration            cat="step"
+          solver fit, iteration       cat="solver"/"step" layer solver
+          blocking pull               cat="sync"    layer sync
 
-Nesting is structural, not declared: each thread keeps a span stack per
-tracer, so a node force that pulls its dependency inside its own thunk
-automatically becomes that dependency's parent, and the overlap engine's
-producer thread gets its own root lane (its tid separates it in the
-Chrome trace view).
+Nesting is structural, not declared: each thread keeps a span stack, so
+a node force that pulls its dependency inside its own thunk is that
+dependency's parent, and the overlap engine's producer thread has a
+root lane of its own.
 
-Activation, cheapest-first:
-
-  - no tracer installed → `span(...)` returns a shared no-op context
-    manager; the hot path costs one global read;
-  - ``with trace_run("out.json"):`` scopes a tracer and writes Chrome
-    trace JSON on exit;
-  - ``KEYSTONE_TRACE=out.json`` (or `ExecutionConfig.trace_path`)
-    installs an ambient process tracer on first use and writes the file
-    at interpreter exit — so ANY entry point (`python -m
-    keystone_tpu.pipelines ...`, bench.py, pytest) produces a trace with
-    zero code changes.
-
-Timestamps use `time.perf_counter()` relative to the tracer's epoch
-(KJ004 discipline); the wall-clock epoch is recorded once in metadata
-for cross-run alignment.
+Host tracer timestamps use `time.perf_counter()` relative to the
+tracer's epoch (KJ004 discipline); the wall-clock epoch is recorded once
+in metadata for cross-run alignment.
 """
 
 from __future__ import annotations
 
 import atexit
 import itertools
+import re
 import threading
 import time
 from typing import Any, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
+
+from .metrics import counter
 
 _capabilities: Dict[str, Dict[str, Any]] = {}
 
@@ -180,25 +192,122 @@ class Tracer:
             self.metadata["observed_live_peak_bytes"] = live
 
 
-class _SpanCtx:
-    """Context manager binding one span to one tracer. Exceptions close
-    the span (marked ``error``) and propagate."""
+#: the layers whose host seconds are always counted, in the order a
+#: request meets them (OBSERVABILITY.md lists each one's site)
+LAYERS = ("optimize", "force", "dispatch", "sync", "solver", "compile",
+          "serve")
 
-    __slots__ = ("_tracer", "_name", "_cat", "_args", "_rec")
+_layer_local = threading.local()
+#: guards `_Span._inner`: a worker thread's spans add to the span of the
+#: thread that waits for it (`adopt_layer_parent`)
+_inner_lock = threading.Lock()
 
-    def __init__(self, tracer: Tracer, name: str, cat: str, args: Dict):
+
+def _layer_stack() -> list:
+    st = getattr(_layer_local, "stack", None)
+    if st is None:
+        st = _layer_local.stack = []
+    return st
+
+
+def layer_parent() -> Optional["_Span"]:
+    """The innermost layer span open on this thread, to hand to the
+    threads it is about to wait for (`adopt_layer_parent`)."""
+    st = _layer_stack()
+    return st[-1] if st else None
+
+
+def adopt_layer_parent(parent: Optional["_Span"]) -> None:
+    """Called first thing on a worker thread whose spawner waits for it
+    inside ``parent``: the worker's layer spans then count as opened
+    inside ``parent``, so the seconds the spawner spends waiting are
+    charged to what the workers did and not to the spawner's layer as
+    well. Workers that run side by side still add up to more than the
+    wall time: these are thread seconds."""
+    _layer_local.stack = [parent] if parent is not None else []
+
+
+def _add_inner(parent: "_Span", seconds: float) -> None:
+    with _inner_lock:
+        parent._inner += seconds
+
+
+#: layer -> the names of its two counters (looked up at every close:
+#: the registry can be reset under a span)
+_LAYER_COUNTERS = {layer: (f"host.{layer}.seconds", f"host.{layer}.spans")
+                   for layer in LAYERS}
+
+
+def _count_layer(layer: str, seconds: float) -> None:
+    seconds_name, spans_name = _LAYER_COUNTERS[layer]
+    counter(seconds_name).inc(seconds)
+    counter(spans_name).inc()
+
+
+def record_layer_complete(layer: str, seconds: float) -> None:
+    """Count ``seconds`` that have already passed on this thread as one
+    closed span of ``layer``, and take them out of the self time of the
+    layer span that is open around them. For measurements that arrive
+    after the fact (jax's compile events)."""
+    _count_layer(layer, seconds)
+    st = _layer_stack()
+    if st:
+        _add_inner(st[-1], seconds)
+
+
+def scope_name(label: str) -> str:
+    """``ks.<label>`` for `jax.named_scope`: the label without blanks,
+    without the slash that separates scopes in an op's name, and without
+    a private name's leading underscores."""
+    return "ks." + re.sub(r"[\s/]+", "", label).lstrip("_")
+
+
+class _Span:
+    """One live span and its three sinks (module docstring). Exceptions
+    close the span (marked ``error`` in the host tracer) and propagate."""
+
+    __slots__ = ("_tracer", "_name", "_cat", "_layer", "_args", "_rec",
+                 "_annotation", "_t0", "_inner")
+
+    def __init__(self, tracer: Optional["Tracer"], name: str, cat: str,
+                 layer: Optional[str], args: Dict):
         self._tracer = tracer
         self._name = name
         self._cat = cat
+        self._layer = layer
         self._args = args
         self._rec = None
 
-    def __enter__(self) -> SpanRecord:
-        self._rec = self._tracer.start(self._name, self._cat, **self._args)
+    def __enter__(self) -> Optional[SpanRecord]:
+        self._annotation = TraceAnnotation(
+            f"ks:{self._layer or self._cat}:{self._name}", **self._args)
+        self._annotation.__enter__()
+        if self._tracer is not None:
+            self._rec = self._tracer.start(
+                self._name, self._cat, **self._args)
+        if self._layer is not None:
+            self._inner = 0.0
+            _layer_stack().append(self)
+            self._t0 = time.perf_counter()
         return self._rec
 
+    def end(self, error: bool = False, **args) -> None:
+        """Close the span; ``args`` are added to the tracer's record."""
+        if self._layer is not None:
+            length = time.perf_counter() - self._t0
+            st = _layer_stack()
+            # tolerate exception-path unwinding that skipped inner ends
+            while st and st.pop() is not self:
+                pass
+            _count_layer(self._layer, max(0.0, length - self._inner))
+            if st:
+                _add_inner(st[-1], length)
+        if self._rec is not None:
+            self._tracer.end(self._rec, error=error, **args)
+        self._annotation.__exit__(None, None, None)
+
     def __exit__(self, exc_type, exc, tb) -> bool:
-        self._tracer.end(self._rec, error=exc_type is not None)
+        self.end(error=exc_type is not None)
         return False
 
 
@@ -312,13 +421,20 @@ def telemetry_active() -> bool:
     return current_tracer() is not None
 
 
-def span(name: str, cat: str = "span", **args):
-    """Open a span under the active tracer; a shared no-op when tracing
-    is off (one global read, zero allocation)."""
+def span(name: str, cat: str = "span", *, layer: Optional[str] = None,
+         rid: Optional[str] = None, **args):
+    """Open a span (module docstring). ``layer`` names the layer whose
+    clock the span's self time is charged to; ``rid`` is an identifier
+    that the spans of one request share. With neither a layer nor a
+    tracer this is the shared no-op."""
     t = current_tracer()
-    if t is None:
+    if t is None and layer is None:
         return _NOOP
-    return _SpanCtx(t, name, cat, args)
+    if layer is not None and layer not in LAYERS:
+        raise ValueError(f"span layer {layer!r} is not one of {LAYERS}")
+    if rid is not None:
+        args["rid"] = rid
+    return _Span(t, name, cat, layer, args)
 
 
 class trace_run:

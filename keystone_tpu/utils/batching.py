@@ -55,7 +55,7 @@ from typing import Callable, Iterable, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from ..telemetry import counter, gauge, histogram, record_dispatch, span
+from ..telemetry import counter, dispatch, fn_label, gauge, histogram, span
 
 
 class _ProducerError:
@@ -218,7 +218,8 @@ def _stack_chunk(
 
 
 def _split_result(res, part: List[int]) -> Tuple[List[int], List]:
-    res = np.asarray(res)  # the blocking device→host pull
+    with span("chunk_pull", cat="sync", layer="sync"):
+        res = np.asarray(res)  # the blocking device→host pull
     counter("overlap.bytes_pulled").inc(float(res.nbytes))
     # slice padded phantom rows off HERE, in the one place both dispatch
     # paths share: the indices/results yielded downstream always cover
@@ -231,9 +232,11 @@ def _stream_serial(items, plan, batch_fn) -> Iterator[Tuple[List[int], List]]:
     at a time."""
     for i, (part, pad_to) in enumerate(plan):
         with span("chunk_serial", cat="chunk", idx=i, rows=len(part)):
-            record_dispatch()  # one program per (shape, chunk) dispatch
-            out = _split_result(
-                batch_fn(_stack_chunk(items, part, pad_to)), part)
+            chunk = _stack_chunk(items, part, pad_to)
+            # one program per (shape, chunk) dispatch
+            with dispatch(fn_label(batch_fn)):
+                res = batch_fn(chunk)
+            out = _split_result(res, part)
         yield out
 
 
@@ -302,6 +305,7 @@ def _stream_overlapped(
         (_stage(ip) for ip in enumerate(plan)), depth,
     )
     inflight: "deque" = deque()  # (part, device result future)
+    label = fn_label(batch_fn)
     inflight_gauge = gauge("overlap.inflight_results")
     resident_gauge = gauge("overlap.resident_chunks")
     dispatched = counter("overlap.chunks_dispatched")
@@ -321,9 +325,9 @@ def _stream_overlapped(
         for part, staged_chunk in staged:
             _bump_staged(-1)  # chunk left the producer side
             # async dispatch: returns immediately, device queues the work
-            inflight.append((part, batch_fn(staged_chunk)))
+            with dispatch(label):  # one program per dispatched chunk
+                inflight.append((part, batch_fn(staged_chunk)))
             dispatched.inc()
-            record_dispatch()  # one program per dispatched chunk
             _note_residency()
             if len(inflight) > depth:
                 yield _drain(drained)
@@ -463,19 +467,22 @@ def _stream_megafused(
                 # fallback re-dispatches with nothing double-counted
                 stack = np.stack([_stack_chunk(items, part, pad_to)
                                   for part, pad_to in entries])
-                ys = _megafused_scanner(batch_fn)(_device_put_host(stack))
+                # the whole run is ONE launched program
+                with dispatch(fn_label(batch_fn)):
+                    ys = _megafused_scanner(batch_fn)(
+                        _device_put_host(stack))
             except Exception:
                 # permanently back to per-chunk for this fn, overlapped
                 # staging included
                 _MEGAFUSED_REJECTED[id(batch_fn)] = batch_fn
                 yield from _fallback_stream(items, entries, batch_fn)
                 continue
-            record_dispatch()  # the whole run is ONE launched program
             # in-order drain of the single result — the sanctioned
             # pull, exactly like _split_result's. A failure HERE is a
             # genuine runtime failure of a launched program and
             # propagates, exactly as the per-chunk path's pull would.
-            res = np.asarray(ys)  # keystone: ignore[KJ005]
+            with span("megafused_pull", cat="sync", layer="sync"):
+                res = np.asarray(ys)  # keystone: ignore[KJ005]
         counter("overlap.bytes_pulled").inc(float(res.nbytes))
         counter("megafusion.programs").inc()
         counter("megafusion.scan_trips").inc(trips)
@@ -661,8 +668,8 @@ def map_spill_windows(
     downstream sees them — the PR-5 pad-exactness contract extended to
     windows."""
     for idxs, win in stream_spill_windows(load, count, window):
-        record_dispatch()  # one program per reloaded window
-        out = fn(win)
+        with dispatch(fn_label(fn)):  # one program per reloaded window
+            out = fn(win)
         yield _split_result(out, idxs)
 
 

@@ -673,14 +673,22 @@ class GraphExecutor:
 
     def execute(self, graph_id: GraphId) -> Expression:
         """Execute up to ``graph_id``, returning its lazy Expression
-        (GraphExecutor.scala:53-80)."""
+        (GraphExecutor.scala:53-80). The plan, the warm-up scan and the
+        walk are the ``force`` layer's, with the root's force
+        (`_arm_concurrent`); the optimizer inside is its own layer."""
+        from ..telemetry import span
+
+        with span("execute", cat="phase", layer="force"):
+            return self._execute(graph_id)
+
+    def _execute(self, graph_id: GraphId) -> Expression:
         graph, prefixes = self._optimized_plan()
         self._check_structure(graph)
         self._warm_plan(graph)
         self._rearm_warmup()  # fits may have resolved since the scan
         env = PipelineEnv.get()
         profiler = getattr(env, "profiler", None)
-        from ..telemetry import counter, current_tracer
+        from ..telemetry import current_tracer
         from ..telemetry.instrument import instrument_node_force
 
         tracer = current_tracer()
@@ -690,8 +698,6 @@ class GraphExecutor:
 
         def go(vid: GraphId) -> Expression:
             if vid in self._memo:
-                if observing:
-                    counter("executor.memo_hits").inc()
                 return self._memo[vid]
             if isinstance(vid, SourceId):
                 raise ValueError(
@@ -709,8 +715,6 @@ class GraphExecutor:
                 prefix = prefixes.get(vid)
                 if prefix is not None and prefix not in env.state:
                     env.state[prefix] = expr
-                    if observing:
-                        counter("executor.prefix_saves").inc()
             self._memo[vid] = expr
             return expr
 
@@ -731,6 +735,12 @@ class GraphExecutor:
         if root_id in self._concurrent_wrapped or root.is_forced:
             return
         self._concurrent_wrapped.add(root_id)
+        from ..telemetry import span
+
+        node = (graph.get_sink_dependency(root_id)
+                if isinstance(root_id, SinkId) else root_id)
+        name = "root " + (graph.get_operator(node).label
+                          if isinstance(node, NodeId) else str(node))
 
         def prefetch():
             if getattr(_sched_local, "active", False):
@@ -740,17 +750,25 @@ class GraphExecutor:
             if cfg.concurrent_dispatch and cfg.dispatch_workers > 1:
                 self._force_concurrent(root_id, graph, cfg.dispatch_workers)
 
+        # the root's force is the `force` layer's span, always on: what
+        # the executor and the nodes' own host code take, less the
+        # dispatch, sync, solver and optimize spans opened inside it
         chunks_thunk = getattr(root, "_chunks_thunk", None)
         if chunks_thunk is not None:
             def chunks(orig=chunks_thunk):
-                prefetch()
-                return orig()
+                # a stream's chunks are pulled between the consumer's own
+                # work, so only the schedule and the stream's start are
+                # under the span; each chunk's dispatch has its own
+                with span(name, cat="phase", layer="force", streamed=True):
+                    prefetch()
+                    return orig()
 
             root._chunks_thunk = chunks
         elif root._thunk is not None:
             def thunk(orig=root._thunk):
-                prefetch()
-                return orig()
+                with span(name, cat="phase", layer="force"):
+                    prefetch()
+                    return orig()
 
             root._thunk = thunk
 
@@ -857,6 +875,7 @@ class GraphExecutor:
         # that worker — concurrency already exists one level up.
 
         from ..telemetry import counter, span
+        from ..telemetry.spans import adopt_layer_parent, layer_parent
 
         topo_index = {v: i for i, v in enumerate(tasks)}
         indeg = {v: len(eff_deps[v]) for v in tasks}
@@ -872,9 +891,14 @@ class GraphExecutor:
         failures: List[Tuple[int, BaseException]] = []
         stop = False
 
+        # the caller waits for the pool inside its own span: what the
+        # workers do is charged to their layers, not to the wait as well
+        waiting_in = layer_parent()
+
         def worker():
             nonlocal outstanding, stop
             _sched_local.active = True
+            adopt_layer_parent(waiting_in)
             try:
                 while True:
                     with cond:

@@ -72,9 +72,11 @@ class RuleExecutor:
         from ..telemetry import span
 
         plan: Plan = (graph, {})
-        with span("optimize", cat="phase", batches=len(self.batches)):
+        with span("optimize", cat="phase", layer="optimize",
+                  batches=len(self.batches)):
             for batch in self.batches:
-                with span(f"optimizer:{batch.name}", cat="phase"):
+                with span(f"optimizer:{batch.name}", cat="phase",
+                          layer="optimize"):
                     for iteration in range(batch.max_iterations):
                         new_plan = plan
                         for rule in batch.rules:
@@ -131,9 +133,6 @@ class SavedStateLoadRule(Rule):
             if expr is not None and not isinstance(
                 graph.get_operator(node), ExpressionOperator
             ):
-                from ..telemetry import counter
-
-                counter("executor.prefix_reuse").inc()
                 graph = graph.set_operator(
                     node, ExpressionOperator(expr, name=str(prefix.operator_key[0]))
                 ).set_dependencies(node, ())
@@ -341,7 +340,7 @@ class UnifiedPlannerRule(Rule):
             return plan
         from ..telemetry import counter, span
 
-        with span("unified_planner", cat="phase"):
+        with span("unified_planner", cat="phase", layer="optimize"):
             try:
                 from ..analysis.plan_ir import plan_unified
                 from ..analysis.propagate import spec_pass
@@ -662,7 +661,7 @@ class ShardingPlannerRule(Rule):
             # the planner's abstract traces (spec_pass runs user apply
             # bodies under eval_shape).
             return plan
-        with span("sharding_planner", cat="phase",
+        with span("sharding_planner", cat="phase", layer="optimize",
                   devices=int(mesh.devices.size)):
             try:
                 from ..analysis.planner import plan_sharding
@@ -835,7 +834,7 @@ class PrecisionPlannerRule(Rule):
             return plan
         from ..telemetry import counter, span
 
-        with span("precision_planner", cat="phase",
+        with span("precision_planner", cat="phase", layer="optimize",
                   programs=len(targets)):
             try:
                 from ..analysis.precision import plan_stage_precision
