@@ -92,8 +92,11 @@ class _ConvRectifyPoolStage(Transformer):
     """Peephole-fused Convolver >> SymmetricRectifier >> Pooler(sum):
     the Pallas one-pass kernel keeps the conv output and the
     channel-doubled rectified tensor in VMEM, writing only the pooled
-    grid (ops/pallas_kernels.py — measured 2.26x the XLA path on v5e).
-    Default-on for TPU; KEYSTONE_DISABLE_FUSED_CONV=1 forces XLA."""
+    grid, at any filter count (a bank too wide for VMEM runs as filter
+    blocks). ops/pallas_kernels.py; measured 3.7x the XLA path at
+    10,000 filters on a TPU v5 lite, kernel alone (PERF.md section 6,
+    PR 27). Default-on for TPU; KEYSTONE_DISABLE_FUSED_CONV=1 forces
+    XLA."""
 
     fusable = True
     precision_tolerance = "tolerant"  # all three fused members are

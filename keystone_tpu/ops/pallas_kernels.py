@@ -266,26 +266,29 @@ def rbf_block(X, Yb, gamma):
 # ---------------------------------------------------------------------------
 #
 # The featurizer's true bottleneck is not the conv FLOPs but the HBM
-# round trips between conv, rectify, and pool: at 2048 CIFAR images /
-# 256 filters the conv output (1.5 GB), the channel-doubled rectified
-# tensor (3 GB written, 3 GB re-read by reduce_window) are all
-# bandwidth, measured at 8.5 of the 9.7 ms per microbatch on v5e.
-# This kernel keeps everything after the im2col in VMEM: one GEMM
-# against the folded filter bank, the rank-1 patch-mean correction, the
-# two-sided rectification, and sum-pooling expressed as a block-diagonal
-# 0/1 matmul — only the (n, gy, gx, 2K) pooled grid is written back.
+# round trips between conv, rectify, and pool: XLA's path writes both
+# halves of the rectified conv output and the pool reads them back, at
+# the documented 10,000 filters 2 x f32[32,27,27,10000] a microbatch of
+# 32 and 958 GB a fit, at 85% of the HBM bandwidth (PERF.md section 5,
+# PR 25). This kernel keeps everything after the im2col in VMEM: one
+# GEMM against the folded filter bank, the rank-1 patch-mean correction,
+# the two-sided rectification, and sum-pooling expressed as a
+# block-diagonal 0/1 matmul — only the (n, gy, gx, 2K) pooled grid is
+# written back. A bank too wide for VMEM runs as filter blocks (grid
+# axis 1): the patches stay resident while the filter tiles stream past.
 #
 # Patches are fed to the MXU in bfloat16: at DEFAULT matmul precision
 # the MXU truncates f32 operands to bf16 anyway, so this halves patch
 # traffic with bit-for-bit-equivalent results vs the XLA conv path
-# (measured max rel. disagreement 5.4e-4 — the same class as two
-# DEFAULT-precision XLA convs of the same values).
+# (max rel. disagreement 1.8e-4 at 10,000 filters on the chip — the
+# same class as two DEFAULT-precision XLA convs of the same values).
 #
-# Measured on v5e (1 chip, 2026-07, chained-iteration timing): XLA path
-# 9.0 ms vs fused kernel 4.0 ms per 2048-image microbatch (2.26x);
-# 50 k-image featurize 219 ms -> 97 ms. Unlike the standalone
-# rectify_pool kernel above, this one is ON by default on TPU
-# (set KEYSTONE_DISABLE_FUSED_CONV=1 to force the XLA path).
+# Measured on one TPU v5 lite (2026-09-30, PR 27; PERF.md section 6 has
+# the runs): the kernel alone, in a loop over microbatches of 32 images
+# at 10,000 filters, XLA path 5.53 ms vs fused kernel 1.49 ms a
+# microbatch (3.7x). Unlike the standalone rectify_pool kernel above,
+# this one is ON by default on TPU (set KEYSTONE_DISABLE_FUSED_CONV=1
+# to force the XLA path).
 
 
 def use_fused_conv() -> bool:
@@ -449,19 +452,28 @@ def conv_rectify_pool(
     if jnp.issubdtype(images.dtype, jnp.floating) \
             and kernel_hwio.dtype != images.dtype:
         kernel_hwio = kernel_hwio.astype(images.dtype)
-    if use_fused_conv() and _fused_conv_canary_ok(
-        images.shape[1], images.shape[2], images.shape[3],
-        kernel_hwio.shape[3], pool, stride, normalize,
-        kernel_hwio.shape[0],
-    ):
-        try:
-            return conv_rectify_pool_pallas(
-                images, hwio_to_cmajor(kernel_hwio), colsum, bias,
-                alpha, max_val, pool, stride, normalize,
-                kernel_hwio.shape[0],
-            )
-        except FusedConvIneligibleError:
-            pass
+    if use_fused_conv():
+        # once per program traced: how often the mechanism engages
+        # (`pallas.fused_conv.traced`) against how often the canary's
+        # one designed demotion sends a program to XLA (`.demoted`)
+        from ..telemetry import counter
+
+        if _fused_conv_canary_ok(
+            images.shape[1], images.shape[2], images.shape[3],
+            kernel_hwio.shape[3], pool, stride, normalize,
+            kernel_hwio.shape[0],
+        ):
+            try:
+                out = conv_rectify_pool_pallas(
+                    images, hwio_to_cmajor(kernel_hwio), colsum, bias,
+                    alpha, max_val, pool, stride, normalize,
+                    kernel_hwio.shape[0],
+                )
+                counter("pallas.fused_conv.traced").inc()
+                return out
+            except FusedConvIneligibleError:
+                pass
+        counter("pallas.fused_conv.demoted").inc()
     return conv_rectify_pool_reference(
         images, kernel_hwio, colsum, bias, alpha, max_val, pool, stride,
         normalize,
@@ -498,9 +510,9 @@ def _pool_matrix(pos_h: int, pos_w: int, posp: int,
 
 def _conv_rect_pool_kernel(
     pat_ref, g_ref, pmat_ref, colsum_ref, bias_ref, o_ref,
-    *, alpha, max_val, d_real, k, normalize, b, posp, grp, rows,
+    *, alpha, max_val, d_real, normalize, b, posp, grp, rows,
 ):
-    g = g_ref[:]                                       # (dp, k) bf16
+    g = g_ref[:]                                       # (dp, tk) bf16
     pm = pmat_ref[:]                                   # (rows, grp·posp)
     cs = colsum_ref[:]
     bs = bias_ref[:]
@@ -543,60 +555,101 @@ def _conv_rect_pool_kernel(
     lax.fori_loop(0, b // grp, body, 0)
 
 
+# the 10 MB cap of the 16 MB VMEM absorbs scheduling slop
+_FUSED_CONV_VMEM_BUDGET = 10 * (1 << 20)  # keystone: ignore[KJ017]
+
+
+def _fused_conv_vmem_bytes(posp: int, dp: int, b: int, g: int, R: int,
+                           kp: int, k2p: int, filter_bufs: int) -> int:
+    """VMEM the kernel is accounted for at an image block of b, groups
+    of g images (R output rows each) and one filter block of kp lanes
+    (k2p for both signs) held in `filter_bufs` buffers: one when it is
+    the whole bank, two when it changes with the grid step.
+
+    Mosaic pads the lane (minor) dimension to 128: every (rows, k) f32
+    buffer really occupies (rows, round_up(k, 128)) of VMEM — ignoring
+    it produced a real scoped-vmem OOM at k=16 (21.5 MB actual vs 8.9 MB
+    estimated). The conv/rectify intermediates (z, act) are ONE group's
+    worth by construction (sequential fori_loop in the kernel), so they
+    don't scale with the block."""
+    return (
+        2 * b * posp * dp * 2            # patches, dbl-buf bf16
+        + g * posp * kp * 4              # z (one group, f32)
+        + g * posp * k2p * 4             # act = both signs
+        + 2 * (b // g) * R * k2p * 4     # pooled out, dbl-buf
+        + R * g * posp * 4               # group pool matrix
+        + filter_bufs * dp * kp * 2      # filter block, bf16
+    )
+
+
+def _fused_conv_largest_block(posp: int, dp: int, g: int, R: int,
+                              kp: int, k2p: int, filter_bufs: int) -> int:
+    """The largest image block, a multiple of g up to 32, whose working
+    set fits the budget; 0 when not even one group does."""
+    # grouped conv working set (patches + per-group z/act + pooled out +
+    # pool matrix + filters) has no chain-formula equivalent; its own
+    # live-chip canary gates it
+    b = 0
+    while b + g <= 32 and _fused_conv_vmem_bytes(
+            posp, dp, b + g, g, R, kp, k2p, filter_bufs
+    ) <= _FUSED_CONV_VMEM_BUDGET:
+        b += g
+    return b
+
+
 def _fused_conv_geometry(posp: int, dp: int, k: int,
-                         cells: int) -> "tuple[int, int, int]":
-    """(b, g, R): image block, images per kernel loop iteration, and
-    output rows per iteration, chosen so the working set fits ~10 MB of
-    VMEM. Groups are tried largest-first — g images per iteration share
-    one pool dot/store whose 8-row tiles are fully used when g·cells is
-    a multiple of 8 — and halved when a group's z/act transients (which
-    scale with g) blow the budget, down to one image per iteration.
-    b is always a multiple of g so the kernel's loop covers the block
-    exactly; R is a multiple of 8 so stores stay tile-aligned."""
+                         cells: int) -> "tuple[int, int, int, int]":
+    """(b, g, R, tk): image block, images per kernel loop iteration,
+    output rows per iteration and filter tile, from shapes alone, so
+    that the working set fits the VMEM budget.
+
+    Groups are tried largest-first — g images per iteration share one
+    pool dot/store whose 8-row tiles are fully used when g·cells is a
+    multiple of 8 — and halved when a group's z/act transients (which
+    scale with g) blow the budget, down to one image per iteration. b is
+    always a multiple of g so the kernel's loop covers the block
+    exactly; R is a multiple of 8 so stores stay tile-aligned.
+
+    A bank that fits whole at some group size is ONE filter block,
+    tk = k. Only a bank that does not is tiled over filter blocks (the
+    pool is a sum per filter, so they are independent): tk is then a
+    multiple of 128, the bank is padded with zero filters to a multiple
+    of it, and no (position x filter) tensor wider than tk exists in
+    VMEM or in HBM. The widest tile that fits a tight group wins, then
+    the blocks are evened out (K = 1,100 is three tiles of 384, not
+    three of 512). Wide, because a loop iteration's cost is a part that
+    grows with the tile and a fixed part of its own, the dependent chain
+    of dot, rectifier and pool dot, and the image block hardly matters:
+    2.28, 1.87, 1.63 and 1.49 ms a microbatch of 32 at K = 10,000 at
+    tiles of 128, 256, 384 and 512, the same at image blocks of 8, 16
+    and 32 (PERF.md section 6, PR 27). b = 0: ineligible (one image at
+    128 filters does not fit, or there are no pooled cells)."""
     if cells <= 0:  # pool window larger than the conv-position grid:
         # no pooled output exists; plainly ineligible, not a crash
-        return 0, 1, 8
-    kp = -(-k // 128) * 128
-    k2p = -(-(2 * k) // 128) * 128
+        return 0, 1, 8, k
+    groups = []
     g = 8 // cells if 8 % cells == 0 else 1
     while g >= 1:
-        if g > 1 and (g * cells) % 8 != 0:
-            # only TIGHT multi-image groups (or g=1): a padded group of
-            # several images would interleave zero rows between groups,
-            # breaking the per-image output reshape below
-            g //= 2
-            continue
-        R = _round_up(g * cells, 8)
-        best = 0
-        cand = g
-        while cand <= 32:
-            # Mosaic pads the lane (minor) dimension to 128: every
-            # (rows, k) f32 buffer really occupies
-            # (rows, round_up(k, 128)) of VMEM — ignoring it produced a
-            # real scoped-vmem OOM at k=16 (21.5 MB actual vs 8.9 MB
-            # estimated). The conv/rectify intermediates (z, act) are
-            # ONE group's worth by construction (sequential fori_loop
-            # in the kernel), so they don't scale with the block; the
-            # 10 MB cap of the 16 MB VMEM absorbs scheduling slop.
-            bytes_needed = (
-                2 * cand * posp * dp * 2         # patches, dbl-buf bf16
-                + g * posp * kp * 4              # z (one group, f32)
-                + g * posp * k2p * 4             # act = both signs
-                + 2 * (cand // g) * R * k2p * 4  # pooled out, dbl-buf
-                + R * g * posp * 4               # group pool matrix
-                + dp * kp * 2
-            )
-            # grouped conv working set (patches + per-group z/act +
-            # pooled out + pool matrix + filters) has no chain-formula
-            # equivalent; its own live-chip canary gates it
-            if bytes_needed > 10 * (1 << 20):  # keystone: ignore[KJ017]
-                break
-            best = cand
-            cand += g
-        if best > 0:
-            return best, g, R
+        # only TIGHT multi-image groups (or g=1): a padded group of
+        # several images would interleave zero rows between groups,
+        # breaking the per-image output reshape below
+        if g == 1 or (g * cells) % 8 == 0:
+            groups.append((g, _round_up(g * cells, 8)))
         g //= 2
-    return 0, 1, _round_up(cells, 8)
+    kp = _round_up(k, 128)
+    for g, R in groups:
+        b = _fused_conv_largest_block(
+            posp, dp, g, R, kp, _round_up(2 * k, 128), 1)
+        if b > 0:
+            return b, g, R, k
+    for g, R in groups:
+        for tk in range(kp - 128, 0, -128):
+            b = _fused_conv_largest_block(posp, dp, g, R, tk, 2 * tk, 2)
+            if b > 0:
+                k_blocks = -(-k // tk)  # as many as the widest tile
+                # takes, evenly sized
+                return b, g, R, _round_up(-(-k // k_blocks), 128)
+    return 0, 1, _round_up(cells, 8), k
 
 
 def _fused_conv_block_images(posp: int, dp: int, k: int, cells: int) -> int:
@@ -629,10 +682,12 @@ def conv_rectify_pool_pallas(
     gx = (pos_w - pool) // stride + 1
     cells = gy * gx
 
-    b, g_img, rows = _fused_conv_geometry(posp, dp, k, cells)
+    b, g_img, rows, tk = _fused_conv_geometry(posp, dp, k, cells)
     if b == 0:
         raise FusedConvIneligibleError("fused conv block does not fit VMEM")
     n_pad = _round_up(n, b)
+    k_pad = _round_up(k, tk)
+    k_blocks = k_pad // tk
 
     pat = lax.conv_general_dilated_patches(
         jnp.moveaxis(images, -1, 1), (patch, patch), (1, 1), "VALID"
@@ -643,36 +698,46 @@ def conv_rectify_pool_pallas(
 
     r_img = rows // g_img  # output rows per image (== cells when tight;
     # padded groups are g=1 only, so this stays exact)
-    Gp = jnp.pad(G_cmajor, ((0, dp - d), (0, 0))).astype(jnp.bfloat16)
+    # zero filters (zero colsum, zero bias) pad the bank to whole tiles;
+    # their columns are sliced off below
+    Gp = jnp.pad(G_cmajor, ((0, dp - d), (0, k_pad - k))).astype(jnp.bfloat16)
     pmat = jnp.asarray(_pool_matrix(pos_h, pos_w, posp, pool, stride, g_img))
-    cs = jnp.asarray(colsum, jnp.float32).reshape(1, k)
-    bs = jnp.asarray(bias, jnp.float32).reshape(1, k)
+    cs = jnp.pad(jnp.asarray(colsum, jnp.float32), (0, k_pad - k))
+    bs = jnp.pad(jnp.asarray(bias, jnp.float32), (0, k_pad - k))
 
-    grid = n_pad // b
+    # filter blocks innermost: the patch block's index ignores j, so it
+    # is fetched once per image block and stays resident while the
+    # (dp, tk) filter tiles stream past
     out = pl.pallas_call(
         partial(
             _conv_rect_pool_kernel,
             alpha=float(alpha), max_val=float(max_val),
-            d_real=d, k=k, normalize=normalize, b=b, posp=posp,
+            d_real=d, normalize=normalize, b=b, posp=posp,
             grp=g_img, rows=rows,
         ),
-        grid=(grid,),
+        grid=(n_pad // b, k_blocks),
         in_specs=[
-            pl.BlockSpec((b * posp, dp), lambda i: (i, 0),
+            pl.BlockSpec((b * posp, dp), lambda i, j: (i, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((dp, k), lambda i: (0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((rows, g_img * posp), lambda i: (0, 0),
+            pl.BlockSpec((dp, tk), lambda i, j: (0, j),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, k), lambda i: (0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, k), lambda i: (0, 0), memory_space=pltpu.VMEM),
+            pl.BlockSpec((rows, g_img * posp), lambda i, j: (0, 0),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, tk), lambda i, j: (0, j),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, tk), lambda i, j: (0, j),
+                         memory_space=pltpu.VMEM),
         ],
-        out_specs=pl.BlockSpec((b * r_img, 2 * k), lambda i: (i, 0),
+        out_specs=pl.BlockSpec((b * r_img, 2 * tk), lambda i, j: (i, j),
                                memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((grid * b * r_img, 2 * k),
+        out_shape=jax.ShapeDtypeStruct((n_pad * r_img, k_blocks * 2 * tk),
                                        jnp.float32),
         interpret=interpret,
         name="ks_conv_rectify_pool",
-    )(pat, Gp, pmat, cs, bs)
-    # tight grouping: r_img == cells and the slice below is a no-op
-    return (out.reshape(n_pad, r_img, 2 * k)[:n, :cells]
-            .reshape(n, gy, gx, 2 * k))
+    )(pat, Gp, pmat, cs.reshape(1, k_pad), bs.reshape(1, k_pad))
+    # a filter block's columns are its positive half, then its negative
+    # half: gather the halves over the blocks. With one block (tk == k)
+    # and tight grouping (r_img == cells) all of this is a reshape.
+    out = out.reshape(n_pad, r_img, k_blocks, 2, tk)[:n, :cells]
+    out = out.transpose(0, 1, 3, 2, 4).reshape(n, cells, 2, k_pad)[..., :k]
+    return out.reshape(n, gy, gx, 2 * k)

@@ -223,7 +223,7 @@ def main():
         conv_rectify_pool_reference,
         hwio_to_cmajor,
     )
-    from keystone_tpu.ops.pallas_kernels import _fused_conv_block_images
+    from keystone_tpu.ops.pallas_kernels import _fused_conv_geometry
 
     interpret = "--interpret" in sys.argv[1:]
     if interpret:
@@ -244,9 +244,11 @@ def main():
     posp = -(-(pos_h * pos_w) // 16) * 16
     dp = -(-(c * patch * patch) // 128) * 128
     cells = ((pos_h - pool) // stride + 1) * ((pos_w - pool) // stride + 1)
-    b = _fused_conv_block_images(posp, dp, k, cells)
+    b, g_img, rows, tk = _fused_conv_geometry(posp, dp, k, cells)
     print(f"block chooser at posp={posp} dp={dp} cells={cells} k={k}: "
-          f"b={b}", flush=True)
+          f"b={b}, {g_img} images and {rows} output rows a loop iteration, "
+          f"filter tile {tk}" + (" (the whole bank)" if tk == k else ""),
+          flush=True)
 
     rng = np.random.default_rng(0)
     kern = jnp.asarray(rng.normal(size=(patch, patch, c, k)).astype(np.float32))

@@ -164,6 +164,96 @@ def test_conv_rectify_pool_pallas_matches_reference(
     )
 
 
+@pytest.mark.parametrize(
+    "n,h,w,c,patch,k,pool,stride,normalize,budget,tk,k_blocks",
+    [
+        # the 10 MB budget itself splits these banks at a small posp
+        (3, 12, 12, 1, 3, 12288, 4, 3, True, None, 4096, 3),    # cells=9
+        (2, 12, 12, 1, 3, 8200, 10, 10, False, None, 768, 11),  # K % 128
+        # smaller budgets at the CIFAR geometry, over more than one
+        # image block: tight groups of two images, then one image a loop
+        # iteration (padded output groups)
+        (5, 32, 32, 3, 6, 1100, 14, 13, True, 6 << 20, 256, 5),  # K % tile
+        (5, 32, 32, 3, 6, 300, 14, 13, True, 3 << 20, 128, 3),
+        (7, 32, 32, 3, 6, 200, 14, 13, True, 2 << 20, 128, 2),  # K % 128
+        (3, 16, 16, 1, 2, 136, 5, 5, False, 700_000, 128, 2),   # cells=9
+    ],
+)
+def test_conv_rectify_pool_pallas_tiles_over_filter_blocks(
+    monkeypatch, n, h, w, c, patch, k, pool, stride, normalize, budget,
+    tk, k_blocks,
+):
+    """A bank too wide for the VMEM budget runs as filter blocks of tk
+    lanes, padded with zero filters to whole tiles, and still returns
+    (N, gy, gx, 2K) with the positive half first."""
+    import keystone_tpu.ops.pallas_kernels as pk
+
+    if budget is not None:
+        monkeypatch.setattr(pk, "_FUSED_CONV_VMEM_BUDGET", budget)
+    pos_h, pos_w = h - patch + 1, w - patch + 1
+    cells = ((pos_h - pool) // stride + 1) * ((pos_w - pool) // stride + 1)
+    geometry = pk._fused_conv_geometry(
+        -(-(pos_h * pos_w) // 16) * 16, -(-(c * patch * patch) // 128) * 128,
+        k, cells)
+    assert geometry[0] > 0 and geometry[3] == tk, geometry
+    assert -(-k // tk) == k_blocks
+
+    rng = np.random.default_rng(7)
+    x = jnp.asarray(rng.random(size=(n, h, w, c)).astype(np.float32))
+    kern = jnp.asarray(
+        rng.normal(size=(patch, patch, c, k)).astype(np.float32))
+    colsum = jnp.asarray(rng.normal(size=(k,)).astype(np.float32))
+    bias = jnp.asarray(rng.normal(size=(k,)).astype(np.float32))
+    alpha, max_val = 0.25, 0.0
+
+    want = np.asarray(pk.conv_rectify_pool_reference(
+        x, kern, colsum, bias, alpha, max_val, pool, stride, normalize))
+    got = np.asarray(pk.conv_rectify_pool_pallas(
+        x, pk.hwio_to_cmajor(kern), colsum, bias, alpha, max_val, pool,
+        stride, normalize, patch, interpret=True))
+    assert got.shape == want.shape == (n,) + want.shape[1:3] + (2 * k,)
+    scale = np.abs(want).max()
+    np.testing.assert_allclose(got, want, atol=2e-2 * scale)
+    # the halves are where the contract puts them: the positive half of
+    # filter f at column f, its negative half at column K + f. Where the
+    # conv output is far from zero exactly one half is above max_val.
+    conv = np.asarray(pk.folded_conv_reference(
+        x, kern, colsum, bias, normalize))
+    strongly_pos = (conv > alpha + 0.5).all(axis=(1, 2))      # (n, k)
+    strongly_neg = (conv < -alpha - 0.5).all(axis=(1, 2))
+    assert strongly_pos.any() and strongly_neg.any()
+    pos_half, neg_half = got[..., :k], got[..., k:]
+
+    def cell_of(mask):  # (n, k) -> every pooled cell of those filters
+        return np.broadcast_to(mask[:, None, None, :], pos_half.shape)
+
+    assert (pos_half[cell_of(strongly_pos)] > 0).all()
+    assert (neg_half[cell_of(strongly_pos)] == 0).all()
+    assert (neg_half[cell_of(strongly_neg)] > 0).all()
+    assert (pos_half[cell_of(strongly_neg)] == 0).all()
+
+
+def test_fused_conv_geometry_tiles_the_documented_width():
+    """RandomPatchCifar's documented 10,000 filters (posp 736, dp 128,
+    cells 4): eligible, as filter tiles inside the budget; the widths
+    that fit whole keep their single block."""
+    import keystone_tpu.ops.pallas_kernels as pk
+
+    b, g, rows, tk = pk._fused_conv_geometry(736, 128, 10000, 4)
+    assert b > 0 and b % g == 0 and rows % 8 == 0
+    assert tk % 128 == 0 and tk < 10000
+    assert pk._fused_conv_vmem_bytes(
+        736, 128, b, g, rows, tk, 2 * tk, 2) <= 10 * (1 << 20)
+    assert pk._fused_conv_block_images(736, 128, 10000, 4) == b
+    for k, want in ((16, 22), (64, 22), (256, 14)):
+        assert pk._fused_conv_geometry(736, 128, k, 4) == (want, 2, 8, k)
+    # the tiles are evened out: three of 384 cover 1,100 filters
+    assert pk._fused_conv_geometry(736, 128, 1100, 4)[3] == 384
+    # nothing fits: not one image at the narrowest tile; no pooled cell
+    assert pk._fused_conv_geometry(1 << 16, 128, 10000, 4)[0] == 0
+    assert pk._fused_conv_geometry(736, 128, 10000, 0)[0] == 0
+
+
 def test_conv_fusion_peephole_matches_stagewise():
     """The _ConvRectifyPoolStage peephole (off-TPU: reference path) must
     equal running Convolver, SymmetricRectifier, Pooler stage-by-stage
@@ -217,7 +307,7 @@ def test_conv_fused_stage_ineligible_fallback_reconstructs_hwio(monkeypatch):
     )
     monkeypatch.setattr(
         "keystone_tpu.ops.pallas_kernels._fused_conv_geometry",
-        lambda *a, **k: (0, 1, 8),
+        lambda *a, **k: (0, 1, 8, 8),
     )
     key, params, fn = stage.fuse()
     assert key[-1] is True  # fused flag baked into the program key
@@ -314,6 +404,46 @@ def test_fused_conv_canary_records_the_designed_demotion(monkeypatch):
     assert calls["n"] == 1, calls["n"]
     assert list(pk._fused_conv_canary.items()) == [
         ((16, 16, 3, 8, 5, 4, True, 5), False)]
+
+
+@pytest.mark.parametrize("eligible", [True, False],
+                         ids=["traced", "demoted"])
+def test_fused_conv_counts_each_program_traced(monkeypatch, eligible):
+    """`pallas.fused_conv.traced` counts the programs that staged the
+    Mosaic call and `pallas.fused_conv.demoted` those the canary's one
+    designed demotion sent to XLA: once per program traced, not per
+    run, and neither moves where the fused path is off."""
+    import jax
+
+    import keystone_tpu.ops.pallas_kernels as pk
+    from keystone_tpu.telemetry import metrics_delta
+
+    imgs, kern, colsum, bias = _canary_case(8, 3)
+    want = np.asarray(pk.conv_rectify_pool_reference(
+        imgs, kern, colsum, bias, 0.1, 0.0, 5, 4, True))
+
+    def kernel(*a, **kw):
+        if not eligible:
+            raise pk.FusedConvIneligibleError("no block fits (simulated)")
+        return jnp.asarray(want)
+
+    monkeypatch.setattr(pk, "conv_rectify_pool_pallas", kernel)
+    monkeypatch.setattr(pk, "_fused_conv_canary", {})
+    program = jax.jit(lambda x: pk.conv_rectify_pool(
+        x, kern, colsum, bias, 0.1, 0.0, 5, 4, True))
+    with metrics_delta() as off:
+        program(imgs)
+    assert not any(k.startswith("pallas.fused_conv") for k in off.counters())
+
+    monkeypatch.setattr(pk, "use_fused_conv", lambda: True)
+    program = jax.jit(lambda x: pk.conv_rectify_pool(
+        x, kern, colsum, bias, 0.1, 0.0, 5, 4, True))
+    with metrics_delta() as on:
+        for _ in range(3):  # one trace, three runs
+            np.testing.assert_allclose(
+                np.asarray(program(imgs)), want, rtol=1e-6, atol=1e-6)
+    assert on.counter("pallas.fused_conv.traced") == (1 if eligible else 0)
+    assert on.counter("pallas.fused_conv.demoted") == (0 if eligible else 1)
 
 
 def test_fused_conv_canary_multihost_verdict_is_broadcast(monkeypatch):

@@ -70,29 +70,53 @@ def _aval(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _conv_avals(n, image_dtype, sharding):
+def _conv_avals(n, image_dtype, sharding, k=K):
     return (
         _aval((n, H, W, C), image_dtype, sharding),
-        _aval((C * PATCH * PATCH, K), jnp.float32, sharding),
-        _aval((K,), jnp.float32, sharding),
-        _aval((K,), jnp.float32, sharding),
+        _aval((C * PATCH * PATCH, k), jnp.float32, sharding),
+        _aval((k,), jnp.float32, sharding),
+        _aval((k,), jnp.float32, sharding),
     )
 
 
-@pytest.mark.parametrize("n,image_dtype", [
-    (MICROBATCH, jnp.float32),
-    (MICROBATCH + 3, jnp.float32),  # ragged: a padded tail block
-    (MICROBATCH, jnp.bfloat16),     # the precision planner's boundary
-], ids=["f32", "ragged", "bf16"])
-def test_fused_conv_compiles_at_the_cifar_geometry(one_chip, n, image_dtype):
+@pytest.mark.parametrize("n,image_dtype,k", [
+    (MICROBATCH, jnp.float32, K),
+    (MICROBATCH + 3, jnp.float32, K),  # ragged: a padded tail block
+    (MICROBATCH, jnp.bfloat16, K),     # the precision planner's boundary
+    # the benchmark's cell (benchmark/configs/random_patch_cifar.json):
+    # the documented 10,000 filters at a microbatch of 32, which runs
+    # as filter tiles (16 image blocks of 2 by 20 filter blocks of 512)
+    (32, jnp.float32, 10000),
+], ids=["f32", "ragged", "bf16", "10000_filters"])
+def test_fused_conv_compiles_at_the_cifar_geometry(
+        one_chip, n, image_dtype, k):
     from keystone_tpu.ops import conv_rectify_pool_pallas
 
     def fn(images, g, colsum, bias):
         return conv_rectify_pool_pallas(
             images, g, colsum, bias, ALPHA, 0.0, POOL, STRIDE, True, PATCH)
 
-    hlo = _compile(fn, *_conv_avals(n, image_dtype, one_chip))
+    hlo = _compile(fn, *_conv_avals(n, image_dtype, one_chip, k))
     assert "tpu_custom_call" in hlo
+    # no (position x filter) tensor leaves the kernel: nothing in the
+    # program is as wide as one image's conv outputs
+    assert f"{POS},{POS},{k}]" not in hlo and f"{POS * POS},{k}]" not in hlo
+    # the benchmark's `fused_conv_ms_per_fit` finds the call by the name
+    # the device trace prints for it (an op's HLO text, reduced by
+    # `trace_reduce.op_name`) inside the fused program `jit_per_shard`
+    import json
+    import os
+    import re
+
+    from benchmark.trace_reduce import op_name
+
+    with open(os.path.join(
+            os.path.dirname(__file__), "..", "benchmark", "layer_metrics",
+            "fused_conv_ms_per_fit.json")) as f:
+        pattern = re.compile(json.load(f)["args"]["pattern"])
+    (call,) = [line.strip() for line in hlo.splitlines()
+               if "tpu_custom_call" in line and " = " in line]
+    assert pattern.search("jit_per_shard/" + op_name(call)), op_name(call)
 
 
 @pytest.mark.parametrize("block_n", [3, None],
