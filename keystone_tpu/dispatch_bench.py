@@ -141,29 +141,24 @@ def _build_random_patch_cifar():
 
 
 def _build_timit():
-    """TimitPipeline (pipelines/timit.py): CosineRandomFeatures → Cacher
-    → BlockLeastSquares → MaxClassifier over pre-featurized frames."""
+    """TimitPipeline (pipelines/timit.py): gather of CosineRandomFeatures
+    branches → VectorCombiner → Cacher → BlockLeastSquares (a block a
+    branch) → MaxClassifier over pre-featurized frames."""
     from .data.dataset import Dataset
-    from .nodes.learning import BlockLeastSquaresEstimator
-    from .nodes.stats import CosineRandomFeatures
-    from .nodes.util import Cacher, ClassLabelIndicatorsFromInt, MaxClassifier
+    from .loaders.csv_loader import LabeledData
+    from .pipelines.timit import TimitConfig, build_pipeline
 
     rng = np.random.default_rng(2)
-    dim, nf, k = 24, 48, 6
+    dim, k = 24, 6
     X = rng.normal(size=(64, dim)).astype(np.float32)
     Xt = rng.normal(size=(32, dim)).astype(np.float32)
     y = rng.integers(0, k, 64).astype(np.int32)
 
-    featurizer = (
-        CosineRandomFeatures(dim, nf, gamma=0.05, seed=0).to_pipeline()
-        >> Cacher("timit-features")
-    )
-    train = Dataset.from_numpy(X)
-    labels = ClassLabelIndicatorsFromInt(k)(Dataset.from_numpy(y)).get()
-    predictor = featurizer.and_then(
-        BlockLeastSquaresEstimator(nf, num_iter=1, lam=1e-3), train, labels
-    ) >> MaxClassifier()
-    return predictor, train, Dataset.from_numpy(Xt)
+    train = LabeledData.from_arrays(y, X)
+    predictor = build_pipeline(train, TimitConfig(
+        num_cosines=2, num_cosine_features=24, gamma=0.05, num_epochs=1,
+        lam=1e-3, num_classes=k))
+    return predictor, train.data, Dataset.from_numpy(Xt)
 
 
 def _build_linear_pixels():

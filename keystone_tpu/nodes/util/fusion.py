@@ -16,6 +16,8 @@ recover it with scan-over-chunks inside `shard_map`.
 
 from __future__ import annotations
 
+import itertools
+import math
 import threading
 from typing import Optional, Sequence
 
@@ -293,7 +295,22 @@ class _GatherConcatStage(Transformer):
 
     @property
     def label(self) -> str:
-        return "Gather[" + " | ".join(b.label for b in self.branches) + "]"
+        """Branch labels in order, a run of equal ones counted: the
+        label is a scope on every op of the program, and fifty equal
+        branches spelled out would be a kilobyte on each."""
+        runs = [(label, len(list(run))) for label, run in
+                itertools.groupby(b.label for b in self.branches)]
+        return "Gather[" + " | ".join(
+            label if n == 1 else f"{n} x {label}" for label, n in runs) + "]"
+
+    def abstract_apply(self, elem):
+        """The branches' output elements side by side on the last axis."""
+        from ...workflow.operators import fitted_elem_fn
+
+        outs = [fitted_elem_fn(b)(elem) for b in self.branches]
+        width = sum(o.shape[-1] for o in outs)
+        return jax.ShapeDtypeStruct(
+            tuple(outs[0].shape[:-1]) + (width,), outs[0].dtype)
 
     @property
     def chunkable(self) -> bool:
@@ -541,6 +558,7 @@ class FusedBatchTransformer(Transformer):
             cache[key] = program
         from ...telemetry import counter, dispatch, span
 
+        self._count_gather_bytes(data)
         swap = self._kernel_swap(statics)
         if swap is not None:
             # the planned chain megakernel is live in this program:
@@ -560,6 +578,36 @@ class FusedBatchTransformer(Transformer):
         # the whole chain is ONE executed program
         with dispatch(self.label, rows=data.count):
             return data.with_data(program(flat, data.array, data.mask))
+
+    def _flat_stages(self):
+        for s in self.stages:
+            if isinstance(s, FusedBatchTransformer):
+                yield from s._flat_stages()
+            else:
+                yield s
+
+    def _count_gather_bytes(self, data):
+        """`gather.concat_bytes`: what the chain's gather stages write
+        when they put their branches side by side. Each branch's output
+        is a buffer of its own first and the combined rows are written
+        from those, a chunk at a time (the v5e's compiler fuses that
+        write into the chunk's slot of the loop's output; PERF.md
+        section 6, PR 28). Counted from shapes, per call."""
+        stages = list(self._flat_stages())
+        last = max((i for i, s in enumerate(stages)
+                    if isinstance(s, _GatherConcatStage)), default=-1)
+        if last < 0:
+            return
+        from ...telemetry import counter
+        from ...workflow.operators import fitted_elem_fn
+
+        elem = jax.ShapeDtypeStruct(data.array.shape[1:], data.array.dtype)
+        per_row = 0
+        for s in stages[:last + 1]:  # what follows the last gather is not priced
+            elem = fitted_elem_fn(s)(elem)
+            if isinstance(s, _GatherConcatStage):
+                per_row += math.prod(elem.shape) * elem.dtype.itemsize
+        counter("gather.concat_bytes").inc(data.padded_count * per_row)
 
     def warmup(self, element, count: int, mesh=None) -> Optional[str]:
         """AOT-compile this chain's batch program from a static spec —

@@ -8,8 +8,10 @@ import pytest
 def test_timit_pipeline():
     from keystone_tpu.pipelines.timit import TimitConfig, run
 
-    r = run(TimitConfig(num_cosines=512, n_synth=1500, synth_dim=128, num_classes=8,
-                        block_size=256))
+    # two branches of 256 cosine features, a solver block each; the
+    # defaults are the source's 50 x 4,096, 5 epochs, lambda 0
+    r = run(TimitConfig(num_cosines=2, num_cosine_features=256, n_synth=1500,
+                        synth_dim=128, num_classes=8))
     assert r["test_accuracy"] > 0.9, r["summary"]
 
 

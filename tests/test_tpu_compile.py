@@ -261,3 +261,36 @@ def test_fused_operator_compiles_per_shard_on_four_chips(
     finally:
         for key in set(_PROGRAM_CACHE) - before:
             del _PROGRAM_CACHE[key]
+
+
+def test_the_gathered_cosine_branches_fit_the_chip_at_timit_fit_s_size(one_chip):
+    """`timit_fit`'s featurizer as the optimizer builds it: the gather of
+    four 4,096-wide `CosineRandomFeatures` branches over 65,536 frames
+    of 440 dimensions, one fused program. Its output is the (n, 16,384)
+    features, 4.29 GB; the branches' chunks are its only temporaries, so
+    no second (n, d) array is held beside it, and every op carries the
+    gather's scope and its branch's."""
+    from keystone_tpu.nodes.stats import CosineRandomFeatures
+    from keystone_tpu.nodes.util.fusion import (
+        FusedBatchTransformer,
+        _GatherConcatStage,
+    )
+
+    n, dim, branches, width = 65536, 440, 4, 4096
+    nodes = [CosineRandomFeatures(dim, 8, 0.05555, seed=i)
+             for i in range(branches)]
+    for node in nodes:  # shapes stand in for the random parameters
+        node.W = _aval((dim, width), jnp.float32, one_chip)
+        node.b = _aval((width,), jnp.float32, one_chip)
+    op = FusedBatchTransformer([_GatherConcatStage(nodes)])
+    statics, flat, treedef, fns = op._decompose()
+    program = op._build_program(None, 1, n, treedef, fns, statics=statics)
+    compiled = program.lower(
+        flat, _aval((n, dim), jnp.float32, one_chip),
+        _aval((n,), jnp.bool_, one_chip)).compile()
+    memory = compiled.memory_analysis()
+    assert memory.output_size_in_bytes == 4 * n * branches * width
+    assert memory.temp_size_in_bytes < 4 * n * branches * width // 8
+    hlo = compiled.as_text()
+    assert hlo.count(
+        "ks.Gather[4xCosineRandomFeatures]/ks.CosineRandomFeatures") >= branches
