@@ -19,6 +19,17 @@ treeReduce. Epochs are an outer `lax.scan`. Mean-centering (the
 reference's per-block StandardScaler) is applied once up front with
 masking so padded rows stay zero.
 
+`BlockLeastSquaresEstimator.fit` runs the same sweep as a host loop of
+donated `_bcd_epoch` programs, one an epoch. A block's Gram ``Xb'Xb +
+lam I`` and its Cholesky factor depend on nothing an epoch changes (only
+the residual moves), so a fit of several epochs forms and factors each
+block's Gram in its first sweep, keeps the stacked upper factors
+``(num_blocks, B, B)`` (B/n of one copy of X), and every later sweep is
+the correlation, two triangular solves on the kept factor and the
+residual updates. A one-epoch fit keeps nothing. `_bcd_fit` (the
+one-program scan form, which forms every Gram in every epoch) is the
+numerics reference of both.
+
 The estimator declares optimizer weight 3·numIter+1 — the number of
 passes over the input — feeding auto-caching (BlockLinearMapper.scala:205-210).
 """
@@ -137,37 +148,69 @@ def _bcd_prepare(X, Y, mask, block_size: int, num_blocks: int, center: bool,
 
 @partial(
     jax.jit,
-    static_argnames=("block_size", "num_blocks"),
+    static_argnames=("block_size", "num_blocks", "keep_factors"),
     donate_argnums=(0, 1),
 )
-def _bcd_epoch(W, R, Xc, lam, block_size: int, num_blocks: int):
+def _bcd_epoch(W, R, Xc, lam, block_size: int, num_blocks: int, *,
+               factors=None, keep_factors: bool = False):
     """One BCD sweep over all feature blocks with the model W and
     residual R DONATED: XLA reuses their buffers for the outputs, so the
     per-epoch host loop updates solver state in place instead of
     re-allocating (num_blocks, B, k) + (n, k) of HBM every epoch. Same
     block_step arithmetic as `_bcd_fit_impl`'s inner scan, hence
-    allclose-identical fits (tests/test_solvers.py)."""
+    allclose-identical fits (tests/test_solvers.py).
+
+    A block's Gram ``Xb'Xb + lam I`` depends on nothing an epoch changes
+    (only R moves), so a fit of several epochs forms and factors it once.
+    One function, three traces, all the XLA module `jit__bcd_epoch`:
+
+    - ``factors=None, keep_factors=False``: the sweep of a one-epoch
+      fit. Forms, factors and solves each block; returns ``(W, R)``.
+    - ``factors=None, keep_factors=True``: the first sweep of a longer
+      fit. The same arithmetic, and the scan also emits each block's
+      upper Cholesky factor (what ``solve(assume_a="pos")`` computes
+      inside): returns ``(W, R, factors)``, factors
+      ``(num_blocks, B, B)``.
+    - ``factors`` given: every later sweep. The factors are a scanned
+      input, NOT donated (each later epoch reads them again); a block
+      step is the residual add-back, the correlation ``Xb'R1``,
+      ``cho_solve`` on the kept factor and the residual update. No Gram
+      and no factorization; returns ``(W, R)``.
+
+    ``cho_factor`` + ``cho_solve`` is what ``solve(assume_a="pos")``
+    runs, on the same operands in the same order, so the kept-factor
+    epochs give the W of the factor-free ones."""
     with jax.default_matmul_precision("highest"):
         eye = lam * jnp.eye(block_size, dtype=Xc.dtype)
 
-        def block_step(carry, b_idx):
+        def block_step(carry, xs):
             W, R = carry
+            b_idx, factor = xs  # factor is None where none was handed in
             Xb = jax.lax.dynamic_slice_in_dim(
                 Xc, b_idx * block_size, block_size, axis=1)
             Wb = W[b_idx]
             with jax.named_scope("ks.bcd.residual"):
                 R1 = R + Xb @ Wb
             with jax.named_scope("ks.bcd.gram"):
-                G = Xb.T @ Xb + eye      # all-reduce over the data axis
+                if factor is None:
+                    G = Xb.T @ Xb + eye  # all-reduce over the data axis
                 C = Xb.T @ R1            # all-reduce over the data axis
+            formed = None
+            if factor is None and keep_factors:
+                with jax.named_scope("ks.bcd.factor"):
+                    formed = factor = jax.scipy.linalg.cho_factor(G)[0]
             with jax.named_scope("ks.bcd.solve"):
-                Wb_new = jax.scipy.linalg.solve(G, C, assume_a="pos")
+                if factor is None:
+                    Wb_new = jax.scipy.linalg.solve(G, C, assume_a="pos")
+                else:
+                    Wb_new = jax.scipy.linalg.cho_solve((factor, False), C)
             with jax.named_scope("ks.bcd.residual"):
                 R2 = R1 - Xb @ Wb_new
-            return (W.at[b_idx].set(Wb_new), R2), None
+            return (W.at[b_idx].set(Wb_new), R2), formed
 
-        (W, R), _ = jax.lax.scan(block_step, (W, R), jnp.arange(num_blocks))
-        return W, R
+        (W, R), formed = jax.lax.scan(
+            block_step, (W, R), (jnp.arange(num_blocks), factors))
+        return (W, R) if formed is None else (W, R, formed)
 
 
 @jax.jit
@@ -363,15 +406,32 @@ class BlockLeastSquaresEstimator(LabelEstimator):
                 )
             # a host scalar: `jnp.asarray` would launch a convert program
             lam = np.asarray(self.lam, X.dtype)
+            # A block's Gram and its Cholesky factor do not change between
+            # epochs, so a fit of several forms them in its first sweep
+            # and every later sweep solves on the kept factors
+            # (num_blocks x B x B: B/n of one copy of X). A one-epoch fit
+            # keeps nothing and runs the factor-free program.
+            factors = None
             for i in range(self.num_iter):
                 # the spans measure the host-side dispatch of one
                 # donated-buffer sweep; device time pipelines
                 # asynchronously and lands on whoever pulls the model
                 # (see OBSERVABILITY.md)
+                reusing = factors is not None
                 with span("bcd_epoch", cat="step", layer="solver", iter=i,
-                          blocks=num_blocks), dispatch("_bcd_epoch"):
-                    W, R = _bcd_epoch(W, R, Xc, lam, bs, num_blocks)
+                          blocks=num_blocks,
+                          gram="reused" if reusing else "formed"), \
+                        dispatch("_bcd_epoch"):
+                    W, R, *kept = _bcd_epoch(
+                        W, R, Xc, lam, bs, num_blocks, factors=factors,
+                        keep_factors=i == 0 and self.num_iter > 1)
+                if kept:
+                    (factors,) = kept
                 counter("solver.steps").inc()
+                if reusing:
+                    counter("solver.gram_blocks_reused").inc(num_blocks)
+                else:
+                    counter("solver.gram_blocks_formed").inc(num_blocks)
             with dispatch("_bcd_finalize"):
                 W, b = _bcd_finalize(W, xm, ym)
         return BlockLinearMapper(W, b if self.fit_intercept else None, self.block_size)

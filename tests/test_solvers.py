@@ -524,36 +524,207 @@ def test_sparse_lbfgs_iterative_dp_sharded_agrees():
 # reference / fused-pipeline path).
 
 
-def test_bcd_donated_epochs_match_scan_form(problem):
-    """BlockLeastSquaresEstimator now loops a donated `_bcd_epoch`; the
-    result must be allclose-identical to the one-program `_bcd_fit` scan
-    (same block_step arithmetic, same op order)."""
+# (block size, epochs, lambda, intercept, rows): 24 features in blocks of 7
+# pad the last block; 197 rows over 8 devices leave 3 padded rows to mask.
+# The first two are the cases this test had as a loop; the others put the
+# kept Cholesky factors of a several-epoch fit through the same comparison.
+BCD_CASES = [
+    pytest.param(8, 3, 0.5, True, 200, id="3-epochs-lam0.5"),
+    pytest.param(7, 2, 0.5, False, 200, id="2-epochs-padded-block-no-intercept"),
+    pytest.param(8, 1, 0.5, True, 200, id="1-epoch"),
+    pytest.param(7, 3, 0.0, True, 200, id="3-epochs-lam0-padded-block"),
+    pytest.param(7, 5, 0.0, True, 200, id="5-epochs-lam0-padded-block"),
+    pytest.param(7, 3, 0.5, True, 200, id="3-epochs-lam0.5-padded-block"),
+    pytest.param(7, 5, 0.5, True, 200, id="5-epochs-lam0.5-padded-block"),
+    pytest.param(8, 3, 0.0, True, 197, id="3-epochs-lam0-row-mask"),
+    pytest.param(8, 5, 0.0, True, 197, id="5-epochs-lam0-row-mask"),
+    pytest.param(8, 3, 0.5, True, 197, id="3-epochs-lam0.5-row-mask"),
+    pytest.param(8, 5, 0.5, True, 197, id="5-epochs-lam0.5-row-mask"),
+]
+
+
+def _bcd_inputs(problem, bs, rows):
+    """The estimator's own view of the problem: X padded to whole blocks,
+    the row mask, and the block count."""
     import jax.numpy as jnp
 
-    from keystone_tpu.nodes.learning import BlockLeastSquaresEstimator
+    X, Y = problem
+    data, labels = Dataset(X[:rows]), Dataset(Y[:rows])
+    nb = -(-X.shape[1] // bs)
+    Xp = jnp.pad(data.array, [(0, 0), (0, nb * bs - X.shape[1])])
+    mask = data.mask_as(Xp.dtype)
+    if rows % 8:
+        assert float(mask.sum()) == rows < Xp.shape[0]
+    return data, labels, Xp, mask, nb
+
+
+@pytest.mark.parametrize("bs,iters,lam,center,rows", BCD_CASES)
+def test_bcd_donated_epochs_match_scan_form(problem, bs, iters, lam, center,
+                                            rows):
+    """BlockLeastSquaresEstimator loops a donated `_bcd_epoch`, which in a
+    fit of several epochs forms and factors each block's Gram in the first
+    sweep only; the result must be allclose-identical to the one-program
+    `_bcd_fit` scan (same block_step arithmetic, same op order), which
+    forms and factors every Gram in every epoch."""
+    import jax.numpy as jnp
+
     from keystone_tpu.nodes.learning.block_ls import _bcd_fit
 
-    X, Y = problem
-    for bs, iters, center in ((8, 3, True), (7, 2, False)):
-        est = BlockLeastSquaresEstimator(
-            block_size=bs, num_iter=iters, lam=0.5, fit_intercept=center)
-        data, labels = Dataset(X), Dataset(Y)
-        model = est.fit(data, labels)
-        nb = -(-X.shape[1] // bs)
-        d_pad = nb * bs
-        Xp = data.array
-        if d_pad != X.shape[1]:
-            Xp = jnp.pad(Xp, [(0, 0), (0, d_pad - X.shape[1])])
-        Wref, bref = _bcd_fit(
-            Xp, labels.array, data.mask.astype(Xp.dtype),
-            jnp.asarray(0.5, Xp.dtype), bs, nb, iters, center,
-            x_sharding=None,
-        )
+    data, labels, Xp, mask, nb = _bcd_inputs(problem, bs, rows)
+    model = BlockLeastSquaresEstimator(
+        block_size=bs, num_iter=iters, lam=lam, fit_intercept=center,
+    ).fit(data, labels)
+    Wref, bref = _bcd_fit(
+        Xp, labels.array, mask, jnp.asarray(lam, Xp.dtype), bs, nb, iters,
+        center, x_sharding=None,
+    )
+    np.testing.assert_allclose(
+        np.asarray(model.W), np.asarray(Wref), atol=1e-5, rtol=1e-5)
+    if center:
         np.testing.assert_allclose(
-            np.asarray(model.W), np.asarray(Wref), atol=1e-5, rtol=1e-5)
-        if center:
-            np.testing.assert_allclose(
-                np.asarray(model.b), np.asarray(bref), atol=1e-5, rtol=1e-5)
+            np.asarray(model.b), np.asarray(bref), atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("bs,iters,lam,center,rows", BCD_CASES)
+def test_bcd_kept_factors_equal_factor_free_epochs_bit_for_bit(
+        problem, bs, iters, lam, center, rows):
+    """On the CPU the estimator's W and b are, bit for bit, those of a loop
+    of factor-free `_bcd_epoch` sweeps (the program every epoch ran before
+    the factors were kept): `solve(assume_a="pos")` is `cho_factor` and
+    `cho_solve` on the same operands."""
+    from keystone_tpu.nodes.learning.block_ls import (
+        _bcd_epoch,
+        _bcd_finalize,
+        _bcd_prepare,
+    )
+
+    data, labels, Xp, mask, nb = _bcd_inputs(problem, bs, rows)
+    model = BlockLeastSquaresEstimator(
+        block_size=bs, num_iter=iters, lam=lam, fit_intercept=center,
+    ).fit(data, labels)
+    Xc, R, xm, ym, W = _bcd_prepare(Xp, labels.array, mask, bs, nb, center)
+    for _ in range(iters):
+        W, R = _bcd_epoch(W, R, Xc, np.asarray(lam, Xp.dtype), bs, nb)
+    W, b = _bcd_finalize(W, xm, ym)
+    np.testing.assert_array_equal(np.asarray(model.W), np.asarray(W))
+    if center:
+        np.testing.assert_array_equal(np.asarray(model.b), np.asarray(b))
+
+
+@pytest.fixture(scope="module")
+def bcd_epoch_traces():
+    """`_bcd_epoch`'s three traces, lowered at n=16, B=4, k=3 over two
+    blocks, by name."""
+    import jax.numpy as jnp
+
+    from keystone_tpu.nodes.learning.block_ls import _bcd_epoch
+
+    X = jnp.ones((16, 8), jnp.float32)
+    R = jnp.ones((16, 3), jnp.float32)
+    W = jnp.zeros((2, 4, 3), jnp.float32)
+    F = jnp.zeros((2, 4, 4), jnp.float32)
+    lam = np.float32(1.0)
+    return {
+        "one-epoch": _bcd_epoch.lower(W, R, X, lam, 4, 2),
+        "first-of-several": _bcd_epoch.lower(
+            W, R, X, lam, 4, 2, keep_factors=True),
+        "later": _bcd_epoch.lower(W, R, X, lam, 4, 2, factors=F),
+    }
+
+
+def _out_shapes(lowered):
+    import jax
+
+    return [leaf.shape for leaf in jax.tree_util.tree_leaves(lowered.out_info)]
+
+
+def _gram_dots(text):
+    """The lowered program's `dot_general`s that produce a (B, B) = (4, 4)
+    matrix: the Gram is the only product of that shape."""
+    return [line for line in text.splitlines()
+            if "dot_general" in line and "-> tensor<4x4xf32>" in line]
+
+
+def test_bcd_one_epoch_trace_keeps_no_factors(bcd_epoch_traces):
+    lowered = bcd_epoch_traces["one-epoch"]
+    # W and R, no (num_blocks, B, B)
+    assert _out_shapes(lowered) == [(2, 4, 3), (16, 3)]
+    text = lowered.as_text(debug_info=True)
+    assert len(_gram_dots(text)) == 1 and "cholesky" in text
+    assert "ks.bcd.factor" not in text
+
+
+def test_bcd_first_epoch_of_several_hands_the_factors_back(bcd_epoch_traces):
+    lowered = bcd_epoch_traces["first-of-several"]
+    assert _out_shapes(lowered) == [(2, 4, 3), (16, 3), (2, 4, 4)]
+    text = lowered.as_text(debug_info=True)
+    assert len(_gram_dots(text)) == 1 and "cholesky" in text
+    for scope in ("ks.bcd.gram", "ks.bcd.factor", "ks.bcd.solve",
+                  "ks.bcd.residual"):
+        assert scope in text, scope
+
+
+def test_bcd_later_epoch_trace_forms_no_gram_and_factors_nothing(
+        bcd_epoch_traces):
+    import jax
+
+    lowered = bcd_epoch_traces["later"]
+    assert _out_shapes(lowered) == [(2, 4, 3), (16, 3)]
+    text = lowered.as_text(debug_info=True)
+    assert _gram_dots(text) == [] and "cholesky" not in text
+    assert "triangular_solve" in text
+    # the correlation Xb'R1 stays under `ks.bcd.gram`, the triangular
+    # solves under `ks.bcd.solve`
+    for scope in ("ks.bcd.gram", "ks.bcd.solve", "ks.bcd.residual"):
+        assert scope in text, scope
+    assert "ks.bcd.factor" not in text
+    # only W and R are donated: every later epoch reads the factors again
+    donated = [a.donated for a in jax.tree_util.tree_leaves(lowered.args_info)]
+    assert donated == [True, True, False, False, False]
+
+
+@pytest.mark.parametrize("trace", ["one-epoch", "first-of-several", "later"])
+def test_bcd_epoch_traces_lower_under_the_module_name_the_readers_match(
+        bcd_epoch_traces, trace):
+    """The benchmark's `solver_ms_per_fit` and `bcd_roofline` find the
+    solver's device time by this pattern over XLA module names
+    (`benchmark/layer_metrics/solver_ms_per_fit.json`); spelled out here so
+    that a rename fails on the CPU and not on the chip."""
+    import re
+
+    text = bcd_epoch_traces[trace].as_text()
+    (module,) = re.findall(r"^module @(\S+)", text, flags=re.M)
+    assert module == "jit__bcd_epoch"
+    assert re.match(r"^jit__bcd_(prepare|epoch|finalize|fit)$", module)
+
+
+@pytest.mark.parametrize("iters", [1, 5])
+def test_bcd_fit_counts_grams_formed_and_reused(problem, iters):
+    """A fit forms each block's Gram once and reuses it in every later
+    epoch; it runs one program an epoch and counts one solver step an
+    epoch, as before the factors were kept."""
+    from keystone_tpu.telemetry import registry, trace_run
+
+    X, Y = problem
+    data, labels = Dataset(X), Dataset(Y)
+    est = BlockLeastSquaresEstimator(block_size=8, num_iter=iters, lam=0.5)
+    est.fit(data, labels)  # compiled before the count
+    before = {k: c.value for k, c in registry().counters.items()}
+    with trace_run() as tr:
+        est.fit(data, labels)
+    moved = {k: c.value - before.get(k, 0.0)
+             for k, c in registry().counters.items()}
+    blocks = 3
+    assert moved["solver.gram_blocks_formed"] == blocks
+    assert moved.get("solver.gram_blocks_reused", 0.0) == (iters - 1) * blocks
+    assert moved["solver.steps"] == iters
+    # the mask's conversion, `_bcd_prepare`, one `_bcd_epoch` an epoch,
+    # `_bcd_finalize`: no program of its own forms the factors (24
+    # features are whole blocks of 8, so there is no `pad`)
+    assert moved["dispatch.programs_executed"] == 3 + iters
+    epochs = [s for s in tr.spans if s.name == "bcd_epoch"]
+    assert [s.args["gram"] for s in epochs] == ["formed"] + ["reused"] * (iters - 1)
+    assert [s.args["iter"] for s in epochs] == list(range(iters))
 
 
 def test_lbfgs_donated_steps_match_scan_form(problem):
