@@ -24,8 +24,7 @@ device-count-multiple and a ragged example count. A host-bucketed
 chunking workload is measured alongside, since the example pipelines'
 device datasets never exercise the ragged-tail path.
 
-Used by ``bench.py`` (the ``compile_count`` tier) and
-tests/test_compile.py (the acceptance gate).
+Used by tests/test_compile.py (the acceptance gate).
 """
 
 from __future__ import annotations
@@ -184,7 +183,7 @@ def measure_host_chunk_compiles(
 def compile_count_report(
     examples: Tuple[str, ...] = ("MnistRandomFFT", "TimitPipeline"),
 ) -> Dict:
-    """The `compile_count` bench-tier payload: cold-vs-warm compiles and
+    """Cold-vs-warm compiles and
     wall clock per example (at multiple AND ragged counts), plus the
     host-chunk ragged-tail microbench. The acceptance gate: every
     example's warm run performs 0 cold compiles and beats the cold run's

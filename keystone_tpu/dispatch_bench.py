@@ -22,8 +22,8 @@ plans and checks the outputs are identical:
     count, halved tolerant stage boundaries. Its outputs are gated
     against the serial unfused f32 reference with the declared
     tolerance band (`analysis.precision.DEFAULT_BAND_*`), not exact
-    equality — the ``precision_in_band`` verdict `bench.finalize_record`
-    fails records on.
+    equality — the ``precision_in_band`` verdict of
+    `dispatch_count_report`.
   - ``kernel`` — the PR-16 plan: ``megafused`` plus the unified
     planner (enforcement floor dropped to 0 so the small bench
     instances actually plan) with its chain-megakernel axis live:
@@ -37,12 +37,11 @@ plans and checks the outputs are identical:
 Each measurement reports the *fit run* (first application: estimator
 fits + train apply) and the *apply run* (re-applying the fitted
 pipeline to held-out data — the serving path) separately; the apply run
-is the headline programs-per-run number the `dispatch_count` bench tier
-records, and the report carries a per-plan breakdown row per example so
-the 2→1 reduction shows up in ``perf_table.py --trace`` directly. Used
-by ``bench.py`` (the ``dispatch_count`` tier) and by
-tests/test_scheduler.py + tests/test_megafusion.py (the acceptance
-gates + allclose identity against the serial unfused path).
+is the headline programs-per-run number, and the report carries a
+per-plan breakdown row per example so the 2→1 reduction shows up in
+``perf_table.py --trace`` directly. Used by tests/test_scheduler.py +
+tests/test_megafusion.py (the acceptance gates + allclose identity
+against the serial unfused path) and by `scripts/lint.sh`.
 """
 
 from __future__ import annotations
@@ -346,7 +345,7 @@ def dispatch_count_report(
                                  "TimitPipeline"),
     check_outputs: bool = True,
 ) -> Dict:
-    """The `dispatch_count` bench-tier payload: per-example programs per
+    """Per-example programs per
     run under each plan (an explicit per-plan breakdown row per
     example), reduction ratios (apply run, the serving path — headline
     plan is ``megafused``), and an output-identity verdict against the
@@ -407,8 +406,7 @@ def dispatch_count_report(
         # the decision-ledger verdict: a megafused plan that executed its
         # apply run as ONE program must have RECORDED that decision, and
         # the record's prediction must say exactly that — the enforced
-        # plan and the ledger cannot disagree (bench.finalize_record
-        # fails records where they do)
+        # plan and the ledger cannot disagree (`decisions_reconciled`)
         mega_uniq: Dict = {}
         for d in mega.get("decisions") or []:
             if d.get("kind") == "megafusion":

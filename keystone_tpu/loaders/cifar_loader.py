@@ -70,9 +70,10 @@ def synthetic_cifar(
     per-sample weight ~ Uniform(0, confusion), creating genuinely
     ambiguous examples (irreducible class overlap). Together they place
     the best attainable accuracy in a nontrivial, calibratable band —
-    the bench asserts that band so solver-quality regressions (broken
-    centering, BCD convergence, precision) fail loudly instead of
-    hiding behind a trivially separable task."""
+    the benchmark and `chip_smoke.py` assert that band so
+    solver-quality regressions (broken centering, BCD convergence,
+    precision) fail loudly instead of hiding behind a trivially
+    separable task."""
     rng = np.random.default_rng(seed)
     # smooth class templates (low-frequency patterns)
     freqs = rng.normal(size=(num_classes, 4, 2))

@@ -97,8 +97,7 @@ class _ConvRectifyPoolStage(Transformer):
     grid, at any filter count (a bank too wide for VMEM runs as filter
     blocks). ops/pallas_kernels.py; measured 3.7x the XLA path at
     10,000 filters on a TPU v5 lite, kernel alone (PERF.md section 6,
-    PR 27). Default-on for TPU; KEYSTONE_DISABLE_FUSED_CONV=1 forces
-    XLA."""
+    PR 27). Default-on for TPU; XLA elsewhere."""
 
     fusable = True
     precision_tolerance = "tolerant"  # all three fused members are

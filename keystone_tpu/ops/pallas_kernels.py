@@ -287,14 +287,12 @@ def rbf_block(X, Yb, gamma):
 # the runs): the kernel alone, in a loop over microbatches of 32 images
 # at 10,000 filters, XLA path 5.53 ms vs fused kernel 1.49 ms a
 # microbatch (3.7x). Unlike the standalone rectify_pool kernel above,
-# this one is ON by default on TPU (set KEYSTONE_DISABLE_FUSED_CONV=1
-# to force the XLA path).
+# this one is ON by default on TPU; the XLA path runs on every other
+# backend and where the chooser raises `FusedConvIneligibleError`.
 
 
 def use_fused_conv() -> bool:
     if not _kernels_enabled():
-        return False
-    if os.environ.get("KEYSTONE_DISABLE_FUSED_CONV") == "1":
         return False
     return jax.default_backend() == "tpu"
 

@@ -95,7 +95,7 @@ class RandomPatchCifarConfig:
     lam: float = 10.0
     sample_patches: int = 100_000
     block_size: int = 4096
-    bcd_iters: int = 1  # shared by the pipeline AND fused solve paths
+    bcd_iters: int = 1
     num_classes: int = 10
     microbatch: int = 2048
     seed: int = 0
@@ -233,8 +233,7 @@ def make_featurizer(filters, whitener, h, w, c, config,
                     microbatch: Optional[int] = None) -> FusedBatchTransformer:
     """THE fused featurization stack (scale → folded-whitening conv →
     two-sided ReLU → sum-pool → flatten), one microbatched XLA program.
-    Single source of truth for `build_pipeline`, `run_staged`, and the
-    microbatch sweep (scripts/featurize_sweep.py)."""
+    Single source of truth for `build_pipeline` and `run_staged`."""
     return FusedBatchTransformer(
         [
             PixelScaler(),
@@ -269,205 +268,6 @@ def build_pipeline(train, config):
         >> MaxClassifier()
     )
     return predictor
-
-
-def _featurize_chunked(imgs, kern, cs, bias, *, config, mesh=None):
-    """`_fused_step`'s featurizer: conv → rectify → pool over
-    ``config.microbatch``-image chunks (bounded HBM, the same kernel
-    dispatcher as the pipeline), (n, h, w, c) → (n, d). On a mesh with
-    more than one ``data`` shard every device featurizes its own rows
-    under `shard_map`, as the pipeline's fused operator does
-    (`nodes/util/fusion.py` ``per_shard``): the fused conv is a Mosaic
-    kernel, and the compiler refuses to partition one automatically."""
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
-    from jax.sharding import PartitionSpec as P
-
-    from ..ops import conv_rectify_pool
-    from ..parallel import mesh as meshlib
-
-    def local(imgs, kern, cs, bias):
-        n = imgs.shape[0]
-        chunk = min(config.microbatch, n)
-        n_chunks = -(-n // chunk)
-        padded = n_chunks * chunk
-        if padded != n:
-            imgs = jnp.pad(imgs, ((0, padded - n), (0, 0), (0, 0), (0, 0)))
-        xs = imgs.reshape((n_chunks, chunk) + imgs.shape[1:])
-
-        def one(xb):
-            pooled = conv_rectify_pool(
-                xb / 255.0, kern, cs, bias, config.alpha, 0.0,
-                config.pool_size, config.pool_stride, True,
-            )
-            return pooled.reshape(xb.shape[0], -1)
-
-        ys = lax.map(one, xs)
-        return ys.reshape(padded, -1)[:n]
-
-    if mesh is not None and meshlib.n_data_shards(mesh) > 1:
-        rows = P(meshlib.DATA_AXIS)
-        local = jax.shard_map(
-            local, mesh=mesh, in_specs=(rows, P(), P(), P()),
-            out_specs=rows, check_vma=False,
-        )
-    return local(imgs, kern, cs, bias)
-
-
-def _fused_step(images, labels_i, count, test_images, test_labels_i,
-                test_count, key, *, config, h, w, c, n_valid, n_sample, m,
-                mesh=None):
-    """The ENTIRE RandomPatchCifar training run as one traced
-    computation: filter learning → chunked fused featurization → scaler
-    applied in-program, the pipeline's own BCD solve → train/test
-    prediction + confusion. One XLA program, one device execution, one
-    packed host transfer.
-
-    This is the TPU-first collapse of the reference's driver-side
-    orchestration (RandomPatchCifar.scala:21-86): where Spark runs each
-    stage as a separate distributed job, XLA traces the whole fit into
-    one program, so the per-dispatch latency the staged path pays per
-    executed program is paid ONCE. Exactness: the solve calls the
-    SAME `_bcd_fit_impl` the pipeline's BlockLeastSquaresEstimator jits
-    (on features scaled in-program), so it matches the pipeline path for
-    any block_size; the scaling is a linear reparameterization folded
-    back into a raw-feature (W, b) afterwards."""
-    import jax
-    import jax.numpy as jnp
-
-    from ..nodes.images.core import Convolver
-    from ..nodes.learning.zca import ZCAWhitener
-
-    # --- filters (same program as learn_filters, inlined) --------------
-    packed = _learn_filters_device(
-        images, key, jnp.float32(0.1),
-        patch=config.patch_size, step=config.patch_steps,
-        n_valid=n_valid, n_sample=n_sample, m=m,
-        num_filters=config.num_filters,
-    )
-    D = config.patch_size * config.patch_size * c
-    K = config.num_filters
-    filters = packed[: K * D].reshape(K, D)
-    Wz = packed[K * D : K * D + D * D].reshape(D, D)
-    mu_z = packed[K * D + D * D :]
-    conv = Convolver(filters, h, w, c, whitener=ZCAWhitener(Wz, mu_z),
-                     normalize_patches=True)
-    kern, cs, bias = conv.kernel, conv.colsum, conv.bias
-
-    def featurize(imgs):
-        return _featurize_chunked(imgs, kern, cs, bias,
-                                  config=config, mesh=mesh)
-
-    X = featurize(images)
-    n_pad, d = X.shape
-    mask = (jnp.arange(n_pad) < count).astype(X.dtype)
-    X = X * mask[:, None]
-    Y = (2.0 * jax.nn.one_hot(labels_i, config.num_classes, dtype=X.dtype)
-         - 1.0) * mask[:, None]
-
-    with jax.default_matmul_precision("highest"):
-        # --- moments (the StandardScaler fit, one pass) ----------------
-        s = jnp.sum(X, axis=0)
-        s2 = jnp.sum(X * X, axis=0)
-        mu = s / count
-        var = (s2 - count * mu * mu) / jnp.maximum(count - 1.0, 1.0)
-        sd = jnp.sqrt(jnp.maximum(var, 0.0))
-        sd = jnp.where(sd == 0.0, 1.0, sd)
-        # --- the REAL block solver on scaled features ------------------
-        # same _bcd_fit_impl the pipeline's BlockLeastSquaresEstimator
-        # jits, so the fused path matches it for ANY block_size/num_iter
-        # (not just the single-block case)
-        from ..nodes.learning.block_ls import _bcd_fit_impl
-
-        Xs = ((X - mu) / sd) * mask[:, None]
-        B = min(config.block_size, d)
-        nb = -(-d // B)
-        d_pad = nb * B
-        if d_pad != d:
-            Xs = jnp.pad(Xs, ((0, 0), (0, d_pad - d)))
-        # same dp×tp feature sharding the pipeline's solver constrains X
-        # with, built from the REAL featurized width (not re-derived)
-        from ..parallel import mesh as meshlib
-
-        x_sharding = meshlib.feature_sharding(mesh, d_pad) if mesh else None
-        Ws_full, b_s = _bcd_fit_impl(
-            Xs, Y, mask, jnp.float32(config.lam),
-            B, nb, config.bcd_iters, True, x_sharding=x_sharding,
-        )
-        Ws = Ws_full[:d]
-        # fold scaling back: ŷ = X W_raw + b_raw on RAW features
-        W_raw = Ws / sd[:, None]
-        b_raw = b_s - (mu / sd) @ Ws
-
-        def confusion(feats, labels, m_mask):
-            scores = feats @ W_raw + b_raw
-            pred = jnp.argmax(scores, axis=-1)
-            oh_p = jax.nn.one_hot(pred, config.num_classes, dtype=jnp.float32)
-            oh_a = jax.nn.one_hot(labels, config.num_classes, dtype=jnp.float32)
-            return (oh_a * m_mask[:, None]).T @ oh_p
-
-        conf_train = confusion(X, labels_i, mask)
-    # test featurize outside the HIGHEST-precision context (the fused
-    # conv kernel pins its own bf16 GEMM precision)
-    Xt = featurize(test_images)
-    t_mask = (jnp.arange(Xt.shape[0]) < test_count).astype(X.dtype)
-    with jax.default_matmul_precision("highest"):
-        conf_test = confusion(Xt * t_mask[:, None], test_labels_i, t_mask)
-    return W_raw, b_raw, conf_train, conf_test
-
-
-_fused_step_jit_cache: dict = {}
-
-
-def run_fused(train, test, config):
-    """One-execution training run (see `_fused_step`). Returns a dict
-    with the fitted raw-feature model and train/test metrics computed
-    from the on-device confusion matrices."""
-    import jax
-    import jax.numpy as jnp
-
-    from ..evaluation.multiclass import MulticlassMetrics
-
-    h, w, c = train.data.array.shape[1:]
-    n = train.data.count
-    n_sample = min(n, max(config.sample_patches // 100, 64))
-    gy = (h - config.patch_size) // config.patch_steps + 1
-    gx = (w - config.patch_size) // config.patch_steps + 1
-    m = min(n_sample * gy * gx, config.sample_patches)
-    # key on EVERY config field baked into the program via partial —
-    # solver/featurizer parameters included, else a second config would
-    # silently reuse the first's compiled fit. The mesh is part of the
-    # key: the solver's feature-sharding constraint is built from it
-    # inside _fused_step (next to the real featurized width).
-    from dataclasses import astuple
-
-    mesh = train.data.mesh
-    key = (astuple(config), h, w, c, n, n_sample, m,
-           train.data.padded_count, test.data.padded_count,
-           test.data.count, mesh)
-    fn = _fused_step_jit_cache.get(key)
-    if fn is None:
-        from functools import partial
-
-        fn = jax.jit(partial(
-            _fused_step, config=config, h=h, w=w, c=c,
-            n_valid=n, n_sample=n_sample, m=m, mesh=mesh,
-        ))
-        _fused_step_jit_cache[key] = fn
-
-    W, b, conf_train, conf_test = fn(
-        train.data.array, train.labels.array, jnp.float32(train.data.count),
-        test.data.array, test.labels.array, jnp.float32(test.data.count),
-        jax.random.PRNGKey(config.seed),
-    )
-    train_m = MulticlassMetrics(np.asarray(conf_train))
-    test_m = MulticlassMetrics(np.asarray(conf_test))
-    return {
-        "W": W, "b": b,
-        "train_metrics": train_m, "test_metrics": test_m,
-        "train_error": train_m.error, "test_accuracy": test_m.accuracy,
-    }
 
 
 def _sync_leaf(x):
@@ -531,7 +331,7 @@ def run_staged(train, config, evaluator):
     return stages, train_metrics, parts
 
 
-def run(config: RandomPatchCifarConfig, fused: bool = False):
+def run(config: RandomPatchCifarConfig):
     if config.train_path:
         train = cifar_loader(config.train_path)
         test = cifar_loader(config.test_path or config.train_path)
@@ -539,29 +339,6 @@ def run(config: RandomPatchCifarConfig, fused: bool = False):
         train, test = synthetic_cifar(
             config.synth_train, config.synth_test, config.num_classes, config.seed
         )
-
-    if fused:
-        # the whole fit as ONE XLA execution (run_fused docstring). The
-        # single program also featurizes+scores the TEST set, so the
-        # throughput is reported over train+test images — dividing only
-        # the train count by this window would deflate the rate ~17% on
-        # CIFAR shapes and make --fused incomparable to the default path
-        t0 = time.perf_counter()
-        res = run_fused(train, test, config)
-        t_total = time.perf_counter() - t0
-        test_metrics = res["test_metrics"]
-        n_imgs = train.data.count + test.data.count
-        return {
-            "train_error": res["train_error"],
-            "test_error": test_metrics.error,
-            "test_accuracy": test_metrics.accuracy,
-            "train_seconds": t_total,
-            "images_per_sec": n_imgs / t_total,
-            "rate_basis": "train+test images (fused program includes "
-                          "test featurize+eval)",
-            "summary": test_metrics.summary(),
-            "model": (res["W"], res["b"]),
-        }
 
     t0 = time.perf_counter()
     predictor = build_pipeline(train, config)
@@ -594,16 +371,11 @@ def main(argv=None):
     p.add_argument("--synth-train", dest="synth_train", type=int, default=2000)
     p.add_argument("--synth-test", dest="synth_test", type=int, default=500)
     p.add_argument("--seed", type=int, default=0)
-    p.add_argument("--fused", action="store_true",
-                   help="run the whole fit as one XLA execution "
-                        "(same BCD solve as the pipeline path)")
     args = p.parse_args(argv)
-    fused = args.fused
-    del args.fused
     config = RandomPatchCifarConfig(
         **{k: v for k, v in vars(args).items() if v is not None}
     )
-    result = run(config, fused=fused)
+    result = run(config)
     print(result["summary"])
     print(
         f"train_error={result['train_error']:.4f} "

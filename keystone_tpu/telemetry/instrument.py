@@ -67,9 +67,9 @@ def record_dispatch(n: int = 1) -> None:
     executed program pays a fixed launch cost whatever its size, so
     trivial stages are bounded by their count, not their bytes. The
     counting half of `dispatch`, which is what the library's call sites
-    use. Always on (not gated on tracing): the `dispatch_count` bench
-    tier, the benchmark's `programs_per_fit` and the scheduler tests
-    read the counter directly.
+    use. Always on (not gated on tracing): the benchmark's
+    `programs_per_fit` and the scheduler tests read the counter
+    directly.
 
     Under a multi-host mesh each count also lands on
     ``dispatch.programs_executed.p<i>`` — every host dispatches its own

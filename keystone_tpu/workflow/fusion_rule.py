@@ -17,8 +17,7 @@ operator:
     joins the chain as a `_FitSlot`. The chain lowers to a
     `FusedChainOperator` whose extra dependencies are the estimator
     expressions; at force time the fitted transformers are captured as
-    fused closure *params* (exactly what `run_fused` does by hand for
-    CIFAR) and the whole chain runs as one program;
+    fused closure *params* and the whole chain runs as one program;
   - also with ``fuse_apply``, fusable ``Pipeline.gather`` diamonds
     (N traceable branches over one source + VectorCombiner) collapse
     into one `_GatherConcatStage` program (`_fuse_gathers`).

@@ -1,30 +1,27 @@
-"""Render the stage/roofline/flagship tables from a bench record
-(a file holding one bench.py output line) as markdown for PERF.md.
+"""Render a run's host trace, its decision ledger, the static roofline
+or the static serving verdicts as markdown tables.
 
-Usage: python scripts/perf_table.py <path>
-       python scripts/perf_table.py --trace run.json [--top N]
+Usage: python scripts/perf_table.py --trace run.json [--top N]
        python scripts/perf_table.py --ledger run.ledger.jsonl
        python scripts/perf_table.py --roofline [EXAMPLE ...]
        python scripts/perf_table.py --serving [EXAMPLE ...]
 
 ``--roofline`` runs the STATIC roofline analyzer
 (keystone_tpu/analysis/roofline.py) over the named analyzable()
-examples (default: the three bench examples) and renders the per-stage
-markdown table PERF.md rounds source their intensity columns from —
-flops, stage-at-a-time HBM bytes, arithmetic intensity, the
-compute/bandwidth classification against the calibrated machine
-balance, predicted seconds, and the KP801 Pallas-candidate chains.
+examples (default: `_ROOFLINE_DEFAULT_EXAMPLES`) and renders the
+per-stage markdown table: flops, stage-at-a-time HBM bytes, arithmetic
+intensity, the compute/bandwidth classification against the calibrated
+machine balance, predicted seconds, and the KP801 Pallas-candidate
+chains.
 
 ``--trace`` renders a Chrome trace (written via KEYSTONE_TRACE /
-`trace_run`, e.g. the ``trace_artifact`` path a bench record carries) as
-a markdown per-node self-time table, so bench rounds can diff span-level
-detail across PRs (see OBSERVABILITY.md). When the trace embeds
-optimizer decisions, the decision tables are appended automatically.
+`trace_run`) as a markdown per-node self-time table (see
+OBSERVABILITY.md). When the trace embeds optimizer decisions, the
+decision tables are appended automatically.
 
-``--ledger`` renders a run's decision ledger (the ``ledger_artifact``
-path a bench record carries, or a decision-carrying trace) as the
-markdown predicted-vs-observed tables PERF.md rounds source their
-decision columns from.
+``--ledger`` renders a run's decision ledger (a ``KEYSTONE_LEDGER``
+file, or a decision-carrying trace) as markdown predicted-vs-observed
+tables.
 
 ``--serving`` runs the STATIC serving-readiness certifier
 (keystone_tpu/analysis/serving.py — the KP9xx tier) over the named
@@ -36,7 +33,6 @@ dominating stage. ``KEYSTONE_SLO_MS`` / ``KEYSTONE_SERVING_MAX_BATCH``
 refine the envelope.
 """
 
-import json
 import sys
 
 
@@ -203,7 +199,7 @@ def ledger_table(path):
     print()
 
 
-#: the bench examples whose roofline table PERF.md rounds carry.
+#: the examples `--roofline` renders when none is named
 _ROOFLINE_DEFAULT_EXAMPLES = (
     "MnistRandomFFT", "RandomPatchCifar", "TimitPipeline")
 
@@ -319,46 +315,7 @@ def main():
         top = (int(sys.argv[sys.argv.index("--top") + 1])
                if "--top" in sys.argv else 15)
         return trace_table(path, top)
-    if len(sys.argv) < 2:
-        raise SystemExit(__doc__)
-    with open(sys.argv[1]) as f:
-        rec = json.loads(f.read().strip())
-    d = rec.get("detail", rec)
-    # Error records must not render as clean results
-    flags = []
-    if rec.get("error"):
-        flags.append(f"ERROR: {rec['error']}")
-    if flags:
-        print("**" + " | ".join(flags) + "**\n")
-    value = rec.get("value", d.get("images_per_sec"))
-    vsb = rec.get("vs_baseline")
-    vsb = f"{vsb}x" if vsb is not None else "n/a"
-    band = d.get("accuracy_band")
-    band_s = f" in band {band}" if band is not None else ""
-    print(f"Headline: {value} img/s ({d.get('train_seconds')} s e2e, "
-          f"vs_baseline {vsb}); test_accuracy "
-          f"{d.get('test_accuracy')}{band_s}\n")
-    stages = d.get("stages_seconds")
-    roofs = d.get("rooflines", {})
-    if stages:
-        print("| Stage | Seconds | GFLOP | GB | TFLOP/s | GB/s | %peak FLOP | %peak BW |")
-        print("|---|---|---|---|---|---|---|---|")
-        for name, secs in stages.items():
-            r = roofs.get(name, {})
-            print(f"| {name} | {secs} | {r.get('gflops','—')} | "
-                  f"{r.get('gbytes','—')} | {r.get('attained_tflops','—')} | "
-                  f"{r.get('attained_gbs','—')} | {r.get('pct_peak_flops','—')} | "
-                  f"{r.get('pct_peak_bw','—')} |")
-        print(f"| **sum** | **{d.get('stages_sum_seconds')}** | | | | | | |")
-    fl = d.get("flagship_bcd_d8192")
-    if fl:
-        r = fl.get("roofline", {})
-        print(f"\nFlagship BCD d={fl['d']} k={fl['k']} n={fl['n']} "
-              f"({fl['num_iter']} epochs x {-(-fl['d']//fl['block_size'])} blocks): "
-              f"{fl['fit_seconds']} s fit "
-              f"({r.get('attained_tflops')} TFLOP/s, {r.get('attained_gbs')} GB/s); "
-              f"n-scaled vs 16x r3.4xlarge reference: "
-              f"{fl.get('speedup_vs_reference_n_scaled')}x faster")
+    raise SystemExit(__doc__)
 
 
 if __name__ == "__main__":

@@ -198,21 +198,6 @@ with use_mesh(mesh):
 # reach high train accuracy or the distributed pipeline is broken
 assert train_acc >= 0.9, f"multihost pipeline train acc {train_acc}"
 
-# --- run_fused across hosts --------------------------------------------
-# the whole-fit-as-one-XLA-execution path under a cross-host mesh: the
-# single program's featurize/scaler/BCD all run SPMD over both
-# processes' devices
-from keystone_tpu.pipelines.random_patch_cifar import run_fused
-
-with use_mesh(mesh):
-    tr_ds2 = LabeledData(
-        data=multihost.dataset_from_process_local(imgs[lo_i:hi_i], mesh=mesh),
-        labels=multihost.dataset_from_process_local(labs[lo_i:hi_i], mesh=mesh),
-    )
-    res_fused = run_fused(tr_ds2, tr_ds2, config)
-assert res_fused["train_error"] <= 0.1, (
-    f"multihost fused fit train_error {res_fused['train_error']}")
-
 # --- dp-sharded sparse iterative L-BFGS across hosts -------------------
 # rows shard over the cross-host 'data' axis; every row-space reduction
 # (gradient, colsum, line-search inner products) psums over the Gloo

@@ -156,3 +156,49 @@ def test_a_run_that_asked_for_the_chip_fails_off_it(monkeypatch):
     monkeypatch.setenv("KEYSTONE_BACKEND", "gpu")
     with pytest.raises(SystemExit, match="must be tpu or cpu"):
         cli._apply_backend_env()
+
+
+def test_the_smoke_and_the_cell_are_the_same_task(smoke):
+    """The start-up check and `cifar_fit` draw their images from one
+    distribution and hold a fit to one lower bar; no third file holds
+    the numbers, so the two are held to each other here."""
+    with open(os.path.join(
+            REPO, "benchmark", "configs", "random_patch_cifar.json")) as f:
+        sizes = json.load(f)
+    assert smoke.TASK_NOISE == sizes["assumed"]["noise"]
+    assert smoke.TASK_CONFUSION == sizes["assumed"]["confusion"]
+    assert smoke.MIN_ACCURACY == sizes["accuracy_band"][0]
+
+
+def test_nothing_imports_a_second_benchmark():
+    """`BENCHMARK.json`'s command is the one benchmark: its module is in
+    the checkout, and no program or script file imports a module named
+    `bench` (the root's old `bench.py`, deleted in PR 31)."""
+    import ast
+    import glob
+
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        command = json.load(f)["command"]
+    module = command[command.index("-m") + 1]
+    assert os.path.isfile(
+        os.path.join(REPO, *module.split(".")) + ".py"), command
+
+    paths = glob.glob(os.path.join(REPO, "*.py"))
+    for top in ("keystone_tpu", "scripts", "benchmark"):
+        paths += glob.glob(
+            os.path.join(REPO, top, "**", "*.py"), recursive=True)
+    assert len(paths) > 100
+    importers = []
+    for path in paths:
+        with open(path) as f:
+            tree = ast.parse(f.read(), path)
+        for node in ast.walk(tree):
+            if isinstance(node, ast.Import):
+                names = [alias.name for alias in node.names]
+            elif isinstance(node, ast.ImportFrom) and not node.level:
+                names = [node.module]
+            else:
+                continue
+            if any(name.split(".")[0] == "bench" for name in names):
+                importers.append(os.path.relpath(path, REPO))
+    assert not importers, importers

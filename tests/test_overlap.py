@@ -316,27 +316,6 @@ def test_streaming_preserves_non_host_pipelines():
         assert len(chunks) == 1 and chunks[0][0] is None
 
 
-@pytest.mark.slow
-def test_bench_overlap_tier_record_shape():
-    """The featurize_overlap bench tier end-to-end at toy scale
-    (timing-sensitive: real wall-clocks, compile + threads; tier-1
-    excludes it via -m 'not slow')."""
-    import importlib.util
-    import os
-
-    spec = importlib.util.spec_from_file_location(
-        "bench_mod", os.path.join(os.path.dirname(__file__), "..", "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-
-    res = bench._flagship_overlap(n=48, chunk=12, num_filters=8,
-                                  block=16, iters=1)
-    assert res["n_chunks"] == 4
-    assert res["serial_seconds"] > 0 and res["overlapped_seconds"] > 0
-    assert res["speedup"] == pytest.approx(
-        res["serial_seconds"] / res["overlapped_seconds"], rel=1e-2)
-
-
 def test_partial_stream_drain_never_rewinds_the_producer():
     """Breaking out of .stream() then forcing .get() must RESUME the
     producer, not re-run it: each chunk is dispatched exactly once, and

@@ -149,8 +149,7 @@ def test_rectify_pool_vectorize_compiles(one_chip):
 
 
 def test_rbf_block_compiles(one_chip):
-    """The KRR flagship's widths (bench.py: d=440) against one column
-    block."""
+    """TIMIT's frame width (d=440) against one column block."""
     from keystone_tpu.ops import rbf_block_pallas
 
     def fn(x, yb):
@@ -194,35 +193,6 @@ def kernels_as_on_the_chip(monkeypatch):
 
     monkeypatch.setattr(pk, "use_fused_conv", lambda: True)
     monkeypatch.setattr(pk, "_fused_conv_canary_ok", lambda *a: True)
-
-
-def test_run_fused_featurize_compiles_on_four_chips(
-        mesh4, kernels_as_on_the_chip):
-    """`run_fused`'s featurize step with the images sharded over `data`.
-    Called bare inside `lax.map` it was refused: "Mosaic kernels cannot
-    be automatically partitioned. Please wrap the call in a
-    shard_map." """
-    from keystone_tpu.pipelines.random_patch_cifar import (
-        RandomPatchCifarConfig,
-        _featurize_chunked,
-    )
-
-    config = RandomPatchCifarConfig()
-    rows = NamedSharding(mesh4, P("data"))
-    whole = NamedSharding(mesh4, P())
-
-    def fn(images, kern, colsum, bias):
-        return _featurize_chunked(images, kern, colsum, bias,
-                                  config=config, mesh=mesh4)
-
-    hlo = _compile(
-        fn,
-        _aval((4 * MICROBATCH + 512, H, W, C), jnp.float32, rows),
-        _aval((PATCH, PATCH, C, K), jnp.float32, whole),
-        _aval((K,), jnp.float32, whole),
-        _aval((K,), jnp.float32, whole),
-    )
-    assert "tpu_custom_call" in hlo
 
 
 def test_fused_operator_compiles_per_shard_on_four_chips(
