@@ -26,9 +26,16 @@ the residual moves), so a fit of several epochs forms and factors each
 block's Gram in its first sweep, keeps the stacked upper factors
 ``(num_blocks, B, B)`` (B/n of one copy of X), and every later sweep is
 the correlation, two triangular solves on the kept factor and the
-residual updates. A one-epoch fit keeps nothing. `_bcd_fit` (the
-one-program scan form, which forms every Gram in every epoch) is the
-numerics reference of both.
+residual updates. A one-epoch fit keeps nothing. The sweep that forms
+the Grams (the only one of a one-epoch fit, the first of a longer one)
+computes each as the row panels of its upper triangle and copies the
+tiles below the diagonal from those above (`_gram_upper_panels`): the
+Gram is symmetric, and at a tile of B/16 its sixteen panels are 17/32 of
+the full product's work. The tile is a function of the block's width
+(`_gram_tile`), and a block too narrow for two tiles keeps the one full
+product. `_bcd_fit` (the one-program scan form, which forms
+every Gram in every epoch, each as one full product) is the numerics
+reference of all three.
 
 The estimator declares optimizer weight 3·numIter+1 — the number of
 passes over the input — feeding auto-caching (BlockLinearMapper.scala:205-210).
@@ -146,13 +153,61 @@ def _bcd_prepare(X, Y, mask, block_size: int, num_blocks: int, center: bool,
         return Xc, Yc, xm, ym, W0
 
 
+#: The triangular Gram's tile: the narrowest the sweep on the chip tried was
+#: the fastest at every shape (PERF.md 6, PR 32), and at most this many
+#: panels keep the forming sweep's program and its compile bounded.
+_GRAM_TILE_MIN = 256
+_GRAM_PANELS_MAX = 16
+
+
+def _gram_tile(block_size: int) -> Optional[int]:
+    """Tile width T of the triangular Gram for a block of ``block_size``
+    columns, or None for the one full product where the block has fewer
+    than two tiles. A pure function of the shape, so every fit of one shape
+    runs one program; no option overrides it. The sweep found no dependence
+    on the rows (8,192 to 65,536)."""
+    if block_size < 2 * _GRAM_TILE_MIN:
+        return None
+    widest = -(-block_size // _GRAM_PANELS_MAX)
+    return -(-widest // _GRAM_TILE_MIN) * _GRAM_TILE_MIN
+
+
+def _gram_tiles_skipped(block_size: int, tile: Optional[int]) -> int:
+    """Tiles of a block's Gram that `_gram_upper_panels` does not compute:
+    the t(t-1)/2 below the diagonal, 0 for the full product."""
+    if tile is None:
+        return 0
+    t = -(-block_size // tile)
+    return t * (t - 1) // 2
+
+
+def _gram_upper_panels(Xb, tile: int):
+    """``Xb.T @ Xb`` from the row panels of its upper triangle. Panel i is
+    ``Xb[:, iT:(i+1)T].T @ Xb[:, iT:]``, a (T, B - iT) product: t =
+    ceil(B/T) products of falling width (the last panel narrower where T
+    does not divide B), (t+1)/(2t) of the full product's work. The
+    tiles below the diagonal are the transposes of those above, copied and
+    not computed, so the result is a full (B, B) matrix equal in both
+    triangles. The caller sets the matmul precision."""
+    B = Xb.shape[1]
+    starts = range(0, B, tile)
+    panels = [Xb[:, s:s + tile].T @ Xb[:, s:] for s in starts]
+    rows = [
+        jnp.concatenate(
+            [panels[j][:, s - sj:s - sj + tile].T
+             for j, sj in enumerate(starts[:i])] + [panels[i]], axis=1)
+        for i, s in enumerate(starts)]
+    return jnp.concatenate(rows, axis=0)
+
+
 @partial(
     jax.jit,
-    static_argnames=("block_size", "num_blocks", "keep_factors"),
+    static_argnames=("block_size", "num_blocks", "keep_factors", "gram_tile"),
     donate_argnums=(0, 1),
 )
 def _bcd_epoch(W, R, Xc, lam, block_size: int, num_blocks: int, *,
-               factors=None, keep_factors: bool = False):
+               factors=None, keep_factors: bool = False,
+               gram_tile: Optional[int] = None):
     """One BCD sweep over all feature blocks with the model W and
     residual R DONATED: XLA reuses their buffers for the outputs, so the
     per-epoch host loop updates solver state in place instead of
@@ -179,7 +234,14 @@ def _bcd_epoch(W, R, Xc, lam, block_size: int, num_blocks: int, *,
 
     ``cho_factor`` + ``cho_solve`` is what ``solve(assume_a="pos")``
     runs, on the same operands in the same order, so the kept-factor
-    epochs give the W of the factor-free ones."""
+    epochs give the W of the factor-free ones.
+
+    ``gram_tile`` (static; `_gram_tile` of the block's width, handed in by
+    the fit) makes the two forming traces compute ``Xb'Xb`` as the row
+    panels of its upper triangle (`_gram_upper_panels`) in place of the one
+    full product: the same operands at the same precision, G still a full
+    (B, B) matrix equal in both triangles. None, and every trace that is
+    handed factors, forms what it formed before."""
     with jax.default_matmul_precision("highest"):
         eye = lam * jnp.eye(block_size, dtype=Xc.dtype)
 
@@ -192,8 +254,10 @@ def _bcd_epoch(W, R, Xc, lam, block_size: int, num_blocks: int, *,
             with jax.named_scope("ks.bcd.residual"):
                 R1 = R + Xb @ Wb
             with jax.named_scope("ks.bcd.gram"):
-                if factor is None:
-                    G = Xb.T @ Xb + eye  # all-reduce over the data axis
+                if factor is None:  # all-reduce over the data axis
+                    XtX = (Xb.T @ Xb if gram_tile is None
+                           else _gram_upper_panels(Xb, gram_tile))
+                    G = XtX + eye
                 C = Xb.T @ R1            # all-reduce over the data axis
             formed = None
             if factor is None and keep_factors:
@@ -394,6 +458,7 @@ class BlockLeastSquaresEstimator(LabelEstimator):
                 with dispatch("pad"):  # `jnp.pad` is a program of its own
                     X = jnp.pad(X, [(0, 0), (0, d_pad - d)])
             mask = data.mask_as(X.dtype)
+            x_sharding = meshlib.feature_sharding(data.mesh, d_pad)
             with dispatch("_bcd_prepare"):
                 Xc, R, xm, ym, W = _bcd_prepare(
                     X,
@@ -402,7 +467,7 @@ class BlockLeastSquaresEstimator(LabelEstimator):
                     bs,
                     num_blocks,
                     self.fit_intercept,
-                    x_sharding=meshlib.feature_sharding(data.mesh, d_pad),
+                    x_sharding=x_sharding,
                 )
             # a host scalar: `jnp.asarray` would launch a convert program
             lam = np.asarray(self.lam, X.dtype)
@@ -412,19 +477,28 @@ class BlockLeastSquaresEstimator(LabelEstimator):
             # (num_blocks x B x B: B/n of one copy of X). A one-epoch fit
             # keeps nothing and runs the factor-free program.
             factors = None
+            # The forming sweep computes the upper triangle of each Gram in
+            # row panels where the block is wide enough for it; the tile
+            # comes from the shapes alone. Where the feature axis is
+            # sharded over `model`, every panel's column slices would be
+            # gathered anew (n/p x B arrays): the one full product there.
+            gram_tile = None if x_sharding is not None else _gram_tile(bs)
             for i in range(self.num_iter):
                 # the spans measure the host-side dispatch of one
                 # donated-buffer sweep; device time pipelines
                 # asynchronously and lands on whoever pulls the model
                 # (see OBSERVABILITY.md)
                 reusing = factors is not None
+                tile = None if reusing else gram_tile
                 with span("bcd_epoch", cat="step", layer="solver", iter=i,
                           blocks=num_blocks,
-                          gram="reused" if reusing else "formed"), \
+                          gram="reused" if reusing else "formed",
+                          gram_tile=tile or 0), \
                         dispatch("_bcd_epoch"):
                     W, R, *kept = _bcd_epoch(
                         W, R, Xc, lam, bs, num_blocks, factors=factors,
-                        keep_factors=i == 0 and self.num_iter > 1)
+                        keep_factors=i == 0 and self.num_iter > 1,
+                        gram_tile=tile)
                 if kept:
                     (factors,) = kept
                 counter("solver.steps").inc()
@@ -432,6 +506,8 @@ class BlockLeastSquaresEstimator(LabelEstimator):
                     counter("solver.gram_blocks_reused").inc(num_blocks)
                 else:
                     counter("solver.gram_blocks_formed").inc(num_blocks)
+                    counter("solver.gram_tiles_skipped").inc(
+                        num_blocks * _gram_tiles_skipped(bs, tile))
             with dispatch("_bcd_finalize"):
                 W, b = _bcd_finalize(W, xm, ym)
         return BlockLinearMapper(W, b if self.fit_intercept else None, self.block_size)

@@ -727,6 +727,195 @@ def test_bcd_fit_counts_grams_formed_and_reused(problem, iters):
     assert [s.args["iter"] for s in epochs] == list(range(iters))
 
 
+def _tiles_skipped_so_far():
+    from keystone_tpu.telemetry import registry
+
+    c = registry().counters.get("solver.gram_tiles_skipped")
+    return c.value if c else 0.0
+
+
+# (block width B, tile T): T dividing B, and two where the last tile is
+# narrower, as a block that is no multiple of the tile leaves it
+GRAM_PANEL_CASES = [(8, 4), (8, 2), (16, 4), (12, 4), (32, 8), (64, 32),
+                    (7, 4), (10, 4)]
+
+
+@pytest.mark.parametrize("B,T", GRAM_PANEL_CASES)
+def test_gram_upper_panels_equal_the_full_product(B, T):
+    """The row panels of the upper triangle, mirrored, against `Xb.T @ Xb`
+    on rows of which the mask zeroed some: allclose at float32 tolerances,
+    a full (B, B) matrix, and exactly symmetric."""
+    import jax
+    import jax.numpy as jnp
+
+    from keystone_tpu.nodes.learning.block_ls import _gram_upper_panels
+
+    rng = np.random.default_rng(B * 100 + T)
+    mask = (np.arange(203) < 197).astype(np.float32)
+    Xb = rng.normal(size=(203, B)).astype(np.float32) * mask[:, None]
+    with jax.default_matmul_precision("highest"):
+        G = np.asarray(_gram_upper_panels(jnp.asarray(Xb), T))
+        full = np.asarray(jnp.asarray(Xb).T @ jnp.asarray(Xb))
+    assert G.shape == (B, B)
+    np.testing.assert_allclose(G, full, rtol=1e-6, atol=1e-4)
+    np.testing.assert_allclose(
+        G, Xb.astype(np.float64).T @ Xb.astype(np.float64), rtol=1e-5,
+        atol=1e-3)
+    np.testing.assert_array_equal(G, G.T)
+
+
+@pytest.mark.parametrize("B,tile", [
+    # every CPU test's block, and a last block narrower than two tiles:
+    # the one full product, the parent's program
+    (4, None), (8, None), (16, None), (147, None), (511, None),
+    # both cells' block (n = 8,192 and 65,536; PERF.md 6, PR 32: the sweep
+    # on the chip), the smoke's, and the narrowest that has two tiles
+    (4_096, 256), (2_048, 256), (1_024, 256), (512, 256),
+    # at most sixteen panels, in whole tiles of 256; a ragged last tile
+    (8_192, 512), (5_000, 512), (600, 256),
+])
+def test_gram_tile_is_a_pure_function_of_the_block_width(B, tile):
+    from keystone_tpu.nodes.learning.block_ls import (
+        _gram_tile,
+        _gram_tiles_skipped,
+    )
+
+    assert _gram_tile(B) == tile
+    if tile is None:
+        assert _gram_tiles_skipped(B, tile) == 0
+    else:
+        t = -(-B // tile)
+        assert 2 <= t <= 16 and tile % 256 == 0
+        assert _gram_tiles_skipped(B, tile) == t * (t - 1) // 2
+
+
+@pytest.fixture
+def gram_tile_of_4(monkeypatch):
+    """Engages the triangular Gram at the tests' block widths, where the
+    shape rule keeps the full product: B = 8 is two tiles of 4, B = 7 a
+    tile of 4 and one of 3."""
+    from keystone_tpu.nodes.learning import block_ls
+
+    monkeypatch.setattr(block_ls, "_gram_tile", lambda block_size: 4)
+
+
+@pytest.mark.parametrize("bs,iters,lam,center,rows", BCD_CASES)
+def test_bcd_triangular_gram_fits_match_scan_form(
+        problem, gram_tile_of_4, bs, iters, lam, center, rows):
+    """The estimator with the upper triangle engaged against `_bcd_fit`,
+    which forms every Gram as one full product: the tolerance of
+    `test_bcd_donated_epochs_match_scan_form`."""
+    test_bcd_donated_epochs_match_scan_form(
+        problem, bs, iters, lam, center, rows)
+
+
+@pytest.mark.parametrize("shape,axes", [
+    ((4,), ("data",)), ((2, 2), ("data", "model")), ((4, 2), ("data", "model"))],
+    ids=["data4", "data2-model2", "data4-model2"])
+def test_bcd_triangular_gram_on_a_mesh_matches_one_device(
+        problem, gram_tile_of_4, shape, axes):
+    """The chip smoke's meshes. On a row-sharded X the panels' products are
+    all-reduced over `data` as the full product was; where the feature axis
+    is sharded over `model` the fit keeps the full product (every panel's
+    column slices would be gathered anew). Layout, not math."""
+    import jax
+
+    from keystone_tpu.parallel.mesh import make_mesh, use_mesh
+
+    X, Y = problem
+    est = lambda: BlockLeastSquaresEstimator(  # noqa: E731
+        block_size=8, num_iter=2, lam=0.1)
+    with use_mesh(make_mesh(jax.devices()[:1])):
+        one = est().fit(Dataset(X), Dataset(Y))
+    devices = jax.devices()[:int(np.prod(shape))]
+    before = _tiles_skipped_so_far()
+    with use_mesh(make_mesh(devices, shape=shape, axis_names=axes)):
+        meshed = est().fit(Dataset(X), Dataset(Y))
+    assert _tiles_skipped_so_far() - before == (0 if "model" in axes else 3)
+    np.testing.assert_allclose(
+        np.asarray(meshed.W), np.asarray(one.W), atol=1e-4, rtol=1e-4)
+    np.testing.assert_allclose(
+        np.asarray(meshed.b), np.asarray(one.b), atol=1e-4, rtol=1e-4)
+
+
+@pytest.fixture(scope="module")
+def bcd_triangle_traces():
+    """`_bcd_epoch`'s three traces at `cifar_fit`'s rows and block over two
+    blocks, lowered from shapes alone with the tile the shape rule gives."""
+    import jax
+    import jax.numpy as jnp
+
+    from keystone_tpu.nodes.learning.block_ls import _bcd_epoch, _gram_tile
+
+    n, B, k, nb = 8_192, 4_096, 3, 2
+    tile = _gram_tile(B)
+    S = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)  # noqa: E731
+    W, R, X, F, lam = S(nb, B, k), S(n, k), S(n, nb * B), S(nb, B, B), S()
+    return tile, {
+        "one-epoch": _bcd_epoch.lower(W, R, X, lam, B, nb, gram_tile=tile),
+        "first-of-several": _bcd_epoch.lower(
+            W, R, X, lam, B, nb, keep_factors=True, gram_tile=tile),
+        "later": _bcd_epoch.lower(W, R, X, lam, B, nb, factors=F),
+    }
+
+
+@pytest.mark.parametrize("trace", ["one-epoch", "first-of-several", "later"])
+def test_bcd_forming_traces_hold_the_panels_and_no_full_gram(
+        bcd_triangle_traces, trace):
+    import re
+
+    tile, traces = bcd_triangle_traces
+    B = 4_096
+    text = traces[trace].as_text()
+    (module,) = re.findall(r"^module @(\S+)", text, flags=re.M)
+    assert module == "jit__bcd_epoch"
+    dots = re.findall(r"dot_general.*-> tensor<(\d+)x(\d+)xf32>", text)
+    square = [d for d in dots if d == (str(B), str(B))]
+    panels = sorted((int(r), int(c)) for r, c in dots if int(r) == tile)
+    assert square == []  # no product yields a (B, B) matrix
+    if trace == "later":
+        assert panels == [] and "cholesky" not in text
+    else:
+        # t products of falling width, (T, B - iT)
+        assert panels == [(tile, B - s) for s in range(B - tile, -1, -tile)]
+        assert "cholesky" in text
+
+
+@pytest.mark.parametrize("iters", [1, 5])
+def test_bcd_fit_counts_gram_tiles_skipped(problem, gram_tile_of_4, iters):
+    """A forming epoch skips t(t-1)/2 tiles a block, a reusing one none; the
+    `bcd_epoch` span says which tile each sweep ran with."""
+    from keystone_tpu.telemetry import registry, trace_run
+
+    X, Y = problem
+    data, labels = Dataset(X), Dataset(Y)
+    est = BlockLeastSquaresEstimator(block_size=8, num_iter=iters, lam=0.5)
+    before = {k: c.value for k, c in registry().counters.items()}
+    with trace_run() as tr:
+        est.fit(data, labels)
+    moved = {k: c.value - before.get(k, 0.0)
+             for k, c in registry().counters.items()}
+    blocks, t = 3, 2
+    assert moved["solver.gram_blocks_formed"] == blocks
+    assert moved["solver.gram_tiles_skipped"] == blocks * t * (t - 1) // 2
+    epochs = [s for s in tr.spans if s.name == "bcd_epoch"]
+    assert [s.args["gram_tile"] for s in epochs] == [4] + [0] * (iters - 1)
+
+
+def test_bcd_fit_skips_no_gram_tile_at_a_narrow_block(problem):
+    """At B = 8 the shape rule keeps the one full product."""
+    from keystone_tpu.telemetry import trace_run
+
+    X, Y = problem
+    before = _tiles_skipped_so_far()
+    with trace_run() as tr:
+        BlockLeastSquaresEstimator(block_size=8, num_iter=2, lam=0.5).fit(
+            Dataset(X), Dataset(Y))
+    assert _tiles_skipped_so_far() == before
+    epochs = [s for s in tr.spans if s.name == "bcd_epoch"]
+    assert [s.args["gram_tile"] for s in epochs] == [0, 0]
+
+
 def test_lbfgs_donated_steps_match_scan_form(problem):
     """DenseLBFGSwithL2 now loops a donated `_lbfgs_step`; must be
     allclose-identical to the one-program `_lbfgs_fit` scan."""
