@@ -181,6 +181,23 @@ def _gram_tiles_skipped(block_size: int, tile: Optional[int]) -> int:
     return t * (t - 1) // 2
 
 
+def _allreduce_bytes(block_size: int, k: int, tile: Optional[int],
+                     forming: bool) -> int:
+    """Bytes one chip hands to the all-reduces over ``data`` in one block
+    step of `_bcd_epoch`, from the shapes and from what the partitioned
+    program reduces (PERF.md 5, the v5e compiler's listing): the (B, k)
+    correlation in every step, and in a forming step the Gram's partial
+    sums as the program forms them, the row panels of the upper triangle
+    before they are mirrored (or the one full product where ``tile`` is
+    None)."""
+    elems = block_size * k
+    if forming:
+        T = tile or block_size
+        elems += sum(min(T, block_size - s) * (block_size - s)
+                     for s in range(0, block_size, T))
+    return 4 * elems
+
+
 def _gram_upper_panels(Xb, tile: int):
     """``Xb.T @ Xb`` from the row panels of its upper triangle. Panel i is
     ``Xb[:, iT:(i+1)T].T @ Xb[:, iT:]``, a (T, B - iT) product: t =
@@ -469,6 +486,12 @@ class BlockLeastSquaresEstimator(LabelEstimator):
                     self.fit_intercept,
                     x_sharding=x_sharding,
                 )
+            # what a chip hands to all-reduces over `data`, from the shapes:
+            # nothing where the rows live on one chip
+            reduced = counter("solver.allreduce_bytes")
+            sharded = meshlib.n_data_shards(data.mesh) > 1
+            if sharded and self.fit_intercept:  # the sums behind xm, count, ym
+                reduced.inc(4 * (d_pad + 1 + Y.shape[1]))
             # a host scalar: `jnp.asarray` would launch a convert program
             lam = np.asarray(self.lam, X.dtype)
             # A block's Gram and its Cholesky factor do not change between
@@ -508,6 +531,9 @@ class BlockLeastSquaresEstimator(LabelEstimator):
                     counter("solver.gram_blocks_formed").inc(num_blocks)
                     counter("solver.gram_tiles_skipped").inc(
                         num_blocks * _gram_tiles_skipped(bs, tile))
+                if sharded:
+                    reduced.inc(num_blocks * _allreduce_bytes(
+                        bs, Y.shape[1], tile, forming=not reusing))
             with dispatch("_bcd_finalize"):
                 W, b = _bcd_finalize(W, xm, ym)
         return BlockLinearMapper(W, b if self.fit_intercept else None, self.block_size)

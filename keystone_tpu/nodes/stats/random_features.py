@@ -12,7 +12,7 @@
 
 from __future__ import annotations
 
-from functools import partial
+from functools import lru_cache, partial
 
 import jax
 import jax.numpy as jnp
@@ -40,6 +40,23 @@ def _draw_cosine_rf(seed, gamma, *, input_dim, num_features, distribution):
     return W, b
 
 
+@lru_cache(maxsize=None)
+def _draw_cosine_rf_on(mesh):
+    """`_draw_cosine_rf` as one program over the chips of ``mesh`` that
+    leaves W and b replicated: every chip draws the same numbers from the
+    same key. Drawn on one chip, each of them was copied to the others
+    again by every program that took it (`DevicePutWithSharding`, three
+    device-to-device copies an array and about 1 ms of host each: 8 ms in
+    front of the gather's program and as much in front of an apply's with
+    four branches on four chips; my chip run, PR 33)."""
+    from ...parallel.mesh import replicated_sharding
+
+    return jax.jit(
+        _draw_cosine_rf.__wrapped__,
+        static_argnames=("input_dim", "num_features", "distribution"),
+        out_shardings=replicated_sharding(mesh))
+
+
 class CosineRandomFeatures(Transformer):
     """cos(x Wᵀ + b) with W ~ gamma·N(0,1) (gaussian) or gamma·Cauchy,
     b ~ U[0, 2π]."""
@@ -60,14 +77,19 @@ class CosineRandomFeatures(Transformer):
     ):
         if distribution not in ("gaussian", "cauchy"):
             raise ValueError(f"unknown distribution {distribution!r}")
+        from ...parallel.mesh import current_mesh
         from ...telemetry import dispatch
 
         # drawn on the device by one program: numpy took 26 ms a branch
         # of 440 x 4,096 with the device idle, and its float64 arrays
         # became two uncounted convert programs a branch (my chip run,
-        # PR 28; PERF.md section 6)
+        # PR 28; PERF.md section 6). Across chips, by one program on all
+        # of them: the data the node will meet is sharded over that mesh
+        mesh = current_mesh()
+        draw = (_draw_cosine_rf if mesh.devices.size == 1
+                else _draw_cosine_rf_on(mesh))
         with dispatch("CosineRandomFeatures.draw"):
-            self.W, self.b = _draw_cosine_rf(
+            self.W, self.b = draw(
                 np.uint32(seed % 2**32), np.float32(gamma),
                 input_dim=input_dim, num_features=num_features,
                 distribution=distribution)

@@ -240,6 +240,27 @@ def test_the_random_parameters_are_drawn_on_the_device_and_counted():
     assert np.abs(np.asarray(cauchy.W)).max() > 100 * 0.05555  # heavy tails
 
 
+@pytest.mark.parametrize("chips", [1, 4])
+def test_every_chip_of_a_mesh_holds_the_same_random_parameters(chips):
+    """One counted program a branch on any mesh; across chips W and b
+    come out replicated (no program that takes them has to copy them
+    from the first chip) and are the numbers one chip draws."""
+    from keystone_tpu.nodes.stats import CosineRandomFeatures
+    from keystone_tpu.parallel.mesh import make_mesh, use_mesh
+    from keystone_tpu.telemetry import counter
+
+    with use_mesh(make_mesh(jax.devices()[:1])):
+        alone = CosineRandomFeatures(440, 256, gamma=0.05555, seed=7)
+    before = counter("dispatch.programs_executed").value
+    with use_mesh(make_mesh(jax.devices()[:chips])):
+        node = CosineRandomFeatures(440, 256, gamma=0.05555, seed=7)
+    assert counter("dispatch.programs_executed").value - before == 1
+    for drawn, want in ((node.W, alone.W), (node.b, alone.b)):
+        assert drawn.sharding.is_fully_replicated
+        assert len(drawn.devices()) == chips
+        np.testing.assert_array_equal(np.asarray(drawn), np.asarray(want))
+
+
 def test_the_command_line_runs_the_pipeline_at_small_sizes(capsys):
     """The README's example; with no sizes given the parser's defaults
     are `TimitConfig`'s, the source's."""

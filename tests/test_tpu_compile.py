@@ -233,6 +233,32 @@ def test_fused_operator_compiles_per_shard_on_four_chips(
             del _PROGRAM_CACHE[key]
 
 
+def _compiled_gather(n, rows, whole, mesh=None, shards=1):
+    """The gather of four 4,096-wide `CosineRandomFeatures` branches over
+    ``n`` frames of 440 dimensions as the optimizer builds it, one fused
+    program, compiled with the frames placed as ``rows`` and the random
+    parameters as ``whole``. Returns (compiled, branches, width)."""
+    from keystone_tpu.nodes.stats import CosineRandomFeatures
+    from keystone_tpu.nodes.util.fusion import (
+        FusedBatchTransformer,
+        _GatherConcatStage,
+    )
+
+    dim, branches, width = 440, 4, 4096
+    nodes = [CosineRandomFeatures(dim, 8, 0.05555, seed=i)
+             for i in range(branches)]
+    for node in nodes:  # shapes stand in for the random parameters
+        node.W = _aval((dim, width), jnp.float32, whole)
+        node.b = _aval((width,), jnp.float32, whole)
+    op = FusedBatchTransformer([_GatherConcatStage(nodes)])
+    statics, flat, treedef, fns = op._decompose()
+    program = op._build_program(mesh, shards, n, treedef, fns, statics=statics)
+    compiled = program.lower(
+        flat, _aval((n, dim), jnp.float32, rows),
+        _aval((n,), jnp.bool_, rows)).compile()
+    return compiled, branches, width
+
+
 def test_the_gathered_cosine_branches_fit_the_chip_at_timit_fit_s_size(one_chip):
     """`timit_fit`'s featurizer as the optimizer builds it: the gather of
     four 4,096-wide `CosineRandomFeatures` branches over 65,536 frames
@@ -240,27 +266,69 @@ def test_the_gathered_cosine_branches_fit_the_chip_at_timit_fit_s_size(one_chip)
     features, 4.29 GB; the branches' chunks are its only temporaries, so
     no second (n, d) array is held beside it, and every op carries the
     gather's scope and its branch's."""
-    from keystone_tpu.nodes.stats import CosineRandomFeatures
-    from keystone_tpu.nodes.util.fusion import (
-        FusedBatchTransformer,
-        _GatherConcatStage,
-    )
-
-    n, dim, branches, width = 65536, 440, 4, 4096
-    nodes = [CosineRandomFeatures(dim, 8, 0.05555, seed=i)
-             for i in range(branches)]
-    for node in nodes:  # shapes stand in for the random parameters
-        node.W = _aval((dim, width), jnp.float32, one_chip)
-        node.b = _aval((width,), jnp.float32, one_chip)
-    op = FusedBatchTransformer([_GatherConcatStage(nodes)])
-    statics, flat, treedef, fns = op._decompose()
-    program = op._build_program(None, 1, n, treedef, fns, statics=statics)
-    compiled = program.lower(
-        flat, _aval((n, dim), jnp.float32, one_chip),
-        _aval((n,), jnp.bool_, one_chip)).compile()
+    n = 65536
+    compiled, branches, width = _compiled_gather(n, one_chip, one_chip)
     memory = compiled.memory_analysis()
     assert memory.output_size_in_bytes == 4 * n * branches * width
     assert memory.temp_size_in_bytes < 4 * n * branches * width // 8
     hlo = compiled.as_text()
     assert hlo.count(
         "ks.Gather[4xCosineRandomFeatures]/ks.CosineRandomFeatures") >= branches
+
+
+def test_the_gathered_cosine_branches_stay_on_their_chip_at_timit_fit_4chip(mesh4):
+    """`timit_fit_4chip`'s featurizer: the same gather over 262,144 frames
+    on the (4,) mesh, `per_shard` inside `shard_map` with W and b
+    replicated. A chip writes its own 65,536 rows of features (4.29 GB)
+    and the program holds no collective."""
+    n = 262144
+    compiled, branches, width = _compiled_gather(
+        n, NamedSharding(mesh4, P("data")), NamedSharding(mesh4, P()),
+        mesh=mesh4, shards=4)
+    memory = compiled.memory_analysis()  # of one chip
+    assert memory.output_size_in_bytes == 4 * (n // 4) * branches * width
+    assert memory.temp_size_in_bytes < 4 * (n // 4) * branches * width // 8
+    assert not _collective_lines(compiled.as_text())
+
+
+def _collective_lines(hlo):
+    import re
+
+    return [line.strip() for line in hlo.splitlines() if re.search(
+        r" (all-reduce|all-gather|reduce-scatter|collective-permute|"
+        r"all-to-all)(-start)?\(", line)]
+
+
+def test_the_forming_sweep_all_reduces_its_panels_once_a_block_step(mesh4):
+    """`_bcd_epoch`'s forming trace on the (4,) mesh by the v5e's own
+    partitioner and all-reduce combiner (2,048 rows a chip, blocks of
+    1,024 in four panels): one all-reduce a block step, of the panels and
+    the correlation together, and X is never gathered. The benchmark's
+    `collective_ms_per_fit` finds the op by the name the device trace
+    prints for it."""
+    import json
+    import os
+    import re
+
+    from benchmark.trace_reduce import op_name
+    from keystone_tpu.nodes.learning import block_ls
+
+    n, B, blocks, k = 8192, 1024, 2, 16
+    rows, whole = P("data"), P()
+    aval = lambda shape, spec: _aval(shape, jnp.float32,
+                                     NamedSharding(mesh4, spec))
+    compiled = block_ls._bcd_epoch.lower(
+        aval((blocks, B, k), whole), aval((n, k), rows),
+        aval((n, blocks * B), rows), aval((), whole), B, blocks,
+        keep_factors=True, gram_tile=block_ls._gram_tile(B)).compile()
+    (reduce,) = _collective_lines(compiled.as_text())
+    result = reduce.split(" all-reduce(")[0]
+    dims = re.findall(r"f32\[([\d,]*)\]", result)
+    reduced = 4 * sum(int(np.prod([int(x) for x in d.split(",")]))
+                      for d in dims)
+    assert reduced == block_ls._allreduce_bytes(B, k, 256, forming=True)
+    with open(os.path.join(
+            os.path.dirname(__file__), "..", "benchmark", "layer_metrics",
+            "collective_ms_per_fit.json")) as f:
+        pattern = re.compile(json.load(f)["args"]["pattern"])
+    assert pattern.search("jit__bcd_epoch/" + op_name(reduce)), op_name(reduce)
