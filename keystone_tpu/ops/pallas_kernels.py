@@ -42,6 +42,7 @@ from __future__ import annotations
 
 import os
 from functools import partial
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -272,23 +273,48 @@ def rbf_block(X, Yb, gamma):
 # 32 and 958 GB a fit, at 85% of the HBM bandwidth (PERF.md section 5,
 # PR 25). This kernel keeps everything after the im2col in VMEM: one
 # GEMM against the folded filter bank, the rank-1 patch-mean correction,
-# the two-sided rectification, and sum-pooling expressed as a
-# block-diagonal 0/1 matmul — only the (n, gy, gx, 2K) pooled grid is
-# written back. A bank too wide for VMEM runs as filter blocks (grid
-# axis 1): the patches stay resident while the filter tiles stream past.
+# the two-sided rectification and the sum pool — only the (n, gy, gx,
+# 2K) pooled grid is written back. A bank too wide for VMEM runs as
+# filter blocks (grid axis 1): the patches stay resident while the
+# filter tiles stream past.
+#
+# How it pools (PR 34). The patch rows of an image are laid out CLASS by
+# class, a class being the positions that feed the same set of pooled
+# cells (`_pool_layout`: a rectangle of the position grid, so slices and
+# one concatenation put them so). The kernel rectifies a class's 8-row
+# tiles of the conv output and adds them to each other on the vector
+# unit, one add a vreg and nothing stored; what is left of a class, 8
+# rows of partial sums a sign, goes through a 0/1 matrix at HIGHEST
+# (`_pool_matrix`) to the output tile. At the CIFAR geometry that dot
+# contracts over 144 rows a group of two images. Before PR 34 the rows
+# were row-major, the rectified (1,472 x 1,024) f32 tile was stored
+# whole (6 MB of the 10 MB budget) and the dot contracted over all of
+# it: Mosaic split it into bf16 parts and pushed them through the
+# matrix unit six times against 8 rows, which was two thirds of a loop
+# iteration's vector operations and half its stores. Where ordering by
+# class would not halve the dot (a stride so small that nearly every
+# position is a class of its own) the layout is the identity and every
+# row goes to the dot, as then.
 #
 # Patches are fed to the MXU in bfloat16: at DEFAULT matmul precision
 # the MXU truncates f32 operands to bf16 anyway, so this halves patch
 # traffic with bit-for-bit-equivalent results vs the XLA conv path
 # (max rel. disagreement 1.8e-4 at 10,000 filters on the chip — the
 # same class as two DEFAULT-precision XLA convs of the same values).
+# Everything behind the GEMM is float32.
 #
-# Measured on one TPU v5 lite (2026-09-30, PR 27; PERF.md section 6 has
-# the runs): the kernel alone, in a loop over microbatches of 32 images
-# at 10,000 filters, XLA path 5.53 ms vs fused kernel 1.49 ms a
-# microbatch (3.7x). Unlike the standalone rectify_pool kernel above,
-# this one is ON by default on TPU; the XLA path runs on every other
-# backend and where the chooser raises `FusedConvIneligibleError`.
+# Measured on one TPU v5 lite (2026-10-02, PR 34; PERF.md section 6 has
+# the runs): the kernel alone, patch extraction included, in a loop
+# over microbatches of 32 images at 10,000 filters: 1.472 ms a
+# microbatch before PR 34 (PR 27: XLA's path 5.53), 0.702 at the same
+# tile of 512, 0.636 at the tile of 1,024 the freed VMEM lets in; the
+# outputs equal to 2.2e-7 of their largest value. The compiler's static
+# schedule says why: a grid step of 2 images x 512 filters was 6,240
+# bundles with 15,523 vector-ALU operations and 4,394 stores in them,
+# and is 2,707 with 8,333 and 1,646. Unlike the standalone rectify_pool
+# kernel above, this one is ON by default on TPU; the XLA path runs on
+# every other backend and where the chooser raises
+# `FusedConvIneligibleError`.
 
 
 def use_fused_conv() -> bool:
@@ -468,6 +494,17 @@ def conv_rectify_pool(
                     kernel_hwio.shape[0],
                 )
                 counter("pallas.fused_conv.traced").inc()
+                # what one loop iteration of that program does with the
+                # rectified rows: added up on the vector unit, and left
+                # for the pool dot to contract over
+                layout, (_, g_img, _, _) = _fused_conv_plan(
+                    images.shape[1], images.shape[2], images.shape[3],
+                    kernel_hwio.shape[3], pool, stride,
+                    kernel_hwio.shape[0])
+                counter("pallas.fused_conv.pool_rows_presummed").inc(
+                    g_img * layout.presummed_rows)
+                counter("pallas.fused_conv.pool_dot_rows").inc(
+                    g_img * layout.dot_rows)
                 return out
             except FusedConvIneligibleError:
                 pass
@@ -478,40 +515,167 @@ def conv_rectify_pool(
     )
 
 
-def _pool_matrix(pos_h: int, pos_w: int, posp: int,
-                 pool: int, stride: int, g: int) -> "np.ndarray":
-    """(R, g·posp) 0/1 sum-pool weights for ONE kernel loop iteration
-    (g images, R = round_up(g·cells, 8)): block-diagonal over the g
-    images, each block the (cells, posp) weights over that image's
-    flattened (i·pos_w + j) position index. Applying it per small group
-    instead of per full image-block keeps the pool GEMM's FLOPs linear
-    in the block size — the whole-block block-diagonal form scaled them
-    with b² (at the CIFAR geometry it out-FLOPed the conv GEMM ~3× at
-    f32-HIGHEST) — while 8-row grouping keeps the dot and the store
-    full-tile (a previous per-image variant with 4-row dots measured
-    SLOWER than the b² form; module docstring history)."""
+def _pool_axis_runs(pos: int, pool: int, stride: int) -> list:
+    """The runs of one axis of the conv-position grid: maximal intervals
+    [lo, hi) whose positions lie in the same, non-empty set of pooling
+    windows, as (lo, hi, windows). Positions no window covers are in no
+    run. Window i covers [i*stride, i*stride + pool), so a set recurs
+    only in adjacent positions and the runs are what partitions the
+    covered part of the axis."""
+    n = (pos - pool) // stride + 1
+    runs = []
+    for x in range(pos):
+        wins = tuple(i for i in range(n)
+                     if i * stride <= x < i * stride + pool)
+        if not wins:
+            continue
+        if runs and runs[-1][2] == wins:
+            runs[-1][1] = x + 1
+        else:
+            runs.append([x, x + 1, wins])
+    return [tuple(r) for r in runs]
+
+
+class _PoolLayout(NamedTuple):
+    """How one image's conv positions are laid out as patch rows, and
+    what the kernel does with each stretch of them.
+
+    posp: patch rows an image, a multiple of 16 (the bf16 tile: the
+        kernel slices the patches per group at dynamic offsets).
+    rects: the stretches as rectangles (y0, y1, x0, x1) of the position
+        grid, row-major inside each, each padded with zero rows to whole
+        8-row tiles (the last to `posp`); the identity layout is the
+        whole grid as one.
+    pieces: the same stretches for the kernel, (offset, tiles, valid,
+        summed): `tiles` 8-row tiles from row `offset`, the first
+        `valid` rows real positions. A summed piece is added up over its
+        tiles on the vector unit and hands 8 rows of partial sums to the
+        pool dot; any other goes to the dot as it is.
+    weights: (cells, reduced rows) 0/1, the pool matrix of one image
+        over the rows its pieces hand to the dot.
+    """
+    posp: int
+    rects: tuple
+    pieces: tuple
+    weights: "np.ndarray"
+
+    @property
+    def dot_rows(self) -> int:
+        """Rows an image hands to the pool dot (its contraction)."""
+        return self.weights.shape[1]
+
+    @property
+    def presummed_rows(self) -> int:
+        """Rows an image adds up on the vector unit before the dot."""
+        return sum(8 * t for _, t, _, summed in self.pieces if summed)
+
+
+def _pool_layout(pos_h: int, pos_w: int, pool: int,
+                 stride: int) -> _PoolLayout:
+    """The layout of the patch rows for a sum pool of `pool` at `stride`
+    over a (pos_h, pos_w) grid of conv positions; a function of these
+    four alone.
+
+    Two positions are of one CLASS when they feed the same set of pooled
+    cells (the same column of the 0/1 pool matrix). A class is a
+    rectangle, a run of rows times a run of columns (`_pool_axis_runs`),
+    so the rows can be put class by class with slices and one
+    concatenation, and the sum over a class needs no matrix: its tiles
+    are added to each other, 8 rows of partial sums a class are left,
+    and the pool dot contracts over those. At the CIFAR geometry (27 x
+    27, pool 14 stride 13) there are 9 classes: four blocks of 13 x 13
+    (176 rows padded), four edges of 13 (16) and the centre (8): 784
+    rows an image where row-major order has 736, 72 rows to the dot
+    where it had 736. Positions under no window are in no class and
+    leave the GEMM.
+
+    Where that does not at least halve the dot's contraction (a stride
+    so small that nearly every position is a class of its own) the
+    layout is the identity: one piece, every row to the dot."""
     import numpy as np
 
     gy = (pos_h - pool) // stride + 1
     gx = (pos_w - pool) // stride + 1
-    cells = gy * gx
-    M = np.zeros((_round_up(g * cells, 8), g * posp), np.float32)
-    for im in range(g):
+    npos = pos_h * pos_w
+    identity_posp = _round_up(npos, 16)
+    classes = [
+        ((y0, y1, x0, x1), [iy * gx + ix for iy in wy for ix in wx])
+        for y0, y1, wy in _pool_axis_runs(pos_h, pool, stride)
+        for x0, x1, wx in _pool_axis_runs(pos_w, pool, stride)
+    ] if gy > 0 and gx > 0 else []
+    if not classes or 2 * 8 * len(classes) > identity_posp:
+        weights = np.zeros((max(gy, 0) * max(gx, 0), identity_posp),
+                           np.float32)
         for iy in range(gy):
             for ix in range(gx):
-                r = im * cells + iy * gx + ix
                 for i in range(iy * stride, iy * stride + pool):
-                    for j in range(ix * stride, ix * stride + pool):
-                        M[r, im * posp + i * pos_w + j] = 1.0
+                    weights[iy * gx + ix,
+                            i * pos_w + ix * stride:
+                            i * pos_w + ix * stride + pool] = 1.0
+        return _PoolLayout(identity_posp, ((0, pos_h, 0, pos_w),),
+                           ((0, identity_posp // 8, npos, False),), weights)
+    pieces, columns, offset = [], [], 0
+    for (y0, y1, x0, x1), cells in classes:
+        valid = (y1 - y0) * (x1 - x0)
+        tiles = -(-valid // 8)
+        pieces.append((offset, tiles, valid, tiles > 1))
+        offset += 8 * tiles
+        # partial-sum row r holds rows r, r + 8, ... of the class; in a
+        # class of one tile it is row r itself, real only below `valid`
+        column = np.zeros((gy * gx, 8), np.float32)
+        column[cells, :min(valid, 8)] = 1.0
+        columns.append(column)
+    return _PoolLayout(_round_up(offset, 16),
+                       tuple(rect for rect, _ in classes), tuple(pieces),
+                       np.concatenate(columns, axis=1))
+
+
+def _pool_matrix(layout: _PoolLayout, g: int) -> "np.ndarray":
+    """(R, g * layout.dot_rows) 0/1 sum-pool weights for ONE kernel loop
+    iteration (g images, R = round_up(g * cells, 8)): block-diagonal over
+    the g images, each block the layout's (cells, dot_rows) weights over
+    the rows that image's pieces hand to the dot: 8 rows of partial sums
+    a summed class, the rows themselves elsewhere (`_pool_layout`). Per
+    group and not per image block, because the block-diagonal form's
+    FLOPs grow with the square of the images it spans; per 8 output rows,
+    because a 4-row dot and store measured slower than that (history in
+    git). Since PR 34 the contraction at the CIFAR geometry is 144 rows
+    where it was 1,472, so the `highest` dot splits and multiplies a
+    tenth of what it did."""
+    import numpy as np
+
+    cells, rows = layout.weights.shape
+    M = np.zeros((_round_up(g * cells, 8), g * rows), np.float32)
+    for im in range(g):
+        M[im * cells:(im + 1) * cells,
+          im * rows:(im + 1) * rows] = layout.weights
     return M
+
+
+def _class_ordered_patches(pat, layout: _PoolLayout):
+    """(n, pos_h, pos_w, d) patches -> (n, layout.posp, d): each
+    rectangle's rows together, padded with zero rows to whole tiles.
+    Slices and one concatenation, no gather: the compiler makes a copy a
+    rectangle of it (it re-tiles the extraction's output either way)
+    and one concatenate."""
+    n, _, _, d = pat.shape
+    ends = [offset for offset, *_ in layout.pieces[1:]] + [layout.posp]
+    parts = []
+    for (y0, y1, x0, x1), (offset, _, valid, _), end in zip(
+            layout.rects, layout.pieces, ends):
+        parts.append(lax.reshape(
+            lax.slice(pat, (0, y0, x0, 0), (n, y1, x1, d)), (n, valid, d)))
+        if end > offset + valid:
+            parts.append(lax.full((n, end - offset - valid, d), 0, pat.dtype))
+    return lax.concatenate(parts, 1)
 
 
 def _conv_rect_pool_kernel(
     pat_ref, g_ref, pmat_ref, colsum_ref, bias_ref, o_ref,
-    *, alpha, max_val, d_real, normalize, b, posp, grp, rows,
+    *, alpha, max_val, d_real, normalize, b, posp, grp, rows, pieces,
 ):
     g = g_ref[:]                                       # (dp, tk) bf16
-    pm = pmat_ref[:]                                   # (rows, grp·posp)
+    pm = pmat_ref[:]                                   # (rows, grp·dot_rows)
     cs = colsum_ref[:]
     bs = bias_ref[:]
 
@@ -530,72 +694,113 @@ def _conv_rect_pool_kernel(
                             keepdims=True) * (1.0 / d_real)
             z = z - means * cs
         out = z + bs
-        # HIGHEST: the rectified activations would otherwise be
-        # truncated to bf16 by the pool GEMM, a second rounding on top
-        # of the documented bf16 patch feed; the 0/1 pm operand is
-        # exact either way. Both the load and the store are
-        # tile-aligned: posp % 16 == 0 and rows % 8 == 0.
+        # (-alpha) - out is -out - alpha to the bit, in one vector
+        # subtraction a vreg where the negation made it two. Written for
+        # the whole group at once, but never whole anywhere: each vreg
+        # of `act` is made where a sum below consumes it
         act = jnp.concatenate(
             [jnp.maximum(max_val, out - alpha),
-             jnp.maximum(max_val, -out - alpha)],
+             jnp.maximum(max_val, (-alpha) - out)],
             axis=1,
         )
+        # lax and not jnp below: a jnp call is a jit of its own to trace,
+        # and some 150 of them a trace of this body were 2 s of every
+        # process's set-up (PERF.md section 6, PR 34)
+        zero_tile = jnp.zeros((8, act.shape[1]), act.dtype)
+        real_rows = {}  # an 8-row tile's rows below n, by n
+        parts = []
+        for im in range(grp):
+            for offset, tiles, valid, summed in pieces:
+                lo = im * posp + offset
+                if not summed:
+                    # every row to the dot: the padded ones meet zero
+                    # weights there
+                    parts.append(lax.slice_in_dim(act, lo, lo + 8 * tiles))
+                    continue
+                # the class's tiles added to each other. A padded row is
+                # not a zero (a zero patch rectifies to max(max_val,
+                # ±bias − alpha)), so the last tile goes through a mask
+                end = lo + 8 * (tiles - 1)
+                last = lax.slice_in_dim(act, end, end + 8)
+                n_real = valid - 8 * (tiles - 1)
+                if n_real < 8:
+                    if n_real not in real_rows:
+                        real_rows[n_real] = lax.broadcasted_iota(
+                            jnp.int32, zero_tile.shape, 0) < n_real
+                    last = lax.select(real_rows[n_real], last, zero_tile)
+                head = lax.reshape(lax.slice_in_dim(act, lo, end),
+                                   (tiles - 1, 8, act.shape[1]))
+                parts.append(lax.add(lax.reduce_sum(head, (0,)), last))
+        # HIGHEST: the partial sums would otherwise be truncated to bf16
+        # by the pool GEMM, a second rounding on top of the documented
+        # bf16 patch feed; the 0/1 pm operand is exact either way. The
+        # loads, the pieces and the store are tile-aligned: posp % 16 ==
+        # 0, every piece whole 8-row tiles, rows % 8 == 0.
         o_ref[pl.ds(i * rows, rows), :] = jnp.dot(
-            pm, act, preferred_element_type=jnp.float32,
+            pm, jnp.concatenate(parts, axis=0),
+            preferred_element_type=jnp.float32,
             precision=lax.Precision.HIGHEST)
         return carry
 
-    # a SEQUENTIAL loop on purpose: per-group z/act transients are the
-    # VMEM hogs, and fori_loop guarantees only one iteration's worth is
-    # live — the block chooser's budget is structural, not a scheduling
-    # guess (a Python-unrolled loop would let Mosaic keep several
-    # groups' transients in flight)
+    # a SEQUENTIAL loop on purpose: per-group z transients are the VMEM
+    # hog, and fori_loop guarantees only one iteration's worth is live —
+    # the block chooser's budget is structural, not a scheduling guess
+    # (a Python-unrolled loop would let Mosaic keep several groups'
+    # transients in flight)
     lax.fori_loop(0, b // grp, body, 0)
 
 
 # the 10 MB cap of the 16 MB VMEM absorbs scheduling slop
 _FUSED_CONV_VMEM_BUDGET = 10 * (1 << 20)  # keystone: ignore[KJ017]
+# four 128 x 128 matrix units a core (v4, v5e, v5p; two of 256 on v6e)
+_MXU_ROUND_LANES = 512
 
 
-def _fused_conv_vmem_bytes(posp: int, dp: int, b: int, g: int, R: int,
-                           kp: int, k2p: int, filter_bufs: int) -> int:
+def _fused_conv_vmem_bytes(posp: int, dot_rows: int, dp: int, b: int, g: int,
+                           R: int, kp: int, k2p: int,
+                           filter_bufs: int) -> int:
     """VMEM the kernel is accounted for at an image block of b, groups
-    of g images (R output rows each) and one filter block of kp lanes
-    (k2p for both signs) held in `filter_bufs` buffers: one when it is
-    the whole bank, two when it changes with the grid step.
+    of g images (R output rows each, `posp` patch rows and `dot_rows`
+    rows to the pool dot an image: `_pool_layout`) and one filter block
+    of kp lanes (k2p for both signs) held in `filter_bufs` buffers: one
+    when it is the whole bank, two when it changes with the grid step.
 
     Mosaic pads the lane (minor) dimension to 128: every (rows, k) f32
     buffer really occupies (rows, round_up(k, 128)) of VMEM — ignoring
     it produced a real scoped-vmem OOM at k=16 (21.5 MB actual vs 8.9 MB
-    estimated). The conv/rectify intermediates (z, act) are ONE group's
-    worth by construction (sequential fori_loop in the kernel), so they
-    don't scale with the block."""
+    estimated). The conv output z and what is rectified of it are ONE
+    group's worth by construction (sequential fori_loop in the kernel),
+    so they don't scale with the block. Of the rectified halves only
+    what goes to the pool dot is ever whole: 8 rows of partial sums a
+    summed class (72 rows an image at the CIFAR geometry where the
+    halves had 736), every row in the identity layout."""
     return (
         2 * b * posp * dp * 2            # patches, dbl-buf bf16
         + g * posp * kp * 4              # z (one group, f32)
-        + g * posp * k2p * 4             # act = both signs
+        + g * dot_rows * k2p * 4         # the pool dot's operand, both signs
         + 2 * (b // g) * R * k2p * 4     # pooled out, dbl-buf
-        + R * g * posp * 4               # group pool matrix
+        + R * g * dot_rows * 4           # group pool matrix
         + filter_bufs * dp * kp * 2      # filter block, bf16
     )
 
 
-def _fused_conv_largest_block(posp: int, dp: int, g: int, R: int,
-                              kp: int, k2p: int, filter_bufs: int) -> int:
+def _fused_conv_largest_block(posp: int, dot_rows: int, dp: int, g: int,
+                              R: int, kp: int, k2p: int,
+                              filter_bufs: int) -> int:
     """The largest image block, a multiple of g up to 32, whose working
     set fits the budget; 0 when not even one group does."""
-    # grouped conv working set (patches + per-group z/act + pooled out +
-    # pool matrix + filters) has no chain-formula equivalent; its own
-    # live-chip canary gates it
+    # grouped conv working set (patches + per-group z and partial sums +
+    # pooled out + pool matrix + filters) has no chain-formula
+    # equivalent; its own live-chip canary gates it
     b = 0
     while b + g <= 32 and _fused_conv_vmem_bytes(
-            posp, dp, b + g, g, R, kp, k2p, filter_bufs
+            posp, dot_rows, dp, b + g, g, R, kp, k2p, filter_bufs
     ) <= _FUSED_CONV_VMEM_BUDGET:
         b += g
     return b
 
 
-def _fused_conv_geometry(posp: int, dp: int, k: int,
+def _fused_conv_geometry(posp: int, dot_rows: int, dp: int, k: int,
                          cells: int) -> "tuple[int, int, int, int]":
     """(b, g, R, tk): image block, images per kernel loop iteration,
     output rows per iteration and filter tile, from shapes alone, so
@@ -603,7 +808,7 @@ def _fused_conv_geometry(posp: int, dp: int, k: int,
 
     Groups are tried largest-first — g images per iteration share one
     pool dot/store whose 8-row tiles are fully used when g·cells is a
-    multiple of 8 — and halved when a group's z/act transients (which
+    multiple of 8 — and halved when a group's z and partial sums (which
     scale with g) blow the budget, down to one image per iteration. b is
     always a multiple of g so the kernel's loop covers the block
     exactly; R is a multiple of 8 so stores stay tile-aligned.
@@ -613,14 +818,16 @@ def _fused_conv_geometry(posp: int, dp: int, k: int,
     pool is a sum per filter, so they are independent): tk is then a
     multiple of 128, the bank is padded with zero filters to a multiple
     of it, and no (position x filter) tensor wider than tk exists in
-    VMEM or in HBM. The widest tile that fits a tight group wins, then
-    the blocks are evened out (K = 1,100 is three tiles of 384, not
-    three of 512). Wide, because a loop iteration's cost is a part that
-    grows with the tile and a fixed part of its own, the dependent chain
-    of dot, rectifier and pool dot, and the image block hardly matters:
-    2.28, 1.87, 1.63 and 1.49 ms a microbatch of 32 at K = 10,000 at
-    tiles of 128, 256, 384 and 512, the same at image blocks of 8, 16
-    and 32 (PERF.md section 6, PR 27). b = 0: ineligible (one image at
+    VMEM or in HBM. The widest tile that fits a tight group wins, in
+    whole rounds of the four matrix units (512 lanes) once it is that
+    wide, then the blocks are evened out (K = 2,500 is three tiles of
+    896, not two of 1,024 and a third of 452). Wide, because a grid
+    step has a part that grows with the tile and a part of its own, and
+    the image block hardly matters; whole rounds, because the conv GEMM
+    gives each 128-lane column to one unit: 0.894, 0.702, 0.790, 0.636
+    and 0.689 ms a microbatch of 32 at K = 10,000 at tiles of 256, 512,
+    768, 1,024 and 1,152, and 0.692 at 512 with the microbatch one image
+    block (PERF.md section 6, PR 34). b = 0: ineligible (one image at
     128 filters does not fit, or there are no pooled cells)."""
     if cells <= 0:  # pool window larger than the conv-position grid:
         # no pooled output exists; plainly ineligible, not a crash
@@ -637,23 +844,43 @@ def _fused_conv_geometry(posp: int, dp: int, k: int,
     kp = _round_up(k, 128)
     for g, R in groups:
         b = _fused_conv_largest_block(
-            posp, dp, g, R, kp, _round_up(2 * k, 128), 1)
+            posp, dot_rows, dp, g, R, kp, _round_up(2 * k, 128), 1)
         if b > 0:
             return b, g, R, k
     for g, R in groups:
         for tk in range(kp - 128, 0, -128):
-            b = _fused_conv_largest_block(posp, dp, g, R, tk, 2 * tk, 2)
+            b = _fused_conv_largest_block(
+                posp, dot_rows, dp, g, R, tk, 2 * tk, 2)
             if b > 0:
+                # whole rounds of the matrix units, once the tile is
+                # that wide: the conv GEMM gives each 128-lane column of
+                # the tile to one unit, so 9 columns are three rounds
+                # with three units idle in the last
+                tk -= tk % _MXU_ROUND_LANES if tk > _MXU_ROUND_LANES else 0
                 k_blocks = -(-k // tk)  # as many as the widest tile
                 # takes, evenly sized
                 return b, g, R, _round_up(-(-k // k_blocks), 128)
     return 0, 1, _round_up(cells, 8), k
 
 
-def _fused_conv_block_images(posp: int, dp: int, k: int, cells: int) -> int:
+def _fused_conv_block_images(posp: int, dot_rows: int, dp: int, k: int,
+                             cells: int) -> int:
     """Largest eligible image block (0 = the geometry cannot fit VMEM);
     see `_fused_conv_geometry`."""
-    return _fused_conv_geometry(posp, dp, k, cells)[0]
+    return _fused_conv_geometry(posp, dot_rows, dp, k, cells)[0]
+
+
+def _fused_conv_plan(h: int, w: int, c: int, k: int, pool: int, stride: int,
+                     patch: int) -> "tuple[_PoolLayout, tuple]":
+    """The layout of the patch rows and the block geometry (b, g, R, tk)
+    the kernel runs (h, w, c) images against k filters of `patch` at;
+    from shapes alone."""
+    pos_h, pos_w = h - patch + 1, w - patch + 1
+    cells = ((pos_h - pool) // stride + 1) * ((pos_w - pool) // stride + 1)
+    layout = _pool_layout(pos_h, pos_w, pool, stride)
+    return layout, _fused_conv_geometry(
+        layout.posp, layout.dot_rows, _round_up(c * patch * patch, 128), k,
+        cells)
 
 
 @jax.named_scope("ks.conv_rectify_pool_pallas")
@@ -670,17 +897,13 @@ def conv_rectify_pool_pallas(
     n, h, w, c = images.shape
     d = c * patch * patch
     k = G_cmajor.shape[1]
-    pos_h, pos_w = h - patch + 1, w - patch + 1
-    npos = pos_h * pos_w
-    # 16, not 8: the kernel takes per-group DYNAMIC row slices of the
-    # bf16 patches ref at offsets i·g·posp, and the bf16 tile is (16,128)
-    posp = _round_up(npos, 16)
     dp = _round_up(d, 128)
-    gy = (pos_h - pool) // stride + 1
-    gx = (pos_w - pool) // stride + 1
+    gy = (h - patch + 1 - pool) // stride + 1
+    gx = (w - patch + 1 - pool) // stride + 1
     cells = gy * gx
-
-    b, g_img, rows, tk = _fused_conv_geometry(posp, dp, k, cells)
+    layout, (b, g_img, rows, tk) = _fused_conv_plan(
+        h, w, c, k, pool, stride, patch)
+    posp = layout.posp
     if b == 0:
         raise FusedConvIneligibleError("fused conv block does not fit VMEM")
     n_pad = _round_up(n, b)
@@ -690,16 +913,16 @@ def conv_rectify_pool_pallas(
     pat = lax.conv_general_dilated_patches(
         jnp.moveaxis(images, -1, 1), (patch, patch), (1, 1), "VALID"
     )  # (N, C·P·P, pos_h, pos_w), channel-major features
-    pat = jnp.moveaxis(pat, 1, -1).reshape(n, npos, d)
-    pat = jnp.pad(pat, ((0, n_pad - n), (0, posp - npos), (0, dp - d)))
-    pat = pat.reshape(n_pad * posp, dp).astype(jnp.bfloat16)
+    pat = jnp.pad(jnp.moveaxis(pat, 1, -1).astype(jnp.bfloat16),
+                  ((0, n_pad - n), (0, 0), (0, 0), (0, dp - d)))
+    pat = _class_ordered_patches(pat, layout).reshape(n_pad * posp, dp)
 
     r_img = rows // g_img  # output rows per image (== cells when tight;
     # padded groups are g=1 only, so this stays exact)
     # zero filters (zero colsum, zero bias) pad the bank to whole tiles;
     # their columns are sliced off below
     Gp = jnp.pad(G_cmajor, ((0, dp - d), (0, k_pad - k))).astype(jnp.bfloat16)
-    pmat = jnp.asarray(_pool_matrix(pos_h, pos_w, posp, pool, stride, g_img))
+    pmat = jnp.asarray(_pool_matrix(layout, g_img))
     cs = jnp.pad(jnp.asarray(colsum, jnp.float32), (0, k_pad - k))
     bs = jnp.pad(jnp.asarray(bias, jnp.float32), (0, k_pad - k))
 
@@ -711,7 +934,7 @@ def conv_rectify_pool_pallas(
             _conv_rect_pool_kernel,
             alpha=float(alpha), max_val=float(max_val),
             d_real=d, normalize=normalize, b=b, posp=posp,
-            grp=g_img, rows=rows,
+            grp=g_img, rows=rows, pieces=layout.pieces,
         ),
         grid=(n_pad // b, k_blocks),
         in_specs=[
@@ -719,7 +942,7 @@ def conv_rectify_pool_pallas(
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((dp, tk), lambda i, j: (0, j),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((rows, g_img * posp), lambda i, j: (0, 0),
+            pl.BlockSpec(pmat.shape, lambda i, j: (0, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, tk), lambda i, j: (0, j),
                          memory_space=pltpu.VMEM),

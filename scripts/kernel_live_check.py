@@ -8,10 +8,12 @@ Three gates per kernel, in order (each is a prerequisite for trusting
 the next):
 
 1. COMPILE: the kernel at the flagship geometry (conv: CIFAR k=256 at
-   the largest VMEM block; chains: the bench-tier item shapes) must
-   compile at a ragged batch (2·block+3, forcing a padded tail block)
-   — a scoped-vmem OOM or Mosaic reject here is the failure class
-   interpret-mode tests cannot see.
+   the largest VMEM block, the benchmark cell's 10,000 filters as
+   filter tiles, and one pool geometry that takes the identity layout;
+   chains: the bench-tier item shapes) must compile at a ragged batch
+   (2·block+3, forcing a padded tail block) — a scoped-vmem OOM or
+   Mosaic reject here is the failure class interpret-mode tests cannot
+   see.
 2. NUMERICS: on-chip agreement vs the XLA reference path at the same
    geometry (conv tolerance: the documented bf16-patch-feed class,
    ~5e-4 relative pooled over 196-element windows; chains: the same
@@ -20,7 +22,9 @@ the next):
 3. TIMING: chained fresh-valued reps inside one program, R vs R/2
    differenced so launch and dispatch costs cancel —
    prints per-rep seconds and kernel-only images/sec for the Pallas
-   path and the XLA reference path at the bench tier's batch.
+   path and the XLA reference path at the bench tier's batch; for the
+   cell's geometry a rep is one microbatch of 32 images, the
+   kernel-alone number PERF.md quotes.
 
 Run from the repo root on the live chip: python scripts/kernel_live_check.py
 ``--interpret`` runs the chain-kernel gates 1+2 in Pallas interpret
@@ -146,7 +150,9 @@ def check_chain_elementwise(interpret=False, timing=True):
           f"max rel err vs XLA = {err:.2e}", flush=True)
 
     if timing:
-        batch = 16384
+        # 16,384 (32, 32, 3) images are 8.6 GB an array on the chip (3
+        # channels padded to 128 lanes): two of them do not fit
+        batch = 4096
         xb = jnp.asarray(rng.random((batch,) + item).astype(np.float32))
         _timing_gate("elementwise_chain pallas",
                      lambda xp: elementwise_chain_pallas(statics, params, xp),
@@ -214,8 +220,10 @@ def check_chain_rectify_pool(interpret=False, timing=True):
                      xb)
 
 
-def main():
-    import jax
+def check_fused_conv(name, k, pool, stride, batch, reps=120):
+    """The fused conv+rectify+pool kernel on 32x32x3 images, 6x6 patches,
+    at `k` filters and one pool geometry: the three gates, the timing at
+    `batch` images a call against the XLA reference path."""
     import jax.numpy as jnp
 
     from keystone_tpu.ops import (
@@ -223,30 +231,20 @@ def main():
         conv_rectify_pool_reference,
         hwio_to_cmajor,
     )
-    from keystone_tpu.ops.pallas_kernels import _fused_conv_geometry
+    from keystone_tpu.ops.pallas_kernels import _fused_conv_plan
 
-    interpret = "--interpret" in sys.argv[1:]
-    if interpret:
-        # CPU smoke of the chain-kernel harness only — not a chip verdict
-        check_chain_elementwise(interpret=True, timing=False)
-        check_chain_rectify_pool(interpret=True, timing=False)
-        print("interpret-mode chain smoke ok (no chip verdict)", flush=True)
-        return
-
-    dev = jax.devices()[0]
-    print(f"device: {dev} ({dev.platform})", flush=True)
-
-    k, patch, c, h, w = 256, 6, 3, 32, 32
-    pool, stride, alpha = 14, 13, 0.25
-    # derive the chooser inputs from the geometry above (must match the
-    # kernel's own internal computation in conv_rectify_pool_pallas)
-    pos_h, pos_w = h - patch + 1, w - patch + 1
-    posp = -(-(pos_h * pos_w) // 16) * 16
-    dp = -(-(c * patch * patch) // 128) * 128
-    cells = ((pos_h - pool) // stride + 1) * ((pos_w - pool) // stride + 1)
-    b, g_img, rows, tk = _fused_conv_geometry(posp, dp, k, cells)
-    print(f"block chooser at posp={posp} dp={dp} cells={cells} k={k}: "
-          f"b={b}, {g_img} images and {rows} output rows a loop iteration, "
+    patch, c, h, w, alpha = 6, 3, 32, 32, 0.25
+    # the kernel's own layout of the patch rows and its block geometry
+    layout, (b, g_img, rows, tk) = _fused_conv_plan(
+        h, w, c, k, pool, stride, patch)
+    assert b > 0, f"gate 1 FAILED: no VMEM block at k={k}"
+    print(f"{name}: k={k} pool {pool} stride {stride}: {layout.posp} patch "
+          f"rows an image, "
+          + (f"{len(layout.rects)} classes, {layout.presummed_rows} rows "
+             f"summed on the vector unit" if layout.presummed_rows
+             else "identity layout")
+          + f", {layout.dot_rows} rows an image to the pool dot; b={b}, "
+          f"{g_img} images and {rows} output rows a loop iteration, "
           f"filter tile {tk}" + (" (the whole bank)" if tk == k else ""),
           flush=True)
 
@@ -266,23 +264,48 @@ def main():
     scale = np.abs(want).max()
     err = np.abs(got - want).max() / scale
     assert err < 2e-3, f"gate 2 FAILED: max rel err {err:.2e}"
-    print(f"gate 1+2 ok: compiled at b={b}, n={n_small}; "
+    print(f"{name} gate 1+2 ok: compiled at b={b}, n={n_small}; "
           f"max rel err vs XLA on-chip = {err:.2e}", flush=True)
 
     # --- gate 3: differenced chained-rep timing ------------------------
-    batch = 16384
     xb = jnp.asarray(rng.random((batch, h, w, c)).astype(np.float32))
+    _timing_gate(f"{name} pallas",
+                 lambda xp: conv_rectify_pool_pallas(
+                     xp, g, colsum, bias, alpha, 0.0, pool, stride, True,
+                     patch),
+                 xb, reps)
+    _timing_gate(f"{name} xla",
+                 lambda xp: conv_rectify_pool_reference(
+                     xp, kern, colsum, bias, alpha, 0.0, pool, stride, True),
+                 xb, reps)
 
-    def pallas_one(xp):
-        return conv_rectify_pool_pallas(
-            xp, g, colsum, bias, alpha, 0.0, pool, stride, True, patch)
 
-    def ref_one(xp):
-        return conv_rectify_pool_reference(
-            xp, kern, colsum, bias, alpha, 0.0, pool, stride, True)
+def main():
+    import jax
 
-    _timing_gate("pallas", pallas_one, xb)
-    _timing_gate("xla", ref_one, xb)
+    interpret = "--interpret" in sys.argv[1:]
+    if interpret:
+        # CPU smoke of the chain-kernel harness only — not a chip verdict
+        check_chain_elementwise(interpret=True, timing=False)
+        check_chain_rectify_pool(interpret=True, timing=False)
+        print("interpret-mode chain smoke ok (no chip verdict)", flush=True)
+        return
+
+    dev = jax.devices()[0]
+    print(f"device: {dev} ({dev.platform})", flush=True)
+
+    # RandomPatchCifar's pool (14 stride 13: class-ordered rows) at the
+    # port's 256 filters, the whole bank one block. 2,048 images a call:
+    # XLA's path passes 2 x f32[n,27,27,256] through HBM, 34 GB at the
+    # 16,384 this script once asked for
+    check_fused_conv("fused_conv", 256, 14, 13, batch=2048)
+    # the benchmark's cell (benchmark/configs/random_patch_cifar.json):
+    # the documented 10,000 filters at a microbatch of 32, as filter
+    # tiles; per_rep is the kernel alone a microbatch
+    check_fused_conv("fused_conv_cell", 10000, 14, 13, batch=32, reps=400)
+    # overlapping windows (36 cells an image): ordering would not halve
+    # the pool dot, so the rows stay row-major and all go to the dot
+    check_fused_conv("fused_conv_identity", 256, 5, 4, batch=2048)
 
     # --- chain megakernels (ops/chain_kernels.py) ----------------------
     check_chain_elementwise()

@@ -218,17 +218,20 @@ def test_fused_conv_vmem_accounting_lane_padding():
     OOM at k=16 on v5e (21.5 MB actual vs 8.9 MB estimated)."""
     from keystone_tpu.ops.pallas_kernels import _fused_conv_block_images
 
-    # CIFAR geometry: 27x27 valid conv -> posp=736, dp=128, cells=4
-    b16 = _fused_conv_block_images(736, 128, 16, 4)
-    b256 = _fused_conv_block_images(736, 128, 256, 4)
+    # CIFAR geometry: 27x27 valid conv, pool 14 stride 13 -> 784 class-
+    # ordered patch rows an image, 72 of partial sums to the pool dot
+    # (`_pool_layout`), dp=128, cells=4
+    b16 = _fused_conv_block_images(784, 72, 128, 16, 4)
+    b256 = _fused_conv_block_images(784, 72, 128, 256, 4)
     # k=16 must be budgeted like k=64 (lane padding: kp=128 and k2p=128
     # for both — the pre-fix unpadded budget OOM'd live at k=16: 21.5 MB
-    # actual vs 8.9 MB estimated). With the per-image sequential pool
-    # loop the z/act transients no longer scale with the block, so the
-    # block is much larger than the block-diagonal design's 8/4.
-    b64 = _fused_conv_block_images(736, 128, 64, 4)
+    # actual vs 8.9 MB estimated). With the per-group sequential loop
+    # the z transient does not scale with the block, and since PR 34
+    # the rectified halves are never whole, so the block is what the
+    # double-buffered patches leave room for: 401,408 bytes an image.
+    b64 = _fused_conv_block_images(784, 72, 128, 64, 4)
     assert b16 == b64 == 22, (b16, b64)
-    assert b256 == 14, b256
+    assert b256 == 20, b256
 
 
 def test_fused_conv_is_chosen_from_the_backend_and_the_master_switch(

@@ -85,7 +85,7 @@ def _conv_avals(n, image_dtype, sharding, k=K):
     (MICROBATCH, jnp.bfloat16, K),     # the precision planner's boundary
     # the benchmark's cell (benchmark/configs/random_patch_cifar.json):
     # the documented 10,000 filters at a microbatch of 32, which runs
-    # as filter tiles (16 image blocks of 2 by 20 filter blocks of 512)
+    # as filter tiles (16 image blocks of 2 by 10 filter blocks of 1,024)
     (32, jnp.float32, 10000),
 ], ids=["f32", "ragged", "bf16", "10000_filters"])
 def test_fused_conv_compiles_at_the_cifar_geometry(
@@ -117,6 +117,15 @@ def test_fused_conv_compiles_at_the_cifar_geometry(
     (call,) = [line.strip() for line in hlo.splitlines()
                if "tpu_custom_call" in line and " = " in line]
     assert pattern.search("jit_per_shard/" + op_name(call)), op_name(call)
+    # the patches the call takes are class-ordered, 784 rows an image
+    # where row-major order had 736 (`_pool_layout`), and slices and a
+    # concatenation put them so: no gather stands in front of the call
+    from keystone_tpu.ops.pallas_kernels import _fused_conv_plan
+
+    layout, (b, _, _, _) = _fused_conv_plan(H, W, C, k, POOL, STRIDE, PATCH)
+    assert layout.posp == 784
+    assert f"bf16[{-(-n // b) * b * 784},128]" in call, call
+    assert "gather" not in hlo
 
 
 @pytest.mark.parametrize("block_n", [3, None],
