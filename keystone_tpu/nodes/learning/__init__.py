@@ -35,7 +35,6 @@ from .classifiers import (
 )
 from .weighted_ls import BlockWeightedLeastSquaresEstimator, PerClassWeightedLeastSquares
 from .kernels import (
-    BlockKernelMatrix,
     GaussianKernelGenerator,
     GaussianKernelTransformer,
     KernelBlockLinearMapper,

@@ -3,17 +3,22 @@ Gauss-Seidel block coordinate descent, and blocked kernel model apply.
 
 Reference: nodes/learning/KernelGenerator.scala:18-206 (RBF via the
 dot-product trick, broadcast column block), KernelMatrix.scala:17-90
-(lazy column-block view with optional caching),
+(lazy column-block view with optional caching: here the blocks a fit
+keeps, `cache_kernel`),
 KernelRidgeRegression.scala:37-275 (arXiv:1602.05310 — per block:
 kernel col-block gen → treeReduce residual → local (B×B) solve →
 distributed model update; lineage truncation via checkpoint every 25
 blocks), KernelBlockLinearMapper.scala:28-90.
 
-TPU-native: the n×n kernel never materializes. One jitted `krr_step`
-(kernel block GEMM + replicated solve + residual update) is compiled
-once and reused for every block and epoch — the host loop only permutes
-block order. The reference's RDD checkpointing maps to the natural
-materialization of each step's outputs (no lineage to truncate).
+TPU-native: one jitted `_krr_step` (kernel block GEMM + replicated solve
++ residual update) takes its contiguous column block by a block index,
+and the host loop only shuffles the order of the blocks each epoch. A
+fit of one epoch never holds more than one (n, B) block of the kernel
+matrix; a fit of several keeps the blocks its first epoch forms
+(`cache_kernel`, the reference's `cacheKernel`) and its later epochs run
+the same step without the kernel generation. The reference's RDD
+checkpointing maps to the natural materialization of each step's
+outputs (no lineage to truncate).
 """
 
 from __future__ import annotations
@@ -83,60 +88,85 @@ class GaussianKernelGenerator(Estimator):
         )
 
 
-class BlockKernelMatrix:
-    """Lazy column-block view of K(X, X) with optional block caching
-    (KernelMatrix.scala:17-90)."""
+def block_order(seed: int, epoch: int, n_blocks: int):
+    """The order in which epoch ``epoch`` visits the column blocks: a
+    permutation of ``range(n_blocks)`` that depends on the seed and the
+    epoch alone (the reference's `blockPermuter`), so a resumed fit
+    replays it. Which rows a block holds never changes."""
+    return np.random.default_rng(seed + epoch).permutation(n_blocks)
 
-    def __init__(self, X, gamma: float, cache_blocks: bool = False):
-        self.X = X  # (n_pad, d) sharded
-        self.gamma = float(gamma)
-        self.cache_blocks = cache_blocks
-        self._cache = {}
 
-    def block(self, idx, block_size: int):
-        key = (int(idx), block_size)
-        if key in self._cache:
-            return self._cache[key]
-        Xb = jax.lax.dynamic_slice_in_dim(self.X, int(idx) * block_size, block_size, 0)
-        Kb = _rbf_block(self.X, Xb, self.gamma)
-        if self.cache_blocks:
-            self._cache[key] = Kb
-        return Kb
+@partial(jax.jit, static_argnames=("rows",))
+def _krr_rows(X, Y, mask, *, rows: int):
+    """X, Y and the mask on ``rows`` rows, a whole number of blocks:
+    zero rows added (their mask is 0) or padding rows dropped."""
+    def resized(a):
+        extra = rows - a.shape[0]
+        if extra <= 0:
+            return a[:rows]
+        return jnp.pad(a, [(0, extra)] + [(0, 0)] * (a.ndim - 1))
+
+    return resized(X), resized(Y), resized(mask)
+
+
+@jax.jit
+def _krr_init(Y):
+    return jnp.zeros_like(Y), jnp.zeros_like(Y)
 
 
 @partial(
-    jax.jit, static_argnames=("gamma", "use_pal"), donate_argnums=(3, 4)
+    jax.jit,
+    static_argnames=("gamma", "block_size", "use_pal", "keep_kernel"),
+    donate_argnums=(3, 4),
 )
-def _krr_step(X, Y, mask, alpha, KA, lam, gamma, block_ids, use_pal):
+def _krr_step(X, Y, mask, alpha, KA, lam, block, Kb=None, *, gamma,
+              block_size: int, use_pal: bool = False,
+              keep_kernel: bool = False):
     """One Gauss-Seidel block update of dual KRR (K + λI)α = Y.
 
-    KA tracks K @ alpha. For block b: solve
-      (K_bb + λI + eps) Δ = (Y_b − KA_b − λ α_b)
+    KA tracks K @ alpha. Block ``block`` is the contiguous rows
+    ``[block * block_size, (block + 1) * block_size)``: solve
+      (K_bb + λI) Δ = (Y_b − KA_b − λ α_b)
     then α_b += Δ, KA += K[:, b] Δ.
 
+    One function, two programs, both the XLA module `jit__krr_step`:
+
+    - ``Kb=None``: forms the (n, B) kernel column block from X (rows
+      and columns of padding zeroed by the mask). With ``keep_kernel``
+      it is also an output: returns ``(alpha, KA, Kb)``.
+    - ``Kb`` given (the block an earlier epoch kept): no kernel is
+      formed. ``Kb`` is NOT donated: every later epoch reads it again.
+
     alpha and KA are DONATED: the solver state is updated in place
-    across the block loop instead of allocating two fresh (n, k) buffers
-    per step — at the flagship shapes (n≈100k) that is ~2·n·k·4 bytes of
-    HBM churn per block removed. Callers must not reuse a passed-in
-    alpha/KA after the call (the fit loop rebinds both every step).
+    across the block loop. Callers must not reuse a passed-in alpha/KA
+    after the call (the fit loop rebinds both every step).
     """
     with jax.default_matmul_precision("highest"):
-        B = block_ids.shape[0]
-        Xb = jnp.take(X, block_ids, axis=0)
-        Kb = _rbf_block_jit(X, Xb, gamma, use_pal) * mask[:, None]  # (n, B) masked rows
-        Kbb = jnp.take(Kb, block_ids, axis=0)  # (B, B)
-        alpha_b = jnp.take(alpha, block_ids, axis=0)
-        resid_b = (
-            jnp.take(Y, block_ids, axis=0)
-            - jnp.take(KA, block_ids, axis=0)
-            - lam * alpha_b
-        )
-        delta = jax.scipy.linalg.solve(
-            Kbb + lam * jnp.eye(B, dtype=X.dtype), resid_b, assume_a="pos"
-        )
-        alpha = alpha.at[block_ids].add(delta)
-        KA = KA + Kb @ delta
-        return alpha, KA
+        B = block_size
+        start = block * B
+
+        def rows_of(a):
+            return jax.lax.dynamic_slice_in_dim(a, start, B, 0)
+
+        mask_b = rows_of(mask)
+        if Kb is None:
+            with jax.named_scope("ks.krr.kernel"):
+                Kb = (_rbf_block_jit(X, rows_of(X), gamma, use_pal)
+                      * mask[:, None] * mask_b[None, :])
+        with jax.named_scope("ks.krr.solve"):
+            alpha_b = rows_of(alpha)
+            resid_b = ((rows_of(Y) - rows_of(KA) - lam * alpha_b)
+                       * mask_b[:, None])
+            # a padding row's row and column of K_bb are zero: a one on
+            # its diagonal keeps the system definite at lam = 0
+            delta = jax.scipy.linalg.solve(
+                rows_of(Kb) + jnp.diag(lam + 1.0 - mask_b), resid_b,
+                assume_a="pos")
+        with jax.named_scope("ks.krr.update"):
+            alpha = jax.lax.dynamic_update_slice_in_dim(
+                alpha, alpha_b + delta, start, 0)
+            KA = KA + Kb @ delta
+        return (alpha, KA, Kb) if keep_kernel else (alpha, KA)
 
 
 @partial(jax.jit, static_argnames=("gamma", "block_size", "n_blocks", "use_pal"))
@@ -151,10 +181,15 @@ def _kernel_apply_scan(X, train_X, alpha, gamma, block_size, n_blocks, use_pal):
     rbf = rbf_block_pallas if use_pal else rbf_block_reference
 
     def body(acc, i):
-        Xb = jax.lax.dynamic_slice_in_dim(train_X, i * block_size, block_size, 0)
-        ab = jax.lax.dynamic_slice_in_dim(alpha, i * block_size, block_size, 0)
-        Kb = rbf(X, Xb, gamma)
-        return acc + Kb @ ab, None
+        # float32 at `highest`, as the mapper declares: at the TPU's
+        # default the scoring product would round Kb and alpha to bfloat16
+        with jax.named_scope("ks.krr.apply"), \
+                jax.default_matmul_precision("highest"):
+            Xb = jax.lax.dynamic_slice_in_dim(
+                train_X, i * block_size, block_size, 0)
+            ab = jax.lax.dynamic_slice_in_dim(
+                alpha, i * block_size, block_size, 0)
+            return acc + rbf(X, Xb, gamma) @ ab, None
 
     acc0 = jnp.zeros((X.shape[0], alpha.shape[1]), X.dtype)
     out, _ = jax.lax.scan(body, acc0, jnp.arange(n_blocks))
@@ -187,9 +222,11 @@ class KernelBlockLinearMapper(Transformer):
         K = _rbf_block(
             jnp.atleast_2d(jnp.asarray(x)), self.train_X, float(self.gamma)
         )
-        return (K @ self.alpha)[0]
+        return jnp.matmul(K, self.alpha, precision="highest")[0]
 
     def apply_batch(self, data: Dataset):
+        from ...telemetry import dispatch
+
         X = data.array
         n_train = self.train_X.shape[0]
         bs = min(self.block_size, n_train)
@@ -199,30 +236,39 @@ class KernelBlockLinearMapper(Transformer):
         if pad:
             # zero-padded anchor rows have alpha = 0, so their (nonzero!)
             # kernel values contribute nothing to K @ alpha
-            train_X = jnp.pad(train_X, [(0, pad), (0, 0)])
-            alpha = jnp.pad(alpha, [(0, pad), (0, 0)])
-        out = _kernel_apply_scan(
-            X, train_X, alpha, float(self.gamma), bs, n_blocks,
-            _use_pallas_now(),
-        )
+            with dispatch("pad", n=2):  # each `jnp.pad` is a program
+                train_X = jnp.pad(train_X, [(0, pad), (0, 0)])
+                alpha = jnp.pad(alpha, [(0, pad), (0, 0)])
+        with dispatch("_kernel_apply_scan"):
+            out = _kernel_apply_scan(
+                X, train_X, alpha, float(self.gamma), bs, n_blocks,
+                _use_pallas_now(),
+            )
         return data.with_data(out)
 
 
 class KernelRidgeRegression(LabelEstimator):
-    """Dual KRR via Gauss-Seidel BCD over permuted sample blocks
-    (KernelRidgeRegression.scala:37-275)."""
+    """Dual KRR via Gauss-Seidel BCD over contiguous column blocks of
+    the kernel matrix, visited in a seeded shuffled order each epoch
+    (KernelRidgeRegression.scala:37-275). With ``cache_kernel`` (the
+    reference's `cacheKernel`, KernelMatrix.scala:17-90) a fit of several
+    epochs keeps each (n, B) block it forms on the device and the later
+    epochs read it: n x n floats in all, held by the fit and by nothing
+    after it."""
 
     precision_tolerance = "exact"  # solver: f32/HIGHEST inputs
 
     def __init__(self, gamma: float, lam: float, block_size: int = 2048,
                  num_epochs: int = 1, seed: int = 0,
                  checkpoint_dir: Optional[str] = None,
-                 blocks_before_checkpoint: int = 25):
+                 blocks_before_checkpoint: int = 25,
+                 cache_kernel: bool = True):
         self.gamma = gamma
         self.lam = lam
         self.block_size = block_size
         self.num_epochs = num_epochs
         self.seed = seed
+        self.cache_kernel = cache_kernel
         # block-loop checkpoint/resume — the analog of the reference's RDD
         # lineage truncation + checkpointDir (KernelRidgeRegression.scala:
         # 35,199-205): solver state (alpha, KA) is persisted every
@@ -290,15 +336,16 @@ class KernelRidgeRegression(LabelEstimator):
     def _fit(self, data: Dataset, labels: Dataset) -> KernelBlockLinearMapper:
         import os
 
-        X = data.array
-        Y = labels.array * data.mask[:, None]
-        n_pad = X.shape[0]
+        from ...telemetry import counter, dispatch, span
+
+        X, Y = data.array, labels.array
         mask = data.mask_as(X.dtype)
-        B = min(self.block_size, n_pad)
-        # permutable blocks over VALID rows only; padded rows keep alpha=0
+        B = min(self.block_size, X.shape[0])
+        # contiguous blocks over the valid rows; padded rows keep alpha=0
         n_blocks = -(-data.count // B)
-        alpha = jnp.zeros((n_pad, Y.shape[1]), X.dtype)
-        KA = jnp.zeros_like(alpha)
+        if n_blocks * B != X.shape[0]:
+            with dispatch("_krr_rows"):
+                X, Y, mask = _krr_rows(X, Y, mask, rows=n_blocks * B)
         start_epoch, start_block = 0, 0
         ckpt = self._ckpt_path(data, labels)
         if ckpt and os.path.exists(ckpt):
@@ -306,25 +353,39 @@ class KernelRidgeRegression(LabelEstimator):
             alpha = jnp.asarray(state["alpha"])
             KA = jnp.asarray(state["KA"])
             start_epoch, start_block = int(state["epoch"]), int(state["block"])
-        lam = jnp.asarray(self.lam, X.dtype)
+        else:
+            with dispatch("_krr_init"):
+                alpha, KA = _krr_init(Y)
+        # host scalars: `jnp.asarray` would launch a convert program each
+        lam = np.asarray(self.lam, X.dtype)
         gamma = float(self.gamma)
+        use_pal = _use_pallas_now()
+        # the blocks this fit has formed and kept, by block index; freed
+        # when the fit returns (the model holds the anchors and alpha)
+        kept = {}
         done = 0
-        from ...telemetry import counter, dispatch, span
         for epoch in range(start_epoch, self.num_epochs):
-            # per-epoch seed so a resumed run replays identical block orders
-            perm = np.random.default_rng(self.seed + epoch).permutation(data.count)
-            pad = (-len(perm)) % (n_blocks * B)
-            ids = np.concatenate([perm, perm[: pad]]) if pad else perm
+            order = block_order(self.seed, epoch, n_blocks)
+            keep = self.cache_kernel and epoch + 1 < self.num_epochs
             first = start_block if epoch == start_epoch else 0
-            for b in range(first, n_blocks):
-                block_ids = jnp.asarray(ids[b * B : (b + 1) * B], jnp.int32)
+            for pos in range(first, n_blocks):
+                b = int(order[pos])
+                Kb = kept.get(b)
                 with span("krr_step", cat="step", layer="solver",
-                          epoch=epoch, block=b), dispatch("_krr_step"):
-                    alpha, KA = _krr_step(
-                        X, Y, mask, alpha, KA, lam, gamma, block_ids,
-                        use_pal=_use_pallas_now(),
-                    )
+                          epoch=epoch, block=b,
+                          kernel="formed" if Kb is None else "reused"), \
+                        dispatch("_krr_step"):
+                    alpha, KA, *formed = _krr_step(
+                        X, Y, mask, alpha, KA, lam, np.int32(b), Kb,
+                        gamma=gamma, block_size=B, use_pal=use_pal,
+                        keep_kernel=keep and Kb is None)
+                if formed:
+                    (kept[b],) = formed
                 counter("solver.steps").inc()
+                if Kb is None:
+                    counter("solver.kernel_blocks_formed").inc()
+                else:
+                    counter("solver.kernel_blocks_reused").inc()
                 done += 1
                 if ckpt and done % self.blocks_before_checkpoint == 0:
                     # atomic write: a crash mid-save must not corrupt the
@@ -332,9 +393,12 @@ class KernelRidgeRegression(LabelEstimator):
                     tmp = ckpt + ".tmp.npz"
                     np.savez(
                         tmp, alpha=np.asarray(alpha), KA=np.asarray(KA),
-                        epoch=epoch, block=b + 1,
+                        epoch=epoch, block=pos + 1,
                     )
                     os.replace(tmp, ckpt)
+            if epoch == start_epoch:
+                counter("solver.kernel_cache_bytes").inc(
+                    sum(K.nbytes for K in kept.values()))
         if ckpt and os.path.exists(ckpt):
             os.unlink(ckpt)  # fit completed; stale state must not resume
         # keep the anchors on device: np.asarray here would fetch a

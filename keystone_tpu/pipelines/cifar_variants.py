@@ -42,7 +42,11 @@ from ..nodes.stats import StandardScaler
 from ..nodes.util import Cacher, ClassLabelIndicatorsFromInt, MaxClassifier
 from ..nodes.util.fusion import FusedBatchTransformer
 from ..workflow import Pipeline
-from .random_patch_cifar import RandomPatchCifarConfig, learn_filters
+from .random_patch_cifar import (
+    RandomPatchCifarConfig,
+    learn_filters,
+    make_featurizer,
+)
 
 
 def _load(config):
@@ -162,9 +166,41 @@ def run_random_cifar(config: RandomCifarConfig):
 
 @dataclass
 class RandomPatchCifarKernelConfig(RandomPatchCifarConfig):
-    gamma: float = 2e-3
-    kernel_block: int = 2048
+    """`RandomPatchCifarKernelConfig`'s defaults in
+    RandomPatchCifarKernel.scala: numFilters 100, gamma 2e-4, blockSize
+    5000, cacheKernel true, numEpochs 1. ``lam`` has no default there
+    (the command line gives it); 10.0 is this port's own."""
+    num_filters: int = 100
+    gamma: float = 2e-4
+    kernel_block: int = 5000
     kernel_epochs: int = 1
+    cache_kernel: bool = True
+
+
+def build_kernel_pipeline(train, config: RandomPatchCifarKernelConfig):
+    """The lazy predictor `Pipeline` of RandomPatchCifarKernel
+    (RandomPatchCifarKernel.scala:62-75), its estimators bound to
+    ``train``: RandomPatchCifar's featurizer and scaler, then
+    `KernelRidgeRegression` and `MaxClassifier`."""
+    filters, whitener = learn_filters(train.data, config)
+    h, w, c = train.data.array.shape[1:]
+    featurizer = (
+        make_featurizer(filters, whitener, h, w, c, config).to_pipeline()
+        >> Cacher("features")
+    )
+    labels = ClassLabelIndicatorsFromInt(config.num_classes)(train.labels).get()
+    return (
+        featurizer.and_then(StandardScaler(), train.data)
+        .and_then(
+            KernelRidgeRegression(
+                config.gamma, config.lam, config.kernel_block,
+                config.kernel_epochs, seed=config.seed,
+                cache_kernel=config.cache_kernel,
+            ),
+            train.data, labels,
+        )
+        >> MaxClassifier()
+    )
 
 
 def run_random_patch_cifar_kernel(config: RandomPatchCifarKernelConfig):
@@ -172,35 +208,12 @@ def run_random_patch_cifar_kernel(config: RandomPatchCifarKernelConfig):
     (RandomPatchCifarKernel.scala:62-75)."""
     train, test = _load(config)
     t0 = time.perf_counter()
-    filters, whitener = learn_filters(train.data, config)
-    h, w, c = train.data.array.shape[1:]
-    featurizer = (
-        FusedBatchTransformer(
-            [
-                PixelScaler(),
-                Convolver(filters, h, w, c, whitener=whitener),
-                SymmetricRectifier(alpha=config.alpha),
-                Pooler(config.pool_stride, config.pool_size, pool_fn="sum"),
-                ImageVectorizer(),
-            ],
-            microbatch=config.microbatch,
-        ).to_pipeline()
-        >> Cacher("features")
-    )
-    labels = ClassLabelIndicatorsFromInt(config.num_classes)(train.labels).get()
-    predictor = (
-        featurizer.and_then(StandardScaler(), train.data)
-        .and_then(
-            KernelRidgeRegression(
-                config.gamma, config.lam, config.kernel_block, config.kernel_epochs
-            ),
-            train.data, labels,
-        )
-        >> MaxClassifier()
-    )
+    predictor = build_kernel_pipeline(train, config)
     evaluator = MulticlassClassifierEvaluator(config.num_classes)
+    train_eval = evaluator(predictor(train.data), train.labels)
     test_eval = evaluator(predictor(test.data), test.labels)
     return {
+        "train_error": train_eval.error,
         "test_error": test_eval.error,
         "test_accuracy": test_eval.accuracy,
         "seconds": time.perf_counter() - t0,

@@ -52,10 +52,16 @@ def cifar_kernel_main(argv=None):
         run_random_patch_cifar_kernel,
     )
 
+    # the source's flags and RandomPatchCifarKernelConfig's defaults for
+    # this app (numFilters 100, gamma 2e-4, blockSize 5000, numEpochs 1,
+    # cacheKernel true)
     p = _cifar_parser("RandomPatchCifarKernel")
-    p.add_argument("--gamma", type=float, default=2e-3)
-    p.add_argument("--kernel-block", type=int, default=2048)
+    p.set_defaults(num_filters=100)
+    p.add_argument("--gamma", type=float, default=2e-4)
+    p.add_argument("--kernel-block", type=int, default=5000)
     p.add_argument("--kernel-epochs", type=int, default=1)
+    p.add_argument("--cache-kernel", default=True,
+                   action=argparse.BooleanOptionalAction)
     args = p.parse_args(argv)
     r = run_random_patch_cifar_kernel(
         RandomPatchCifarKernelConfig(

@@ -107,7 +107,8 @@ def test_krr_still_learns_with_static_gamma():
     rng = np.random.default_rng(4)
     X = rng.uniform(-1, 1, size=(256, 2)).astype(np.float32)
     y = np.where(X[:, 0] * X[:, 1] > 0, 1.0, -1.0).astype(np.float32)[:, None]
-    model = KernelRidgeRegression(gamma=4.0, lam=1e-3, block_size=64).fit(
+    model = KernelRidgeRegression(gamma=4.0, lam=1e-3, block_size=64,
+                                  num_epochs=2).fit(
         Dataset(X), Dataset(y)
     )
     preds = np.sign(model.apply_batch(Dataset(X)).numpy()[:, 0])

@@ -140,6 +140,28 @@ def test_cli_registry_lists_and_dispatches(capsys):
     assert main(["NoSuchPipeline"]) == 2
 
 
+def test_cifar_kernel_app_runs_from_the_launcher(capsys):
+    """`python -m keystone_tpu RandomPatchCifarKernel` at a tiny size:
+    the source's flag names reach `build_kernel_pipeline`, two epochs
+    over three cached blocks."""
+    from keystone_tpu import telemetry
+    from keystone_tpu.__main__ import main
+
+    formed = telemetry.counter("solver.kernel_blocks_formed")
+    reused = telemetry.counter("solver.kernel_blocks_reused")
+    before = formed.value, reused.value
+    assert main([
+        "pipelines.images.cifar.RandomPatchCifarKernel",
+        "--synth-train", "240", "--synth-test", "60", "--num-filters", "16",
+        "--gamma", "2e-3", "--lam", "0.1", "--kernel-block", "80",
+        "--kernel-epochs", "2", "--cache-kernel"]) == 0
+    out = capsys.readouterr().out
+    assert "test_error=" in out
+    assert float(out.split("test_error=")[1].split()[0]) < 0.1
+    # the train error's and the test's pipelines share one fit
+    assert (formed.value - before[0], reused.value - before[1]) == (3, 3)
+
+
 def test_voc_sideband_model_files(tmp_path):
     """Reference --pcaFile/--gmm*File flags (VOCSIFTFisher.scala:49-67):
     precomputed PCA + GMM load from CSV and skip fitting."""

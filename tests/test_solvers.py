@@ -950,7 +950,8 @@ def test_krr_donated_step_matches_undonated_reference():
     """`_krr_step` donates (alpha, KA); one step must equal the same
     update computed without donation, and the fit loop's rebinding
     discipline must keep multi-step fits identical to a hand-rolled
-    undonated Gauss-Seidel loop."""
+    undonated Gauss-Seidel loop over the same contiguous blocks in the
+    same shuffled order."""
     import jax
     import jax.numpy as jnp
 
@@ -958,6 +959,7 @@ def test_krr_donated_step_matches_undonated_reference():
         KernelRidgeRegression,
         _krr_step,
         _rbf_block,
+        block_order,
     )
 
     rng = np.random.default_rng(7)
@@ -980,11 +982,8 @@ def test_krr_donated_step_matches_undonated_reference():
     B = 16
     n_blocks = -(-data.count // B)
     for epoch in range(2):
-        perm = np.random.default_rng(3 + epoch).permutation(data.count)
-        pad = (-len(perm)) % (n_blocks * B)
-        ids = np.concatenate([perm, perm[:pad]]) if pad else perm
-        for b in range(n_blocks):
-            blk = ids[b * B : (b + 1) * B]
+        for b in block_order(3, epoch, n_blocks):
+            blk = slice(b * B, (b + 1) * B)
             Kb = np.asarray(
                 _rbf_block(jnp.asarray(Xp), jnp.asarray(Xp[blk]), 0.5)
             ) * mask[:, None]
@@ -998,18 +997,16 @@ def test_krr_donated_step_matches_undonated_reference():
         np.asarray(model.alpha), alpha, atol=1e-3, rtol=1e-3)
 
     # single donated step vs an undonated jit of the same update
-    alpha0 = jnp.zeros((n_pad, k), jnp.float32)
-    KA0 = jnp.zeros_like(alpha0)
-    blk = jnp.arange(16, dtype=jnp.int32)
-    a1, K1 = _krr_step(
-        jnp.asarray(Xp), jnp.asarray(Yp), jnp.asarray(mask),
-        alpha0, KA0, jnp.float32(0.1), 0.5, blk, False)
-    undonated = jax.jit(
-        _krr_step.__wrapped__, static_argnames=("gamma", "use_pal"))
-    a2, K2 = undonated(
-        jnp.asarray(Xp), jnp.asarray(Yp), jnp.asarray(mask),
-        jnp.zeros((n_pad, k), jnp.float32),
-        jnp.zeros((n_pad, k), jnp.float32),
-        jnp.float32(0.1), 0.5, blk, False)
+    statics = ("gamma", "block_size", "use_pal", "keep_kernel")
+    undonated = jax.jit(_krr_step.__wrapped__, static_argnames=statics)
+
+    def step(fn):
+        return fn(
+            jnp.asarray(Xp), jnp.asarray(Yp), jnp.asarray(mask),
+            jnp.zeros((n_pad, k), jnp.float32),
+            jnp.zeros((n_pad, k), jnp.float32),
+            np.float32(0.1), np.int32(1), gamma=0.5, block_size=16)
+
+    (a1, K1), (a2, K2) = step(_krr_step), step(undonated)
     np.testing.assert_allclose(np.asarray(a1), np.asarray(a2), atol=1e-6)
     np.testing.assert_allclose(np.asarray(K1), np.asarray(K2), atol=1e-6)

@@ -70,6 +70,23 @@ def _aval(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
+def _module_name(compiled):
+    import re
+
+    return re.match(r"HloModule (\S+?),", compiled.as_text()).group(1)
+
+
+def _metric_pattern(metric):
+    import json
+    import os
+    import re
+
+    with open(os.path.join(
+            os.path.dirname(__file__), "..", "benchmark", "layer_metrics",
+            metric + ".json")) as f:
+        return re.compile(json.load(f)["args"]["pattern"])
+
+
 def _conv_avals(n, image_dtype, sharding, k=K):
     return (
         _aval((n, H, W, C), image_dtype, sharding),
@@ -87,7 +104,11 @@ def _conv_avals(n, image_dtype, sharding, k=K):
     # the documented 10,000 filters at a microbatch of 32, which runs
     # as filter tiles (16 image blocks of 2 by 10 filter blocks of 1,024)
     (32, jnp.float32, 10000),
-], ids=["f32", "ragged", "bf16", "10000_filters"])
+    # `cifar_kernel_fit` (random_patch_cifar_kernel.json): the source's
+    # 100 filters at the optimizer's default microbatch, one filter block
+    # under a lane tile (93 image blocks of 22)
+    (MICROBATCH, jnp.float32, 100),
+], ids=["f32", "ragged", "bf16", "10000_filters", "100_filters"])
 def test_fused_conv_compiles_at_the_cifar_geometry(
         one_chip, n, image_dtype, k):
     from keystone_tpu.ops import conv_rectify_pool_pallas
@@ -104,16 +125,9 @@ def test_fused_conv_compiles_at_the_cifar_geometry(
     # the benchmark's `fused_conv_ms_per_fit` finds the call by the name
     # the device trace prints for it (an op's HLO text, reduced by
     # `trace_reduce.op_name`) inside the fused program `jit_per_shard`
-    import json
-    import os
-    import re
-
     from benchmark.trace_reduce import op_name
 
-    with open(os.path.join(
-            os.path.dirname(__file__), "..", "benchmark", "layer_metrics",
-            "fused_conv_ms_per_fit.json")) as f:
-        pattern = re.compile(json.load(f)["args"]["pattern"])
+    pattern = _metric_pattern("fused_conv_ms_per_fit")
     (call,) = [line.strip() for line in hlo.splitlines()
                if "tpu_custom_call" in line and " = " in line]
     assert pattern.search("jit_per_shard/" + op_name(call)), op_name(call)
@@ -315,8 +329,6 @@ def test_the_forming_sweep_all_reduces_its_panels_once_a_block_step(mesh4):
     the correlation together, and X is never gathered. The benchmark's
     `collective_ms_per_fit` finds the op by the name the device trace
     prints for it."""
-    import json
-    import os
     import re
 
     from benchmark.trace_reduce import op_name
@@ -336,8 +348,67 @@ def test_the_forming_sweep_all_reduces_its_panels_once_a_block_step(mesh4):
     reduced = 4 * sum(int(np.prod([int(x) for x in d.split(",")]))
                       for d in dims)
     assert reduced == block_ls._allreduce_bytes(B, k, 256, forming=True)
-    with open(os.path.join(
-            os.path.dirname(__file__), "..", "benchmark", "layer_metrics",
-            "collective_ms_per_fit.json")) as f:
-        pattern = re.compile(json.load(f)["args"]["pattern"])
+    pattern = _metric_pattern("collective_ms_per_fit")
     assert pattern.search("jit__bcd_epoch/" + op_name(reduce)), op_name(reduce)
+
+
+# `cifar_kernel_fit` (benchmark/configs/random_patch_cifar_kernel.json):
+# 50,000 rows of 800 features, column blocks of 5,000, 10 classes
+KRR_N, KRR_D, KRR_B, KRR_K, KRR_GAMMA = 50000, 800, 5000, 10, 2e-4
+CHIP_BYTES = 16 * 2**30
+
+
+@pytest.mark.parametrize("cached", [False, True], ids=["forming", "cached"])
+def test_the_kernel_solver_s_step_fits_the_chip_at_cifar_kernel_fit_s_size(
+        one_chip, cached):
+    """`_krr_step`'s two programs at the cell's shapes: the forming step
+    writes its (n, B) kernel block (1 GB) as an output and holds no
+    second array of that size beside it; the cached step takes the block
+    as an argument and forms nothing. Both are the XLA module
+    `jit__krr_step`, which `krr_ms_per_fit` and `krr_roofline` find."""
+    from keystone_tpu.nodes.learning import kernels
+
+    f32 = lambda *shape: _aval(shape, jnp.float32, one_chip)
+    block_bytes = 4 * KRR_N * KRR_B
+    compiled = kernels._krr_step.lower(
+        f32(KRR_N, KRR_D), f32(KRR_N, KRR_K), f32(KRR_N),
+        f32(KRR_N, KRR_K), f32(KRR_N, KRR_K), f32(),
+        _aval((), jnp.int32, one_chip),
+        f32(KRR_N, KRR_B) if cached else None,
+        gamma=KRR_GAMMA, block_size=KRR_B, keep_kernel=not cached).compile()
+    memory = compiled.memory_analysis()
+    hlo = compiled.as_text()
+    assert memory.temp_size_in_bytes < block_bytes // 2
+    if cached:
+        assert memory.argument_size_in_bytes >= block_bytes
+        assert "ks.krr.kernel" not in hlo and "exponential" not in hlo
+    else:
+        assert memory.output_size_in_bytes >= block_bytes
+        assert "ks.krr.kernel" in hlo
+    assert "ks.krr.solve" in hlo and "ks.krr.update" in hlo
+    # ten kept blocks and this program beside them stay under the chip
+    assert (9 * block_bytes + memory.argument_size_in_bytes
+            + memory.output_size_in_bytes + memory.temp_size_in_bytes
+            < CHIP_BYTES)
+    for metric in ("krr_ms_per_fit", "krr_roofline"):
+        assert _metric_pattern(metric).search(_module_name(compiled))
+
+
+@pytest.mark.parametrize("rows", [KRR_N, 10000], ids=["train", "test"])
+def test_the_kernel_apply_fits_the_chip_at_cifar_kernel_fit_s_size(
+        one_chip, rows):
+    """`_kernel_apply_scan` over the cell's 50,000 anchors in ten blocks,
+    for the train error's 50,000 rows and the test set's 10,000: one
+    program, its body under `ks.krr.apply`, found by
+    `kernel_apply_ms_per_fit` and `kernel_apply_roofline`."""
+    from keystone_tpu.nodes.learning import kernels
+
+    f32 = lambda *shape: _aval(shape, jnp.float32, one_chip)
+    compiled = kernels._kernel_apply_scan.lower(
+        f32(rows, KRR_D), f32(KRR_N, KRR_D), f32(KRR_N, KRR_K), KRR_GAMMA,
+        KRR_B, KRR_N // KRR_B, False).compile()
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 3 * 4 * rows * KRR_B
+    assert "ks.krr.apply" in compiled.as_text()
+    for metric in ("kernel_apply_ms_per_fit", "kernel_apply_roofline"):
+        assert _metric_pattern(metric).search(_module_name(compiled))
