@@ -24,18 +24,28 @@ donated `_bcd_epoch` programs, one an epoch. A block's Gram ``Xb'Xb +
 lam I`` and its Cholesky factor depend on nothing an epoch changes (only
 the residual moves), so a fit of several epochs forms and factors each
 block's Gram in its first sweep, keeps the stacked upper factors
-``(num_blocks, B, B)`` (B/n of one copy of X), and every later sweep is
-the correlation, two triangular solves on the kept factor and the
-residual updates. A one-epoch fit keeps nothing. The sweep that forms
-the Grams (the only one of a one-epoch fit, the first of a longer one)
-computes each as the row panels of its upper triangle and copies the
-tiles below the diagonal from those above (`_gram_upper_panels`): the
-Gram is symmetric, and at a tile of B/16 its sixteen panels are 17/32 of
-the full product's work. The tile is a function of the block's width
-(`_gram_tile`), and a block too narrow for two tiles keeps the one full
-product. `_bcd_fit` (the one-program scan form, which forms
-every Gram in every epoch, each as one full product) is the numerics
-reference of all three.
+``(num_blocks, B, B)`` (B/n of one copy of X), and every later sweep is,
+a block, the correlation ``Xb'R``, two triangular solves on the kept
+factor and one residual update. A one-epoch fit keeps nothing.
+
+A block step solves for the block's change, ``(Xb'Xb + lam I) delta =
+Xb'R - lam Wb``, and subtracts ``Xb delta`` from the residual: two
+products over the block's slice of X, where adding the block's
+contribution back, solving the block again and subtracting it (the
+textbook step, `_bcd_fit`'s) takes three.
+
+The sweep that forms the Grams (the only one of a one-epoch fit, the
+first of a longer one) computes each as the row panels of its upper
+triangle and copies the tiles below the diagonal from those above
+(`_gram_upper_panels`): the Gram is symmetric, and at a tile of B/16 its
+sixteen panels are 17/32 of the full product's work. The tile is a
+function of the block's width (`_gram_tile`), and a block too narrow for
+two tiles keeps the one full product.
+
+`_bcd_fit` (the one-program scan form, which forms every Gram in every
+epoch, each as one full product, and runs the textbook step) is called
+by the tests alone: the independent statement that all three traces are
+held to.
 
 The estimator declares optimizer weight 3·numIter+1 — the number of
 passes over the input — feeding auto-caching (BlockLinearMapper.scala:205-210).
@@ -228,9 +238,17 @@ def _bcd_epoch(W, R, Xc, lam, block_size: int, num_blocks: int, *,
     """One BCD sweep over all feature blocks with the model W and
     residual R DONATED: XLA reuses their buffers for the outputs, so the
     per-epoch host loop updates solver state in place instead of
-    re-allocating (num_blocks, B, k) + (n, k) of HBM every epoch. Same
-    block_step arithmetic as `_bcd_fit_impl`'s inner scan, hence
-    allclose-identical fits (tests/test_solvers.py).
+    re-allocating (num_blocks, B, k) + (n, k) of HBM every epoch.
+
+    A block step is `_bcd_fit_impl`'s in another form. That one adds the
+    block's contribution back (``R1 = R + Xb Wb``), solves ``(G + lam I)
+    Wb_new = Xb'R1`` with ``G = Xb'Xb`` and subtracts ``Xb Wb_new``. Since
+    ``Xb'R1 = Xb'R + G Wb`` the same system reads ``(G + lam I)(Wb_new -
+    Wb) = Xb'R - lam Wb``, so this one solves for the change and updates
+    the residual once: the same mathematics at the same precision with
+    other rounding (fits allclose to `_bcd_fit`'s, and no farther from a
+    float64 run of the textbook step: tests/test_solvers.py), and one
+    product over the (n, B) slice fewer.
 
     A block's Gram ``Xb'Xb + lam I`` depends on nothing an epoch changes
     (only R moves), so a fit of several epochs forms and factors it once.
@@ -245,9 +263,9 @@ def _bcd_epoch(W, R, Xc, lam, block_size: int, num_blocks: int, *,
       ``(num_blocks, B, B)``.
     - ``factors`` given: every later sweep. The factors are a scanned
       input, NOT donated (each later epoch reads them again); a block
-      step is the residual add-back, the correlation ``Xb'R1``,
-      ``cho_solve`` on the kept factor and the residual update. No Gram
-      and no factorization; returns ``(W, R)``.
+      step is the correlation ``Xb'R``, ``cho_solve`` on the kept factor
+      and the residual update. No Gram and no factorization; returns
+      ``(W, R)``.
 
     ``cho_factor`` + ``cho_solve`` is what ``solve(assume_a="pos")``
     runs, on the same operands in the same order, so the kept-factor
@@ -268,26 +286,26 @@ def _bcd_epoch(W, R, Xc, lam, block_size: int, num_blocks: int, *,
             Xb = jax.lax.dynamic_slice_in_dim(
                 Xc, b_idx * block_size, block_size, axis=1)
             Wb = W[b_idx]
-            with jax.named_scope("ks.bcd.residual"):
-                R1 = R + Xb @ Wb
             with jax.named_scope("ks.bcd.gram"):
                 if factor is None:  # all-reduce over the data axis
                     XtX = (Xb.T @ Xb if gram_tile is None
                            else _gram_upper_panels(Xb, gram_tile))
                     G = XtX + eye
-                C = Xb.T @ R1            # all-reduce over the data axis
+                C = Xb.T @ R             # all-reduce over the data axis
             formed = None
             if factor is None and keep_factors:
                 with jax.named_scope("ks.bcd.factor"):
                     formed = factor = jax.scipy.linalg.cho_factor(G)[0]
+            # the block's change: (Xb'Xb + lam I) delta = Xb'R - lam Wb
             with jax.named_scope("ks.bcd.solve"):
+                rhs = C - lam * Wb
                 if factor is None:
-                    Wb_new = jax.scipy.linalg.solve(G, C, assume_a="pos")
+                    delta = jax.scipy.linalg.solve(G, rhs, assume_a="pos")
                 else:
-                    Wb_new = jax.scipy.linalg.cho_solve((factor, False), C)
+                    delta = jax.scipy.linalg.cho_solve((factor, False), rhs)
             with jax.named_scope("ks.bcd.residual"):
-                R2 = R1 - Xb @ Wb_new
-            return (W.at[b_idx].set(Wb_new), R2), formed
+                R = R - Xb @ delta
+            return (W.at[b_idx].set(Wb + delta), R), formed
 
         (W, R), formed = jax.lax.scan(
             block_step, (W, R), (jnp.arange(num_blocks), factors))
@@ -467,8 +485,8 @@ class BlockLeastSquaresEstimator(LabelEstimator):
         # model/residual allocation per epoch, and the host loop's
         # dispatches pipeline through jax's async queue (no sync until
         # the caller pulls the model). `_bcd_fit`/_bcd_fit_impl (the
-        # single-program scan form) remains the fused-pipeline path and
-        # the numerics reference for these steps.
+        # single-program scan form of the textbook step) is what the
+        # tests hold these steps to; nothing else calls it.
         with span(self.label, cat="solver", layer="solver",
                   blocks=num_blocks):
             if d_pad != d:
@@ -525,6 +543,8 @@ class BlockLeastSquaresEstimator(LabelEstimator):
                 if kept:
                     (factors,) = kept
                 counter("solver.steps").inc()
+                # one product a block step: the add-back the step leaves out
+                counter("solver.residual_addbacks_skipped").inc(num_blocks)
                 if reusing:
                     counter("solver.gram_blocks_reused").inc(num_blocks)
                 else:

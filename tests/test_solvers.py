@@ -558,14 +558,9 @@ def _bcd_inputs(problem, bs, rows):
     return data, labels, Xp, mask, nb
 
 
-@pytest.mark.parametrize("bs,iters,lam,center,rows", BCD_CASES)
-def test_bcd_donated_epochs_match_scan_form(problem, bs, iters, lam, center,
-                                            rows):
-    """BlockLeastSquaresEstimator loops a donated `_bcd_epoch`, which in a
-    fit of several epochs forms and factors each block's Gram in the first
-    sweep only; the result must be allclose-identical to the one-program
-    `_bcd_fit` scan (same block_step arithmetic, same op order), which
-    forms and factors every Gram in every epoch."""
+def _estimator_and_scan_fits(problem, bs, iters, lam, center, rows):
+    """One problem fitted twice: by the estimator, and by `_bcd_fit` on the
+    estimator's own inputs (which come back too)."""
     import jax.numpy as jnp
 
     from keystone_tpu.nodes.learning.block_ls import _bcd_fit
@@ -578,6 +573,22 @@ def test_bcd_donated_epochs_match_scan_form(problem, bs, iters, lam, center,
         Xp, labels.array, mask, jnp.asarray(lam, Xp.dtype), bs, nb, iters,
         center, x_sharding=None,
     )
+    return model, (Wref, bref), (Xp, labels.array, mask, nb)
+
+
+@pytest.mark.parametrize("bs,iters,lam,center,rows", BCD_CASES)
+def test_bcd_donated_epochs_match_scan_form(problem, bs, iters, lam, center,
+                                            rows):
+    """BlockLeastSquaresEstimator loops a donated `_bcd_epoch`, which in a
+    fit of several epochs forms and factors each block's Gram in the first
+    sweep only, and whose block step solves for the block's change from
+    `Xb'R - lam Wb` and updates the residual once. `_bcd_fit` is the
+    textbook form it is held to at `atol`/`rtol` 1e-5: one program, every
+    Gram formed and factored in every epoch, the block's contribution added
+    back into the residual, the block solved again and subtracted. The same
+    mathematics at the same precision; the rounding differs."""
+    model, (Wref, bref), _ = _estimator_and_scan_fits(
+        problem, bs, iters, lam, center, rows)
     np.testing.assert_allclose(
         np.asarray(model.W), np.asarray(Wref), atol=1e-5, rtol=1e-5)
     if center:
@@ -673,7 +684,7 @@ def test_bcd_later_epoch_trace_forms_no_gram_and_factors_nothing(
     text = lowered.as_text(debug_info=True)
     assert _gram_dots(text) == [] and "cholesky" not in text
     assert "triangular_solve" in text
-    # the correlation Xb'R1 stays under `ks.bcd.gram`, the triangular
+    # the correlation Xb'R stays under `ks.bcd.gram`, the triangular
     # solves under `ks.bcd.solve`
     for scope in ("ks.bcd.gram", "ks.bcd.solve", "ks.bcd.residual"):
         assert scope in text, scope
@@ -698,6 +709,80 @@ def test_bcd_epoch_traces_lower_under_the_module_name_the_readers_match(
     assert re.match(r"^jit__bcd_(prepare|epoch|finalize|fit)$", module)
 
 
+def _slice_products(text):
+    """The lowered program's `dot_general`s that take the (n, B) = (16, 4)
+    slice of X, or its transpose, against k = 3 columns, by the shape each
+    yields: (4, 3) is the correlation, (16, 3) a product into the residual."""
+    import re
+
+    return sorted(
+        out for lhs, out in re.findall(
+            r"dot_general.*: \(tensor<(\d+x\d+)xf32>, tensor<\d+x3xf32>\)"
+            r" -> tensor<(\d+x3)xf32>", text)
+        if lhs in ("16x4", "4x16"))
+
+
+@pytest.mark.parametrize("trace", ["one-epoch", "first-of-several", "later"])
+def test_bcd_epoch_traces_run_two_products_over_the_slice(
+        bcd_epoch_traces, trace):
+    """A block step reads its slice of X for the correlation `Xb'R` and for
+    the one residual update `R - Xb delta` (and for the Gram where it forms
+    one). It ran a third product, `R + Xb Wb`, which added the block's
+    contribution back before the block was solved again."""
+    text = bcd_epoch_traces[trace].as_text()
+    assert _slice_products(text) == ["16x3", "4x3"]
+    # nothing is added into an (n, k) array: the residual is only subtracted
+    # from
+    assert not [line for line in text.splitlines()
+                if "stablehlo.add" in line and "tensor<16x3xf32>" in line]
+    assert len(_gram_dots(text)) == (0 if trace == "later" else 1)
+
+
+def _textbook_bcd_float64(Xp, Y, mask, lam, bs, nb, iters, center):
+    """The three-product block step as the textbook has it, in numpy
+    float64 on the estimator's own inputs: add the block's contribution
+    back, solve the block again, subtract."""
+    X, Y, m = (np.asarray(a, np.float64) for a in (Xp, Y, mask))
+    if center:
+        count = m.sum()
+        X, Y = X - X.sum(0) / count, Y - Y.sum(0) / count
+    Xc, R = X * m[:, None], Y * m[:, None]
+    W = np.zeros((nb, bs, Y.shape[1]))
+    for _ in range(iters):
+        for b in range(nb):
+            Xb = Xc[:, b * bs:(b + 1) * bs]
+            R1 = R + Xb @ W[b]
+            W[b] = np.linalg.solve(Xb.T @ Xb + lam * np.eye(bs), Xb.T @ R1)
+            R = R1 - Xb @ W[b]
+    return W.reshape(nb * bs, -1)
+
+
+@pytest.mark.parametrize("bs,iters,lam,center,rows", BCD_CASES)
+def test_bcd_estimator_is_as_near_the_float64_textbook_step_as_the_scan_form(
+        problem, bs, iters, lam, center, rows):
+    """The estimator's W (the step that solves for the block's change) is no
+    farther from a float64 run of the textbook step than `_bcd_fit`'s
+    float32 W (the textbook step itself) is, within a factor of two. The
+    lambda 0.5 cases of 3 and 5 epochs are what fail if `- lam * Wb` is
+    dropped from the right-hand side: the estimator then strays by 7e-3
+    where `_bcd_fit` strays by 1e-6. In a first sweep W is zero and the two
+    forms are one."""
+    model, (Wscan, _), (Xp, Y, mask, nb) = _estimator_and_scan_fits(
+        problem, bs, iters, lam, center, rows)
+    W, Wscan = np.asarray(model.W), np.asarray(Wscan)
+    textbook = lambda: _textbook_bcd_float64(  # noqa: E731
+        Xp, Y, mask, lam, bs, nb, iters, center)
+    if lam == 0 and Xp.shape[1] != problem[0].shape[1]:
+        # the padded block's Gram has zero columns and no ridge: float64
+        # refuses the system and both float32 forms give no number
+        with pytest.raises(np.linalg.LinAlgError):
+            textbook()
+        assert np.isnan(W).all() and np.isnan(Wscan).all()
+        return
+    W64 = textbook()
+    assert np.abs(W - W64).max() <= 2 * np.abs(Wscan - W64).max()
+
+
 @pytest.mark.parametrize("iters", [1, 5])
 def test_bcd_fit_counts_grams_formed_and_reused(problem, iters):
     """A fit forms each block's Gram once and reuses it in every later
@@ -718,6 +803,7 @@ def test_bcd_fit_counts_grams_formed_and_reused(problem, iters):
     assert moved["solver.gram_blocks_formed"] == blocks
     assert moved.get("solver.gram_blocks_reused", 0.0) == (iters - 1) * blocks
     assert moved["solver.steps"] == iters
+    assert moved["solver.residual_addbacks_skipped"] == iters * blocks
     # the mask's conversion, `_bcd_prepare`, one `_bcd_epoch` an epoch,
     # `_bcd_finalize`: no program of its own forms the factors (24
     # features are whole blocks of 8, so there is no `pad`)
