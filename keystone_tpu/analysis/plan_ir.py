@@ -217,6 +217,9 @@ class _UnifiedModel:
         #: scorer's spill branches are dead — bit-for-bit the PR-19 plan
         self.allow_spill = bool(allow_spill)
         self._host_bw: Optional[float] = None
+        #: calls of `score`, added to ``planner.candidates_scored`` once
+        #: a solve (`plan_unified`): no span or counter inside the loop
+        self.candidates_scored = 0
         self._get_runs = get_runs
         order, _ = toposort(graph)
         self.order = [v for v in order if not isinstance(v, SinkId)]
@@ -422,6 +425,7 @@ class _UnifiedModel:
         objective every candidate (sequential composition included) is
         measured by. INF means a hard KP600 infeasibility (the
         assignment is pruned, never enforced-then-linted)."""
+        self.candidates_scored += 1
         families = a.fam()
         policies = a.pol()
         trails = a.trl()
@@ -1053,11 +1057,14 @@ def plan_unified(
         # KEYSTONE_OOC_SPILL=0 is the bit-for-bit kill switch: no spill
         # toggle is scored and the chosen plan matches PR 19 exactly
         allow_spill = bool(getattr(cfg, "ooc_spill", False))
-    model = _UnifiedModel(
-        graph, specs, mesh, hbm_budget_bytes, chunk_default, machine,
-        include_boundary_policies=include_boundary_policies,
-        precision_floor_bytes=precision_floor_bytes,
-        allow_spill=allow_spill)
+    from ..telemetry import counter, span
+
+    with span("price", cat="phase", layer="optimize", part="price"):
+        model = _UnifiedModel(
+            graph, specs, mesh, hbm_budget_bytes, chunk_default, machine,
+            include_boundary_policies=include_boundary_policies,
+            precision_floor_bytes=precision_floor_bytes,
+            allow_spill=allow_spill)
     if not model.roof.stages:
         return None
     has_axis = bool(model.cache_candidates or model.program_trails
@@ -1099,6 +1106,8 @@ def plan_unified(
                    "predicted_seconds":
                    (None if best_obj == _INF else float(best_obj)),
                    "feasible": best_obj != _INF})
+
+    counter("planner.candidates_scored").inc(model.candidates_scored)
 
     if not best_obj < seq_obj:
         best, best_obj = seq, seq_obj  # the plan IS the sequential one

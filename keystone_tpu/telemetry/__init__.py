@@ -70,6 +70,7 @@ from .instrument import (
     record_dispatch,
 )
 from .compile_events import compiles_snapshot, install_compile_listeners
+from .gc_events import install_gc_hook
 from .flight import (
     FlightRecorder,
     ensure_flight,
@@ -92,6 +93,9 @@ from .watchdog import (
 # `dispatch.programs_compiled` — the same always-on discipline as
 # `dispatch`.
 install_compile_listeners()
+# So is the collector's hook: two clock reads and three counter adds a
+# collection (`gc_events`).
+install_gc_hook()
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsDelta", "MetricsRegistry",

@@ -18,9 +18,13 @@ the file's protobuf wire format itself. From those this prints:
     scopes   device seconds (self time) per chain of ``ks.`` scopes, with
              the ops that took most of each
     modules  launches and device seconds per XLA module (jit name)
-    gaps     the longest idle gaps between device ops, each named by the
-             innermost ``ks:`` span open at the gap's middle, or
-             ``no span``
+    idle     the device's idle seconds (every gap between device ops, a
+             mean over the chips) summed by the innermost ``ks:`` span
+             open at each gap's middle, or ``no span``: the table
+             ``idle_by_span_s``, which sums to ``device_idle_s``
+    gaps     the longest idle gaps, each named the same way; a gap that
+             every chip of a mesh sees is listed once, with the number
+             of planes that saw it
 
 `reduce_planes` works on plain data, so a test hands it planes made by
 hand. The benchmark's own reduction is `benchmark/trace_reduce.py`,
@@ -219,7 +223,7 @@ def reduce_planes(planes, prefixes: Sequence[str] = (SPAN_PREFIX,),
         lambda: {"device_s": 0.0, "ops": collections.Counter()})
     modules: Dict[str, dict] = collections.defaultdict(
         lambda: {"launches": 0, "device_s": 0.0})
-    gap_list: List[Tuple[float, float]] = []
+    gap_list: List[Tuple[float, float]] = []  # (start, end), every plane
     busy = 0.0
     for lines in devices.values():
         merged = _union((ev.start, ev.end) for ev in lines[OPS_LINE])
@@ -241,18 +245,19 @@ def reduce_planes(planes, prefixes: Sequence[str] = (SPAN_PREFIX,),
             entry["device_s"] += own * 1e-9 / n_dev
             entry["ops"][_op_name(ev.name)] += own * 1e-9 / n_dev
         for (_, end), (start, _) in zip(merged, merged[1:]):
-            gap_list.append((start - end, (start + end) / 2))
+            gap_list.append((end, start))
 
     ks_spans = [ev for ev in spans if ev.name.startswith(SPAN_PREFIX)]
-
-    def span_at(t: float) -> str:
-        inside = [(ev.end - ev.start, ev.name) for ev in ks_spans
-                  if ev.start <= t < ev.end]
-        return min(inside)[1] if inside else NO_SPAN
+    gap_list.sort(key=lambda gap: gap[0] + gap[1])
+    names = _spans_at(ks_spans, [(s + e) / 2 for s, e in gap_list])
+    idle_by_span: Dict[str, float] = collections.defaultdict(float)
+    for (start, end), name in zip(gap_list, names):
+        idle_by_span[name] += (end - start) * 1e-9 / n_dev
 
     return {
         "devices": len(devices),
         "device_busy_s": busy * 1e-9 / n_dev,
+        "device_idle_s": sum(e - s for s, e in gap_list) * 1e-9 / n_dev,
         "spans": dict(sorted(per_span.items(),
                              key=lambda kv: -kv[1]["host_s"])),
         "scopes": {
@@ -262,9 +267,67 @@ def reduce_planes(planes, prefixes: Sequence[str] = (SPAN_PREFIX,),
                                       key=lambda kv: -kv[1]["device_s"])},
         "modules": dict(sorted(modules.items(),
                                key=lambda kv: -kv[1]["device_s"])),
-        "gaps": [{"seconds": length * 1e-9, "span": span_at(middle)}
-                 for length, middle in sorted(gap_list, reverse=True)[:gaps]],
+        "idle_by_span_s": dict(sorted(idle_by_span.items(),
+                                      key=lambda kv: -kv[1])),
+        "gaps": [
+            {"seconds": seconds, "span": name, "planes": planes,
+             "under": _spans_over(ks_spans, start, end)}
+            for seconds, name, planes, (start, end) in sorted(
+                _gaps_seen_once(gap_list, names), reverse=True)[:gaps]],
     }
+
+
+def _spans_at(ks_spans: Sequence[Event], times: Sequence[float]) -> List[str]:
+    """For each of ``times`` (ascending) the innermost of ``ks_spans``
+    open at it (the shortest; ties by name), or `NO_SPAN`: one sweep,
+    since a trace has a gap between every two ops."""
+    pending = sorted(ks_spans, key=lambda ev: ev.start, reverse=True)
+    open_: List[Event] = []
+    out = []
+    for t in times:
+        while pending and pending[-1].start <= t:
+            open_.append(pending.pop())
+        open_ = [ev for ev in open_ if t < ev.end]
+        out.append(min((ev.end - ev.start, ev.name) for ev in open_)[1]
+                   if open_ else NO_SPAN)
+    return out
+
+
+def _spans_over(ks_spans: Sequence[Event], start: float,
+                end: float) -> Dict[str, float]:
+    """The seconds of [start, end) under each innermost span, in the
+    order the spans come: a long gap split by what the host was doing."""
+    inside = [ev for ev in ks_spans if ev.start < end and start < ev.end]
+    cuts = sorted({start, end, *(t for ev in inside
+                                 for t in (ev.start, ev.end)
+                                 if start < t < end)})
+    out: Dict[str, float] = {}
+    for lo, hi in zip(cuts, cuts[1:]):
+        (name,) = _spans_at(inside, [(lo + hi) / 2])
+        out[name] = out.get(name, 0.0) + (hi - lo) * 1e-9
+    return out
+
+
+def _gaps_seen_once(gap_list, names, recent: int = 8):
+    """``gap_list`` (sorted by middle) with the gaps of different planes
+    that hold each other's middles made one: the chips of a mesh wait
+    for the host together. Gives (seconds, name, planes, the first
+    plane's interval) a gap: the seconds are the mean of what its planes
+    saw, the name the first's. Two gaps of one plane never hold each
+    other's middles, so a group has a plane once."""
+    groups: List[Tuple[str, List[Tuple[float, float]]]] = []
+    for (start, end), name in zip(gap_list, names):
+        middle = (start + end) / 2
+        for _, members in reversed(groups[-recent:]):
+            first_start, first_end = members[0]
+            if first_start <= middle < first_end \
+                    and start <= (first_start + first_end) / 2 < end:
+                members.append((start, end))
+                break
+        else:
+            groups.append((name, [(start, end)]))
+    return [(sum(e - s for s, e in members) * 1e-9 / len(members), name,
+             len(members), members[0]) for name, members in groups]
 
 
 def render(table: dict, top: int = 20) -> str:
@@ -282,9 +345,17 @@ def render(table: dict, top: int = 20) -> str:
     out += ["", f"{'launches':>9}{'device s':>12}  module"]
     for name, e in list(table["modules"].items())[:top]:
         out.append(f"{e['launches']:>9}{e['device_s']:>12.6f}  {name}")
-    out += ["", "longest idle gaps"]
+    out += ["", f"device idle {table['device_idle_s']:.6f} s, by the "
+            "innermost span at each gap's middle (idle_by_span_s)"]
+    for name, seconds in list(table["idle_by_span_s"].items())[:top]:
+        out.append(f"{seconds:>12.6f}  {name}")
+    out += ["", "longest idle gaps (planes that saw each)"]
     for gap in table["gaps"]:
-        out.append(f"  {gap['seconds'] * 1e3:10.3f} ms  {gap['span']}")
+        out.append(f"  {gap['seconds'] * 1e3:10.3f} ms  {gap['planes']:>2}  "
+                   f"{gap['span']}")
+        if len(gap["under"]) > 1:
+            out += [f"  {seconds * 1e3:18.3f} ms  under {name}"
+                    for name, seconds in gap["under"].items()]
     return "\n".join(out)
 
 

@@ -96,7 +96,7 @@ class _Dispatch(_Span):
 
     def __init__(self, label: str, n: int, args: dict):
         super().__init__(current_tracer(), label, "dispatch", "dispatch",
-                         args)
+                         None, args)
         self._n = n
 
     def __exit__(self, exc_type, exc, tb) -> bool:

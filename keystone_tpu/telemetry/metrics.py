@@ -13,7 +13,11 @@ contract documented in OBSERVABILITY.md:
   host.<layer>.seconds / host.<layer>.spans (counters; the layer clock
                                              of `spans.span`: self time
                                              and count of each layer's
-                                             spans, always on)
+                                             spans, always on; with
+                                             ``.<part>`` after the layer
+                                             for a span that names one)
+  host.gc.seconds / collections / full_collections
+                                            (counters; `gc_events`)
   executor.live_bytes                       (gauge; .max = observed peak)
   prefetch.queue_depth                      (gauge)
   prefetch.producer_stall_s / consumer_wait_s   (histograms, seconds)

@@ -1,7 +1,7 @@
 """The one span primitive, and the host tracer behind it.
 
-`span(name, cat, layer=..., rid=..., **args)` is the only way the program
-opens a span. A live span feeds three sinks:
+`span(name, cat, layer=..., part=..., rid=..., **args)` is the only way
+the program opens a span. A live span feeds three sinks:
 
   - the profiler: a `jax.profiler.TraceAnnotation` named
     ``ks:<layer or cat>:<name>``. The annotation checks the profiler's
@@ -12,7 +12,10 @@ opens a span. A live span feeds three sinks:
   - the layer clock (``layer=`` one of `LAYERS`, always on): the span's
     self time, its length less the layer spans opened inside it on the
     same thread, goes to the counter ``host.<layer>.seconds`` and 1 to
-    ``host.<layer>.spans``;
+    ``host.<layer>.spans``; a layer span that names a ``part`` adds the
+    same self time to ``host.<layer>.<part>.seconds`` as well (and 1 to
+    ``host.<layer>.<part>.spans``), so where every span of a layer names
+    a part the parts sum to the layer;
   - the host tracer (`Tracer`, on under `trace_run` / ``KEYSTONE_TRACE``):
     a closed `SpanRecord` whose parent is the span that was open on the
     thread, written as Chrome trace JSON by `export.write_trace`.
@@ -232,16 +235,31 @@ def _add_inner(parent: "_Span", seconds: float) -> None:
         parent._inner += seconds
 
 
+def pause_layer_span(seconds: float) -> None:
+    """Take ``seconds`` that this thread has just spent in a pause of
+    the interpreter (a collection: `gc_events`) out of the self time of
+    the innermost layer span open on it. No lock is taken: one
+    collection runs at a time and nothing else writes ``_paused``, and
+    the collector may stop a thread that holds `_inner_lock`."""
+    st = getattr(_layer_local, "stack", None)
+    if st:
+        st[-1]._paused += seconds
+
+
 #: layer -> the names of its two counters (looked up at every close:
 #: the registry can be reset under a span)
 _LAYER_COUNTERS = {layer: (f"host.{layer}.seconds", f"host.{layer}.spans")
                    for layer in LAYERS}
 
 
-def _count_layer(layer: str, seconds: float) -> None:
+def _count_layer(layer: str, seconds: float,
+                 part: Optional[str] = None) -> None:
     seconds_name, spans_name = _LAYER_COUNTERS[layer]
     counter(seconds_name).inc(seconds)
     counter(spans_name).inc()
+    if part is not None:
+        counter(f"host.{layer}.{part}.seconds").inc(seconds)
+        counter(f"host.{layer}.{part}.spans").inc()
 
 
 def record_layer_complete(layer: str, seconds: float) -> None:
@@ -266,15 +284,16 @@ class _Span:
     """One live span and its three sinks (module docstring). Exceptions
     close the span (marked ``error`` in the host tracer) and propagate."""
 
-    __slots__ = ("_tracer", "_name", "_cat", "_layer", "_args", "_rec",
-                 "_annotation", "_t0", "_inner")
+    __slots__ = ("_tracer", "_name", "_cat", "_layer", "_part", "_args",
+                 "_rec", "_annotation", "_t0", "_inner", "_paused")
 
     def __init__(self, tracer: Optional["Tracer"], name: str, cat: str,
-                 layer: Optional[str], args: Dict):
+                 layer: Optional[str], part: Optional[str], args: Dict):
         self._tracer = tracer
         self._name = name
         self._cat = cat
         self._layer = layer
+        self._part = part
         self._args = args
         self._rec = None
 
@@ -286,7 +305,7 @@ class _Span:
             self._rec = self._tracer.start(
                 self._name, self._cat, **self._args)
         if self._layer is not None:
-            self._inner = 0.0
+            self._inner = self._paused = 0.0
             _layer_stack().append(self)
             self._t0 = time.perf_counter()
         return self._rec
@@ -299,7 +318,9 @@ class _Span:
             # tolerate exception-path unwinding that skipped inner ends
             while st and st.pop() is not self:
                 pass
-            _count_layer(self._layer, max(0.0, length - self._inner))
+            _count_layer(
+                self._layer,
+                max(0.0, length - self._inner - self._paused), self._part)
             if st:
                 _add_inner(st[-1], length)
         if self._rec is not None:
@@ -422,19 +443,23 @@ def telemetry_active() -> bool:
 
 
 def span(name: str, cat: str = "span", *, layer: Optional[str] = None,
-         rid: Optional[str] = None, **args):
+         part: Optional[str] = None, rid: Optional[str] = None, **args):
     """Open a span (module docstring). ``layer`` names the layer whose
-    clock the span's self time is charged to; ``rid`` is an identifier
+    clock the span's self time is charged to, ``part`` the part of that
+    layer whose clock gets the same seconds; ``rid`` is an identifier
     that the spans of one request share. With neither a layer nor a
     tracer this is the shared no-op."""
     t = current_tracer()
-    if t is None and layer is None:
-        return _NOOP
-    if layer is not None and layer not in LAYERS:
+    if layer is None:
+        if part is not None:
+            raise ValueError(f"span part {part!r} needs a layer")
+        if t is None:
+            return _NOOP
+    elif layer not in LAYERS:
         raise ValueError(f"span layer {layer!r} is not one of {LAYERS}")
     if rid is not None:
         args["rid"] = rid
-    return _Span(t, name, cat, layer, args)
+    return _Span(t, name, cat, layer, part, args)
 
 
 class trace_run:

@@ -675,17 +675,22 @@ class GraphExecutor:
         """Execute up to ``graph_id``, returning its lazy Expression
         (GraphExecutor.scala:53-80). The plan, the warm-up scan and the
         walk are the ``force`` layer's, with the root's force
-        (`_arm_concurrent`); the optimizer inside is its own layer."""
+        (`_arm_concurrent`); the optimizer inside is its own layer. The
+        layer's parts: ``prepare`` (the structure check and the warm-up
+        scan) and ``walk`` (the graph walk and arming the root)."""
         from ..telemetry import span
 
         with span("execute", cat="phase", layer="force"):
             return self._execute(graph_id)
 
     def _execute(self, graph_id: GraphId) -> Expression:
+        from ..telemetry import span
+
         graph, prefixes = self._optimized_plan()
-        self._check_structure(graph)
-        self._warm_plan(graph)
-        self._rearm_warmup()  # fits may have resolved since the scan
+        with span("prepare", cat="phase", layer="force", part="prepare"):
+            self._check_structure(graph)
+            self._warm_plan(graph)
+            self._rearm_warmup()  # fits may have resolved since the scan
         env = PipelineEnv.get()
         profiler = getattr(env, "profiler", None)
         from ..telemetry import current_tracer
@@ -718,8 +723,9 @@ class GraphExecutor:
             self._memo[vid] = expr
             return expr
 
-        root = go(graph_id)
-        self._arm_concurrent(graph_id, root, graph)
+        with span("walk", cat="phase", layer="force", part="walk"):
+            root = go(graph_id)
+            self._arm_concurrent(graph_id, root, graph)
         return root
 
     # ---------------------------------------------------- concurrent force

@@ -17,7 +17,9 @@ only the saveable nodes' structural prefixes.
 
 from __future__ import annotations
 
+import hashlib
 import logging
+import re
 import weakref
 from dataclasses import dataclass, field
 from typing import Dict, List, Tuple
@@ -36,6 +38,86 @@ from .operators import (
 logger = logging.getLogger(__name__)
 
 Plan = Tuple[Graph, Dict[NodeId, Prefix]]
+
+#: an address in a repr: never part of a digest
+_ADDRESS = re.compile(r" at 0x[0-9a-f]+")
+#: tags that hold the planner's prediction and not its decision
+_PREDICTION_TAGS = ("planned_kernel_seconds",
+                    "planned_kernel_statically_verified")
+#: digest of a graph's labels -> digest of the last plan this process
+#: made for a graph of those labels (`_note_plan`)
+_LAST_PLAN: Dict[str, str] = {}
+_LAST_PLAN_MAX = 256
+
+
+def _hexdigest(text: str) -> str:
+    return hashlib.sha1(text.encode()).hexdigest()[:16]
+
+
+def plan_digest(graph: Graph) -> str:
+    """What the optimizer decided for ``graph``, as sixteen hex digits:
+    the operators' labels in topological order (cache and spill markers
+    among them), each with its ``planned_*`` tags, a fused program's
+    microbatch and a dataset's placement, and the planned chunk size.
+    Shapes, labels and tags only: no array is read, and nothing that
+    holds an object's address is hashed, so two processes that decide
+    alike read alike."""
+    from .env import planned_chunk_size
+
+    lines = [f"chunk={planned_chunk_size()}"]
+    operators = graph.operators
+    for vid in linearize(graph):
+        op = operators.get(vid)
+        if op is None:
+            continue  # a source or a sink
+        line = op.label
+        microbatch = getattr(op, "microbatch", None)
+        if microbatch is not None:
+            line += f" microbatch={microbatch}"
+        tags = [tag for tag in getattr(op, "__dict__", ())
+                if tag.startswith("planned_") and tag not in _PREDICTION_TAGS]
+        for tag in sorted(tags):
+            line += f" {tag}={getattr(op, tag)!r}"
+        if isinstance(op, DatasetOperator):
+            import jax
+
+            for leaf in jax.tree_util.tree_leaves(
+                    getattr(op.dataset, "data", None)):
+                spec = getattr(getattr(leaf, "sharding", None), "spec", None)
+                if spec is not None:
+                    line += f" placed={spec!r}"
+        lines.append(line)
+    return _hexdigest(_ADDRESS.sub("", "\n".join(lines)))
+
+
+def _note_plan(raw: Graph, planned: Graph) -> str:
+    """The plan's digest; adds 1 to ``planner.plan_changes`` when it
+    differs from the last plan made for a graph of ``raw``'s labels (a
+    first plan has nothing to differ from)."""
+    from ..telemetry import counter
+
+    digest = plan_digest(planned)
+    operators = raw.operators
+    key = _hexdigest("\n".join(
+        operators[vid].label
+        for vid in sorted(operators, key=lambda n: n.id)))
+    last = _LAST_PLAN.get(key)
+    if last is not None and last != digest:
+        counter("planner.plan_changes").inc()
+    if last is None and len(_LAST_PLAN) >= _LAST_PLAN_MAX:
+        _LAST_PLAN.clear()
+    _LAST_PLAN[key] = digest
+    return digest
+
+
+def _spec_pass(graph: Graph):
+    """`spec_pass(graph, {})`, which traces every stage under
+    `eval_shape`, as the `specs` part of the optimize layer."""
+    from ..analysis.propagate import spec_pass
+    from ..telemetry import span
+
+    with span("specs", cat="phase", layer="optimize", part="specs"):
+        return spec_pass(graph, {})
 
 
 class Rule:
@@ -72,11 +154,14 @@ class RuleExecutor:
         from ..telemetry import span
 
         plan: Plan = (graph, {})
-        with span("optimize", cat="phase", layer="optimize",
-                  batches=len(self.batches)):
+        # the self time of this span and of the batches' is the `rules`
+        # part of the layer: fusion, CSE, saved state; the planner rules
+        # name parts of their own
+        with span("optimize", cat="phase", layer="optimize", part="rules",
+                  batches=len(self.batches)) as record:
             for batch in self.batches:
                 with span(f"optimizer:{batch.name}", cat="phase",
-                          layer="optimize"):
+                          layer="optimize", part="rules"):
                     for iteration in range(batch.max_iterations):
                         new_plan = plan
                         for rule in batch.rules:
@@ -91,6 +176,9 @@ class RuleExecutor:
                                 iteration,
                                 plan[0].to_dot(),
                             )
+            digest = _note_plan(graph, plan[0])
+            if record is not None:
+                record.args["plan"] = digest
         return plan
 
     @staticmethod
@@ -340,12 +428,14 @@ class UnifiedPlannerRule(Rule):
             return plan
         from ..telemetry import counter, span
 
-        with span("unified_planner", cat="phase", layer="optimize"):
+        # the span's own self time is the `solve` part: the pre-filter,
+        # the sequential point, the chain DP and the descent
+        with span("unified_planner", cat="phase", layer="optimize",
+                  part="solve"):
             try:
                 from ..analysis.plan_ir import plan_unified
-                from ..analysis.propagate import spec_pass
 
-                specs, _ = spec_pass(graph, {})
+                specs, _ = _spec_pass(graph)
                 uplan = plan_unified(
                     graph, specs,
                     hbm_budget_bytes=cfg.hbm_budget_bytes,
@@ -368,7 +458,9 @@ class UnifiedPlannerRule(Rule):
                 "UnifiedPlannerRule: enforcing joint plan, predicted "
                 "%.3es -> %.3es (%s)", uplan.sequential_seconds,
                 uplan.joint_seconds, ", ".join(uplan.changed_kinds()))
-            graph = self._enforce(graph, uplan, cfg)
+            with span("enforce", cat="phase", layer="optimize",
+                      part="enforce"):
+                graph = self._enforce(graph, uplan, cfg)
         return graph, prefixes
 
     @staticmethod
@@ -662,12 +754,11 @@ class ShardingPlannerRule(Rule):
             # bodies under eval_shape).
             return plan
         with span("sharding_planner", cat="phase", layer="optimize",
-                  devices=int(mesh.devices.size)):
+                  part="sequential", devices=int(mesh.devices.size)):
             try:
                 from ..analysis.planner import plan_sharding
-                from ..analysis.propagate import spec_pass
 
-                specs, _ = spec_pass(graph, {})
+                specs, _ = _spec_pass(graph)
                 splan = plan_sharding(
                     graph, specs, mesh=mesh,
                     hbm_budget_bytes=cfg.hbm_budget_bytes)
@@ -835,12 +926,11 @@ class PrecisionPlannerRule(Rule):
         from ..telemetry import counter, span
 
         with span("precision_planner", cat="phase", layer="optimize",
-                  programs=len(targets)):
+                  part="sequential", programs=len(targets)):
             try:
                 from ..analysis.precision import plan_stage_precision
-                from ..analysis.propagate import spec_pass
 
-                specs, _ = spec_pass(graph, {})
+                specs, _ = _spec_pass(graph)
                 total_saved = 0
                 tagged = 0
                 for vid in targets:

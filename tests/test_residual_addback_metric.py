@@ -34,8 +34,11 @@ def test_manifest_entry_and_reader_file_agree(bench):
         "name": METRIC, "unit": "products", "better": "higher",
         "source": "program_counter", "layer": "solvers (nodes/learning/)",
         "moves": "fit_throughput", "workloads": CELLS}
-    # appended at the end: one put elsewhere reads as a change
-    assert bench.manifest["per_layer"][-1] == entry
+    # appended behind PR 36's last: one put elsewhere reads as a change
+    # (later PRs append behind it in turn)
+    names = [m["name"] for m in bench.manifest["per_layer"]]
+    assert names.index(METRIC) == names.index(
+        "kernel_blocks_reused_per_fit") + 1
     assert bench.reader_spec(METRIC) == {
         "reader": "counter_delta",
         "args": {"counter": "solver.residual_addbacks_skipped",
