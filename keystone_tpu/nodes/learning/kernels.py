@@ -15,8 +15,10 @@ TPU-native: one jitted `_krr_step` (kernel block GEMM + replicated solve
 and the host loop only shuffles the order of the blocks each epoch. A
 fit of one epoch never holds more than one (n, B) block of the kernel
 matrix; a fit of several keeps the blocks its first epoch forms
-(`cache_kernel`, the reference's `cacheKernel`) and its later epochs run
-the same step without the kernel generation. The reference's RDD
+(`cache_kernel`, the reference's `cacheKernel`), each with the Cholesky
+factor of its `K_bb + lam I` (which depends on nothing an epoch changes),
+and its later epochs run the same step without the kernel generation
+and without the factorization. The reference's RDD
 checkpointing maps to the natural materialization of each step's
 outputs (no lineage to truncate).
 """
@@ -119,7 +121,7 @@ def _krr_init(Y):
     static_argnames=("gamma", "block_size", "use_pal", "keep_kernel"),
     donate_argnums=(3, 4),
 )
-def _krr_step(X, Y, mask, alpha, KA, lam, block, Kb=None, *, gamma,
+def _krr_step(X, Y, mask, alpha, KA, lam, block, kept=None, *, gamma,
               block_size: int, use_pal: bool = False,
               keep_kernel: bool = False):
     """One Gauss-Seidel block update of dual KRR (K + λI)α = Y.
@@ -131,11 +133,16 @@ def _krr_step(X, Y, mask, alpha, KA, lam, block, Kb=None, *, gamma,
 
     One function, two programs, both the XLA module `jit__krr_step`:
 
-    - ``Kb=None``: forms the (n, B) kernel column block from X (rows
-      and columns of padding zeroed by the mask). With ``keep_kernel``
-      it is also an output: returns ``(alpha, KA, Kb)``.
-    - ``Kb`` given (the block an earlier epoch kept): no kernel is
-      formed. ``Kb`` is NOT donated: every later epoch reads it again.
+    - ``kept=None``: forms the (n, B) kernel column block from X (rows
+      and columns of padding zeroed by the mask) and solves by
+      ``solve(assume_a="pos")``. With ``keep_kernel`` the block and the
+      (B, B) upper Cholesky factor of ``K_bb + λI`` are also outputs:
+      returns ``(alpha, KA, (Kb, Ub))``. The factor is what ``solve``
+      takes inside (``cho_factor(a, lower=False)``, then ``cho_solve``),
+      on the same operand, so keeping it changes no arithmetic.
+    - ``kept=(Kb, Ub)`` (what an earlier epoch kept): no kernel is formed
+      and nothing is factored: ``cho_solve`` on ``Ub`` alone. Neither is
+      donated: every later epoch reads both again.
 
     alpha and KA are DONATED: the solver state is updated in place
     across the block loop. Callers must not reuse a passed-in alpha/KA
@@ -149,24 +156,32 @@ def _krr_step(X, Y, mask, alpha, KA, lam, block, Kb=None, *, gamma,
             return jax.lax.dynamic_slice_in_dim(a, start, B, 0)
 
         mask_b = rows_of(mask)
-        if Kb is None:
+        if kept is None:
             with jax.named_scope("ks.krr.kernel"):
                 Kb = (_rbf_block_jit(X, rows_of(X), gamma, use_pal)
                       * mask[:, None] * mask_b[None, :])
+        else:
+            Kb, Ub = kept
         with jax.named_scope("ks.krr.solve"):
             alpha_b = rows_of(alpha)
             resid_b = ((rows_of(Y) - rows_of(KA) - lam * alpha_b)
                        * mask_b[:, None])
-            # a padding row's row and column of K_bb are zero: a one on
-            # its diagonal keeps the system definite at lam = 0
-            delta = jax.scipy.linalg.solve(
-                rows_of(Kb) + jnp.diag(lam + 1.0 - mask_b), resid_b,
-                assume_a="pos")
+            if kept is None:
+                # a padding row's row and column of K_bb are zero: a one
+                # on its diagonal keeps the system definite at lam = 0
+                system = rows_of(Kb) + jnp.diag(lam + 1.0 - mask_b)
+                Ub = (jax.scipy.linalg.cho_factor(system, lower=False)[0]
+                      if keep_kernel else None)
+            if Ub is None:
+                delta = jax.scipy.linalg.solve(system, resid_b,
+                                               assume_a="pos")
+            else:
+                delta = jax.scipy.linalg.cho_solve((Ub, False), resid_b)
         with jax.named_scope("ks.krr.update"):
             alpha = jax.lax.dynamic_update_slice_in_dim(
                 alpha, alpha_b + delta, start, 0)
             KA = KA + Kb @ delta
-        return (alpha, KA, Kb) if keep_kernel else (alpha, KA)
+        return (alpha, KA, (Kb, Ub)) if keep_kernel else (alpha, KA)
 
 
 @partial(jax.jit, static_argnames=("gamma", "block_size", "n_blocks", "use_pal"))
@@ -254,7 +269,16 @@ class KernelRidgeRegression(LabelEstimator):
     reference's `cacheKernel`, KernelMatrix.scala:17-90) a fit of several
     epochs keeps each (n, B) block it forms on the device and the later
     epochs read it: n x n floats in all, held by the fit and by nothing
-    after it."""
+    after it. Beside each kept block the fit keeps the (B, B) upper
+    Cholesky factor of its ``K_bb + lam I``, formed in the step that
+    forms the block (features, ``gamma`` and ``lam`` set it; no epoch
+    changes it), so a step on a kept block runs two triangular solves and
+    no factorization: n x B floats more over a fit (a block's size, 1 /
+    n_blocks of the cache), replicated on a mesh, kept exactly as long
+    as the blocks and under the same condition. A fit of one epoch, or
+    without ``cache_kernel``, keeps neither. Counted by
+    ``solver.kernel_factors_formed`` / ``_reused`` / ``kernel_factor_bytes``
+    (`OBSERVABILITY.md`)."""
 
     precision_tolerance = "exact"  # solver: f32/HIGHEST inputs
 
@@ -360,8 +384,9 @@ class KernelRidgeRegression(LabelEstimator):
         lam = np.asarray(self.lam, X.dtype)
         gamma = float(self.gamma)
         use_pal = _use_pallas_now()
-        # the blocks this fit has formed and kept, by block index; freed
-        # when the fit returns (the model holds the anchors and alpha)
+        # the blocks this fit has formed and kept, by block index, each
+        # with the factor of its K_bb + lam I: (Kb, Ub). Freed when the
+        # fit returns (the model holds the anchors and alpha)
         kept = {}
         done = 0
         for epoch in range(start_epoch, self.num_epochs):
@@ -370,22 +395,28 @@ class KernelRidgeRegression(LabelEstimator):
             first = start_block if epoch == start_epoch else 0
             for pos in range(first, n_blocks):
                 b = int(order[pos])
-                Kb = kept.get(b)
+                reusing = b in kept
+                keeping = keep and not reusing
                 with span("krr_step", cat="step", layer="solver",
                           epoch=epoch, block=b,
-                          kernel="formed" if Kb is None else "reused"), \
+                          kernel="reused" if reusing else "formed",
+                          factor=("reused" if reusing else
+                                  "formed" if keeping else "unkept")), \
                         dispatch("_krr_step"):
                     alpha, KA, *formed = _krr_step(
-                        X, Y, mask, alpha, KA, lam, np.int32(b), Kb,
-                        gamma=gamma, block_size=B, use_pal=use_pal,
-                        keep_kernel=keep and Kb is None)
+                        X, Y, mask, alpha, KA, lam, np.int32(b),
+                        kept.get(b), gamma=gamma, block_size=B,
+                        use_pal=use_pal, keep_kernel=keeping)
                 if formed:
                     (kept[b],) = formed
                 counter("solver.steps").inc()
-                if Kb is None:
-                    counter("solver.kernel_blocks_formed").inc()
-                else:
+                if reusing:
                     counter("solver.kernel_blocks_reused").inc()
+                    counter("solver.kernel_factors_reused").inc()
+                else:
+                    counter("solver.kernel_blocks_formed").inc()
+                    if keeping:
+                        counter("solver.kernel_factors_formed").inc()
                 done += 1
                 if ckpt and done % self.blocks_before_checkpoint == 0:
                     # atomic write: a crash mid-save must not corrupt the
@@ -398,7 +429,9 @@ class KernelRidgeRegression(LabelEstimator):
                     os.replace(tmp, ckpt)
             if epoch == start_epoch:
                 counter("solver.kernel_cache_bytes").inc(
-                    sum(K.nbytes for K in kept.values()))
+                    sum(Kb.nbytes for Kb, _ in kept.values()))
+                counter("solver.kernel_factor_bytes").inc(
+                    sum(Ub.nbytes for _, Ub in kept.values()))
         if ckpt and os.path.exists(ckpt):
             os.unlink(ckpt)  # fit completed; stale state must not resume
         # keep the anchors on device: np.asarray here would fetch a

@@ -1,8 +1,9 @@
 """`KernelRidgeRegression`'s block structure, as the source has it
 (KernelRidgeRegression.scala:37-275, KernelMatrix.scala:17-90): contiguous
 column blocks of the kernel matrix visited in a seeded shuffled order, an
-optional cache of the blocks a fit's first epoch forms, and a
-Gauss-Seidel iteration that converges to the dual system's solution."""
+optional cache of the blocks a fit's first epoch forms, each kept with
+the Cholesky factor of its diagonal part, and a Gauss-Seidel iteration
+that converges to the dual system's solution."""
 
 import numpy as np
 import pytest
@@ -16,7 +17,9 @@ from keystone_tpu.nodes.learning.kernels import (
 )
 
 COUNTERS = ("solver.steps", "solver.kernel_blocks_formed",
-            "solver.kernel_blocks_reused", "solver.kernel_cache_bytes")
+            "solver.kernel_blocks_reused", "solver.kernel_cache_bytes",
+            "solver.kernel_factors_formed", "solver.kernel_factors_reused",
+            "solver.kernel_factor_bytes")
 
 
 def _problem(n=96, d=5, k=3, seed=0):
@@ -65,6 +68,7 @@ def test_the_cache_changes_what_is_formed_and_not_the_model(cache_kernel):
         assert counts["kernel_blocks_formed"] == epochs * blocks
         assert counts["kernel_blocks_reused"] == 0
         assert counts["kernel_cache_bytes"] == 0
+        assert not any(counts[name] for name in counts if "factor" in name)
     assert set(vars(model)) == {"train_X", "alpha", "gamma", "block_size"}
 
 
@@ -73,7 +77,122 @@ def test_a_fit_of_one_epoch_keeps_no_block():
     _, counts = _counted(lambda: KernelRidgeRegression(
         0.3, 0.5, block_size=24).fit(Dataset(X), Dataset(Y)))
     assert counts == {"steps": 4, "kernel_blocks_formed": 4,
-                      "kernel_blocks_reused": 0, "kernel_cache_bytes": 0}
+                      "kernel_blocks_reused": 0, "kernel_cache_bytes": 0,
+                      "kernel_factors_formed": 0, "kernel_factors_reused": 0,
+                      "kernel_factor_bytes": 0}
+
+
+def test_a_kept_block_s_factor_is_formed_once_and_read_in_every_later_epoch():
+    """Three epochs over six blocks: each block's Cholesky factor is
+    formed with the block, kept beside it (B x B floats a block, counted
+    apart from the blocks' own bytes) and solved on twice; when the fit
+    returns nothing of the fit's cache is alive, reachable from the
+    mapper or otherwise."""
+    import gc
+
+    import jax
+
+    B, blocks, epochs = 17, 6, 3
+    X, Y = _problem(n=B * blocks)
+    model, counts = _counted(lambda: KernelRidgeRegression(
+        0.3, 0.5, block_size=B, num_epochs=epochs, seed=4).fit(
+            Dataset(X), Dataset(Y)))
+    assert counts["kernel_factors_formed"] == blocks
+    assert counts["kernel_factors_reused"] == (epochs - 1) * blocks
+    assert counts["kernel_factor_bytes"] == blocks * B * B * 4
+    assert counts["kernel_cache_bytes"] == 4 * (B * blocks) ** 2
+    gc.collect()
+    assert model.alpha.shape == (B * blocks, Y.shape[1])
+    assert not [a.shape for a in jax.live_arrays()
+                if a.ndim == 2 and a.shape[1] == B]
+
+
+@pytest.mark.parametrize("lam", [0.5, 0.0], ids=["ridge", "lam_0"])
+@pytest.mark.parametrize("n", [96, 90], ids=["whole_blocks", "padded_block"])
+def test_solving_on_kept_factors_gives_the_alpha_of_factoring_every_step(
+        n, lam):
+    """A cached fit factors each K_bb + lam I once and runs `cho_solve` on
+    the kept factor in the later epochs; the same fit with
+    ``cache_kernel=False`` runs `solve(assume_a="pos")`, a factorization,
+    in every step. `solve` is `cho_factor` and `cho_solve` on the same
+    operand, so alpha agrees to float32 rounding: also where the last
+    block holds 6 rows of padding (ones on their diagonal) and at
+    lam = 0, where nothing but the kernel keeps the system definite."""
+    X, Y = _problem(n=n)
+    fit = lambda cache_kernel: KernelRidgeRegression(
+        0.3, lam, block_size=24, num_epochs=4, seed=5,
+        cache_kernel=cache_kernel).fit(Dataset(X), Dataset(Y))
+    (cached, counts), uncached = _counted(lambda: fit(True)), fit(False)
+    assert counts["kernel_factors_reused"] == 12
+    alpha = np.asarray(cached.alpha)
+    assert np.isfinite(alpha).all() and not alpha[n:].any()
+    np.testing.assert_allclose(alpha, np.asarray(uncached.alpha),
+                               rtol=2e-5, atol=2e-6)
+
+
+def test_on_a_mesh_a_kept_factor_is_replicated_and_its_block_is_not(
+        monkeypatch):
+    """Across devices a kept (n, B) block stays sharded by rows, as the
+    data is, and its (B, B) factor is on every device, as the solve is."""
+    import jax
+
+    if len(jax.devices()) < 2:
+        pytest.skip("one device: nothing to shard")
+    X, Y = _problem()
+    step, kept = kernels._krr_step, []
+
+    def recording(*args, **kwargs):
+        out = step(*args, **kwargs)
+        kept.extend(out[2:])
+        return out
+
+    monkeypatch.setattr(kernels, "_krr_step", recording)
+    KernelRidgeRegression(0.3, 0.5, block_size=24, num_epochs=2).fit(
+        Dataset(X), Dataset(Y))
+    assert len(kept) == 4
+    for Kb, Ub in kept:
+        assert Ub.shape == (24, 24) and Ub.sharding.is_fully_replicated
+        assert not Kb.sharding.is_fully_replicated
+
+
+def _primitives(jaxpr):
+    """The names of every primitive of a jaxpr, nested ones included."""
+    import jax
+
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _primitives(sub)
+
+
+@pytest.mark.parametrize("program,factorizations", [
+    ("kept", 0), ("forming_and_keeping", 1), ("forming", 1)])
+def test_a_step_on_a_kept_block_factors_nothing(program, factorizations):
+    """The step handed a kept block and its factor holds no `cholesky`
+    (two triangular solves and nothing else of the solve); a forming
+    step holds exactly one, whether it hands the factor out or not."""
+    from functools import partial
+
+    import jax
+    import jax.numpy as jnp
+
+    n, d, k, B = 48, 5, 3, 12
+    f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)
+    kept = (f32(n, B), f32(B, B)) if program == "kept" else None
+    step = partial(kernels._krr_step, gamma=0.3, block_size=B,
+                   keep_kernel=program == "forming_and_keeping")
+    args = (f32(n, d), f32(n, k), f32(n), f32(n, k), f32(n, k), f32(),
+            jax.ShapeDtypeStruct((), jnp.int32), kept)
+    names = list(_primitives(jax.make_jaxpr(step)(*args).jaxpr))
+    assert names.count("cholesky") == factorizations
+    if program != "forming":  # `solve` also carries its transpose's pair
+        assert names.count("triangular_solve") == 2
+    assert ("exp" in names) == (program != "kept")
+    outputs = jax.eval_shape(step, *args)
+    if program == "forming_and_keeping":
+        assert [o.shape for o in outputs[2]] == [(n, B), (B, B)]
+    else:
+        assert len(outputs) == 2
 
 
 @pytest.mark.parametrize("n", [96, 90], ids=["whole_blocks", "padded_block"])
@@ -135,11 +254,25 @@ def test_a_fit_visits_fixed_blocks_in_each_epoch_s_order(monkeypatch):
                        for b in block_order(11, epoch, 4)]
 
 
+@pytest.mark.parametrize("lost_after,left", [
+    # the first epoch's last two blocks are formed, factored and kept, the
+    # two before the loss formed again in epoch 1
+    (2, {"steps": 10, "kernel_blocks_formed": 4, "kernel_factors_formed": 4,
+         "kernel_blocks_reused": 6, "kernel_factors_reused": 6,
+         "kernel_factor_bytes": 2 * 24 * 24 * 4}),
+    # lost on kept blocks and factors: the second epoch's last two blocks
+    # are formed, factored and kept, the third epoch reads those two and
+    # forms the other two, which no epoch follows to read
+    (6, {"steps": 6, "kernel_blocks_formed": 4, "kernel_factors_formed": 2,
+         "kernel_blocks_reused": 2, "kernel_factors_reused": 2,
+         "kernel_factor_bytes": 2 * 24 * 24 * 4}),
+], ids=["first_epoch", "second_epoch"])
 def test_a_resumed_fit_forms_again_what_the_lost_process_had_kept(
-        tmp_path, monkeypatch):
-    """A fit that dies in its first epoch and is resumed from the
-    checkpoint ends at the uninterrupted fit's alpha: the blocks the lost
-    process had kept are formed again when a later epoch reaches them."""
+        tmp_path, monkeypatch, lost_after, left):
+    """A fit that dies and is resumed from the checkpoint ends at the
+    uninterrupted fit's alpha: the blocks and factors the lost process
+    had kept are formed again, a block's factor with the block, when a
+    later epoch reaches them, and kept where another epoch follows."""
     X, Y = _problem()
     make = lambda **kw: KernelRidgeRegression(
         0.3, 0.5, block_size=24, num_epochs=3, seed=2, **kw)
@@ -148,7 +281,7 @@ def test_a_resumed_fit_forms_again_what_the_lost_process_had_kept(
     step, calls = kernels._krr_step, []
 
     def dying(*args, **kwargs):
-        if len(calls) == 2:
+        if len(calls) == lost_after:
             raise RuntimeError("lost")
         calls.append(1)
         return step(*args, **kwargs)
@@ -163,11 +296,7 @@ def test_a_resumed_fit_forms_again_what_the_lost_process_had_kept(
         lambda: make(**checkpointed).fit(Dataset(X), Dataset(Y)))
     np.testing.assert_allclose(np.asarray(model.alpha),
                                np.asarray(want.alpha), rtol=1e-5, atol=1e-6)
-    # 10 of the 12 steps were left; the first epoch's last two blocks were
-    # formed and kept, the two before the loss formed again in epoch 1
-    assert counts["steps"] == 10
-    assert counts["kernel_blocks_formed"] == 4
-    assert counts["kernel_blocks_reused"] == 6
+    assert {name: counts[name] for name in left} == left
 
 
 def test_the_mapper_s_products_run_at_the_precision_it_declares():

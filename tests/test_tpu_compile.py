@@ -362,32 +362,35 @@ CHIP_BYTES = 16 * 2**30
 def test_the_kernel_solver_s_step_fits_the_chip_at_cifar_kernel_fit_s_size(
         one_chip, cached):
     """`_krr_step`'s two programs at the cell's shapes: the forming step
-    writes its (n, B) kernel block (1 GB) as an output and holds no
-    second array of that size beside it; the cached step takes the block
-    as an argument and forms nothing. Both are the XLA module
-    `jit__krr_step`, which `krr_ms_per_fit` and `krr_roofline` find."""
+    writes its (n, B) kernel block (1 GB) and the block's (B, B) Cholesky
+    factor (100 MB) as outputs and holds no second array of the block's
+    size beside them; the cached step takes both as arguments, forms
+    nothing and factors nothing. Both are the XLA module `jit__krr_step`,
+    which `krr_ms_per_fit` and `krr_roofline` find."""
     from keystone_tpu.nodes.learning import kernels
 
     f32 = lambda *shape: _aval(shape, jnp.float32, one_chip)
-    block_bytes = 4 * KRR_N * KRR_B
+    block_bytes, factor_bytes = 4 * KRR_N * KRR_B, 4 * KRR_B * KRR_B
     compiled = kernels._krr_step.lower(
         f32(KRR_N, KRR_D), f32(KRR_N, KRR_K), f32(KRR_N),
         f32(KRR_N, KRR_K), f32(KRR_N, KRR_K), f32(),
         _aval((), jnp.int32, one_chip),
-        f32(KRR_N, KRR_B) if cached else None,
+        (f32(KRR_N, KRR_B), f32(KRR_B, KRR_B)) if cached else None,
         gamma=KRR_GAMMA, block_size=KRR_B, keep_kernel=not cached).compile()
     memory = compiled.memory_analysis()
     hlo = compiled.as_text()
     assert memory.temp_size_in_bytes < block_bytes // 2
     if cached:
-        assert memory.argument_size_in_bytes >= block_bytes
+        assert memory.argument_size_in_bytes >= block_bytes + factor_bytes
         assert "ks.krr.kernel" not in hlo and "exponential" not in hlo
+        assert "cholesky" not in hlo
     else:
-        assert memory.output_size_in_bytes >= block_bytes
-        assert "ks.krr.kernel" in hlo
+        assert memory.output_size_in_bytes >= block_bytes + factor_bytes
+        assert "ks.krr.kernel" in hlo and "cholesky" in hlo
     assert "ks.krr.solve" in hlo and "ks.krr.update" in hlo
-    # ten kept blocks and this program beside them stay under the chip
-    assert (9 * block_bytes + memory.argument_size_in_bytes
+    # ten kept blocks with their factors and this program beside them
+    # stay under the chip
+    assert (9 * (block_bytes + factor_bytes) + memory.argument_size_in_bytes
             + memory.output_size_in_bytes + memory.temp_size_in_bytes
             < CHIP_BYTES)
     for metric in ("krr_ms_per_fit", "krr_roofline"):
