@@ -210,13 +210,6 @@ def ladder_shapes(envelope: ServingEnvelope,
 #: the ingress is priced and certified; the certificate names the
 #: boundary it was issued at.
 SERVING_INGRESS: Dict[str, Dict[str, Any]] = {
-    "VOCSIFTFisher": {
-        "stage": "MultiLabeledImageExtractor",
-        "shape": (96, 96, 3),
-        "dtype": "float32",
-        "note": "requests enter as decoded fixed-size images; the "
-                "label-extract wrapper is train-time plumbing",
-    },
     "ImageNetSiftLcsFV": {
         "stage": "_Image",
         "shape": (64, 64, 3),
@@ -234,14 +227,15 @@ SERVING_INGRESS: Dict[str, Dict[str, Any]] = {
 SERVING_SUPPRESSIONS: Dict[str, Dict[str, str]] = {
     "VOCSIFTFisher": {
         "KP903": "the worst in-envelope shape (batch 64) prices "
-                 "≈1.07s against the 1s default SLO — dominated by "
+                 "≈5.7s against the 1s default SLO at the source's "
+                 "SIFT (step 3, four scales, its stencils as banded "
+                 "products) on 64 x 64 requests — dominated by "
                  "SIFTExtractor (the dense multi-scale descriptor "
                  "grid). Fix: the serving runtime caps this "
-                 "pipeline's coalescing window at max_batch 32 "
-                 "(every shape ≤32 certifies with ≈2× margin) until "
-                 "the Pallas SIFT kernel (ROADMAP) lands; "
-                 "--certify-serving --max-batch 32 certifies clean "
-                 "today",
+                 "pipeline's coalescing window at max_batch 8 "
+                 "(every shape ≤8 certifies) until a SIFT kernel "
+                 "(ROADMAP) lands; --certify-serving --max-batch 8 "
+                 "certifies clean today",
     },
     "NewsgroupsPipeline": {
         "KP901": "the NLP front-end (Trim >> LowerCase >> Tokenizer >> "

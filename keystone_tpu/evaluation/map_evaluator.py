@@ -11,12 +11,14 @@ import numpy as np
 
 
 class MeanAveragePrecisionEvaluator:
-    """actuals: per-example list/array of true class ids (multi-label);
+    """actuals: per-example list/array of true class ids (multi-label),
+    or with ``multi_hot`` an (n, classes) array of 0/1 indicators;
     scores: per-example score vector over classes. Returns per-class AP
     array (mean is mAP)."""
 
-    def __init__(self, num_classes: int):
+    def __init__(self, num_classes: int, multi_hot: bool = False):
         self.num_classes = num_classes
+        self.multi_hot = multi_hot
 
     def evaluate(self, scores, actuals) -> np.ndarray:
         from ..data.dataset import Dataset, HostDataset
@@ -33,9 +35,15 @@ class MeanAveragePrecisionEvaluator:
         if isinstance(actuals, (Dataset, HostDataset)):
             actuals = actuals.numpy() if isinstance(actuals, Dataset) else actuals.items
 
+        if self.multi_hot:
+            indicators = np.asarray(actuals)[: len(scores)] > 0.5
         aps = np.zeros(self.num_classes)
         for c in range(self.num_classes):
-            y_true = np.array([c in set(np.atleast_1d(a).tolist()) for a in actuals])
+            if self.multi_hot:
+                y_true = indicators[:, c]
+            else:
+                y_true = np.array([c in set(np.atleast_1d(a).tolist())
+                                   for a in actuals])
             s = scores[:, c]
             order = np.argsort(-s, kind="stable")
             tp = y_true[order]

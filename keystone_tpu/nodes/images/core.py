@@ -244,6 +244,9 @@ class PixelScaler(Transformer):
     chunkable = True  # per-item host map: distributes over chunks
     precision_tolerance = "tolerant"  # uint8 decode: 8 significant bits
 
+    def abstract_apply(self, elem):
+        return jax.ShapeDtypeStruct(tuple(elem.shape), jnp.float32)
+
     def apply(self, x):
         return jnp.asarray(x, jnp.float32) / 255.0
 
@@ -274,30 +277,45 @@ class PixelScaler(Transformer):
 
 
 class GrayScaler(Transformer):
-    """NTSC grayscale (GrayScaler.scala:9)."""
+    """NTSC grayscale (GrayScaler.scala:9): (H, W, C) to (H, W, 1), or
+    with ``channel=False`` to (H, W). A set of one-channel images held
+    on a TPU wants the latter: the chip's tiled layout of (n, H, W, 1)
+    puts the images' index on the lanes, and a program that then takes
+    them a few at a time first copies the whole set."""
 
     fusable = True
     chunkable = True  # per-item host map: distributes over chunks
 
+    def __init__(self, channel: bool = True):
+        self.channel = channel
+
+    def abstract_apply(self, elem):
+        return jax.ShapeDtypeStruct(
+            tuple(elem.shape[:-1]) + ((1,) if self.channel else ()),
+            jnp.float32)
+
     def apply(self, x):
         from ...utils.images import grayscale
 
-        return grayscale(x)
+        gray = grayscale(x)
+        return gray if self.channel else gray[..., 0]
 
     def fuse(self):
         # shape-only state: one static key serves every instance, so
         # fused programs containing this stage stay structurally cached
         # (KP501 — the PR-6 silent-retrace class)
+        channel = self.channel
+
         def fn(p, x):
             if x.shape[-1] == 1:
-                return x
+                return x if channel else x[..., 0]
             # uint8 pixel decode (see PixelScaler.fuse): widening to f32
             # is the stage's contract, not a policy leak
             w = jnp.asarray([0.299, 0.587, 0.114], jnp.float32)  # keystone: ignore[KJ011]
             return jnp.sum(
-                jnp.asarray(x, jnp.float32) * w, axis=-1, keepdims=True)  # keystone: ignore[KJ011]
+                jnp.asarray(x, jnp.float32) * w, axis=-1, keepdims=channel)  # keystone: ignore[KJ011]
 
-        return (("GrayScaler",), (), fn)
+        return (("GrayScaler", channel), (), fn)
 
     def apply_batch(self, data):
         from ...data.dataset import HostDataset
@@ -306,9 +324,10 @@ class GrayScaler(Transformer):
             import numpy as np
 
             w = np.asarray([0.299, 0.587, 0.114], np.float32)
+            keep = self.channel
             return data.map(
-                lambda x: x if x.shape[-1] == 1
-                else np.sum(np.asarray(x, np.float32) * w, -1, keepdims=True)
+                lambda x: (x if keep else x[..., 0]) if x.shape[-1] == 1
+                else np.sum(np.asarray(x, np.float32) * w, -1, keepdims=keep)
             )
         return super().apply_batch(data)
 

@@ -17,40 +17,73 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ...data.dataset import Dataset, HostDataset
+from ...data.dataset import HostDataset
 from ...workflow.pipeline import Estimator, OptimizableEstimator, Transformer
-from ..learning.gmm import GaussianMixtureModel, GaussianMixtureModelEstimator, _log_gauss_posteriors
+from ..learning.gmm import GaussianMixtureModel, GaussianMixtureModelEstimator
+
+
+def _fisher_batch(X, means, variances, weights):
+    """FVs of a batch of descriptor matrices X (b, nd, d) → (b, d, 2k)
+    (each matching the reference's DenseMatrix[d, 2k] layout,
+    FisherVector.scala:33-53). Two products an image: the posteriors'
+    Mahalanobis form as [x², x] (nd, 2d) against [1/var; -2 mu/var], and
+    both moments as q' [x, x²]: the (nd, k) arrays are written and read
+    once each where four products made it twice."""
+    with jax.named_scope("ks.fisher"), \
+            jax.default_matmul_precision("highest"):
+        nd, d = X.shape[1:]
+        moments_in = jnp.concatenate([X, X * X], axis=2)  # (b, nd, 2d)
+        inv = 1.0 / variances  # (k, d)
+        # ||x-m||²_inv = x²·inv - 2x·(m·inv) + m²·inv
+        quad = (
+            moments_in @ jnp.concatenate([-2.0 * means * inv, inv], axis=1).T
+            + jnp.sum(means * means * inv, axis=1)
+        )
+        logp = jnp.log(weights) - 0.5 * (
+            quad + jnp.sum(jnp.log(variances), axis=1)
+            + d * jnp.log(2.0 * jnp.pi))
+        q = jax.nn.softmax(logp, axis=2)  # (b, nd, k)
+        sigma = jnp.sqrt(variances)  # (k, d)
+        # S0_k = sum_i q_ik ; S1_k = sum_i q_ik x_i ; S2_k = sum_i q_ik x_i²
+        S0 = jnp.sum(q, axis=1)[:, :, None]  # (b, k, 1)
+        S = jnp.einsum("bnk,bnd->bkd", q, moments_in)
+        S1, S2 = S[:, :, :d], S[:, :, d:]
+        w = weights[:, None]
+        # gradient wrt means:   (S1 - mu*S0) / (sigma * sqrt(w) * nd)
+        g_mu = (S1 - means * S0) / (sigma * jnp.sqrt(w) * nd)
+        # gradient wrt sigmas:  (S2 - 2 mu S1 + (mu²-sigma²) S0) / (sigma² sqrt(2w) nd)
+        g_sig = (
+            S2 - 2.0 * means * S1 + (means**2 - variances) * S0
+        ) / (variances * jnp.sqrt(2.0 * w) * nd)
+        return jnp.concatenate(
+            [g_mu.transpose(0, 2, 1), g_sig.transpose(0, 2, 1)], axis=2)
 
 
 @jax.jit
 def _fisher_vector(X, means, variances, weights):
-    """FV of one descriptor matrix X (nd, d) → (d, 2k) (matching the
-    reference's DenseMatrix[d, 2k] layout, FisherVector.scala:33-53)."""
-    with jax.default_matmul_precision("highest"):
-        nd = X.shape[0]
-        q = jnp.exp(_log_gauss_posteriors(X, means, variances, weights))  # (nd, k)
-        sigma = jnp.sqrt(variances)  # (k, d)
-        # normalized deviations per component: (nd, k, d) contracted via GEMMs
-        # S0_k = sum_i q_ik ; S1_k = sum_i q_ik x_i ; S2_k = sum_i q_ik x_i²
-        S0 = jnp.sum(q, axis=0)  # (k,)
-        S1 = q.T @ X  # (k, d)
-        S2 = q.T @ (X * X)  # (k, d)
-        w = weights[:, None]
-        # gradient wrt means:   (S1 - mu*S0) / (sigma * sqrt(w) * nd)
-        g_mu = (S1 - means * S0[:, None]) / (sigma * jnp.sqrt(w) * nd)
-        # gradient wrt sigmas:  (S2 - 2 mu S1 + (mu²-sigma²) S0) / (sigma² sqrt(2w) nd)
-        g_sig = (
-            S2 - 2.0 * means * S1 + (means**2 - variances) * S0[:, None]
-        ) / (variances * jnp.sqrt(2.0 * w) * nd)
-        return jnp.concatenate([g_mu.T, g_sig.T], axis=1)  # (d, 2k)
+    """FV of one descriptor matrix X (nd, d) → (d, 2k)."""
+    return _fisher_batch(X[None], means, variances, weights)[0]
 
 
 class FisherVector(Transformer):
     """Descriptor matrix (nd, d) → FV matrix (d, 2k)
-    (FisherVector.scala:14-62)."""
+    (FisherVector.scala:14-62). Traceable: in a fused program a
+    microbatch of descriptor matrices is encoded where it was made."""
+
+    fusable = True
+    chunkable = True  # pure per-item fn: distributes over chunks
+    precision_tolerance = "exact"  # posteriors and moments: f32 at highest
 
     def __init__(self, gmm: GaussianMixtureModel):
         self.gmm = gmm
+
+    def abstract_apply(self, elem):
+        from ...analysis.specs import SpecMismatchError, shape_struct
+
+        if getattr(elem, "ndim", 0) != 2:
+            raise SpecMismatchError(
+                "FisherVector input element must be a 2-D descriptor matrix")
+        return shape_struct((int(elem.shape[-1]), 2 * self.gmm.k), np.float32)
 
     def apply(self, x):
         return _fisher_vector(
@@ -60,16 +93,22 @@ class FisherVector(Transformer):
             self.gmm.weights,
         )
 
+    def fuse(self):
+        g = self.gmm
+        return (("FisherVector",), (g.means, g.variances, g.weights),
+                lambda p, xb: _fisher_batch(xb, *p))
+
+    def count_rows(self, elem, rows: int):
+        from ...telemetry import counter
+
+        counter("fisher.images").inc(rows)
+
     def apply_batch(self, data):
         if isinstance(data, HostDataset):
             return HostDataset([np.asarray(self.apply(x)) for x in data.items])
-        g = self.gmm
-        return data.map_batches(
-            lambda X: jax.vmap(
-                lambda xi: _fisher_vector(xi, g.means, g.variances, g.weights)
-            )(X),
-            jitted=False,
-        )
+        from ..util.fusion import FusedBatchTransformer
+
+        return FusedBatchTransformer([self]).apply_batch(data)
 
 
 def _fv_fit_spec(k: int, label: str):
@@ -111,6 +150,8 @@ class ScalaGMMFisherVectorEstimator(Estimator):
     """Fit a GMM on descriptor samples, return the FV encoder
     (FisherVector.scala:69-84)."""
 
+    fusable_fit = True  # always fits a traceable FisherVector
+
     def __init__(self, k: int, num_iters: int = 30, seed: int = 0):
         self.k = k
         self.num_iters = num_iters
@@ -137,6 +178,8 @@ class GMMFisherVectorEstimator(OptimizableEstimator):
     """Optimizable FV estimator (FisherVector.scala:86-94). Both the
     reference's scala and enceval routes map to the same XLA kernel, so
     the choice is degenerate — kept for API parity."""
+
+    fusable_fit = True  # either route fits a traceable FisherVector
 
     def __init__(self, k: int, num_iters: int = 30, seed: int = 0):
         self.k = k

@@ -10,10 +10,13 @@ threshold 0.005 zeroing (:63,140-147), descriptors transposed and
 ×512 short-scaled with a 255 clamp (:252-259).
 
 The vl_dsift fast path is convolutional, so it maps directly onto XLA
-(one jitted program, vmapped over the batch):
+(one traced function of a batch of images, all scales in one program;
+the maps keep the image's (rows, columns) as their minor axes and the
+eight orientations lead):
 
-  1. Gaussian-smooth per scale: separable depthwise conv, support
-     ceil(4σ), edge-replicate padding (vl_imsmooth semantics).
+  1. Gaussian-smooth per scale: separable, support ceil(4σ),
+     edge-replicate padding (vl_imsmooth semantics); kernel and padding
+     are one banded matrix an axis, and the convolution two products.
   2. Gradients: central differences inside, one-sided at borders
      (dsift.c's update pass) — exactly `jnp.gradient`.
   3. Soft-assign magnitude into 8 orientation channels (linear
@@ -21,9 +24,11 @@ The vl_dsift fast path is convolutional, so it maps directly onto XLA
   4. Spatial binning = per-channel TRIANGULAR convolution of unit
      integral and half-width binSize, edge-replicate padding
      (vl_imconvcoltri_f — bilinear bin interpolation under a flat
-     window), NOT a box filter.
-  5. Descriptors are strided gathers of the aggregated maps at bin
-     centers frame + bin·binSize; each spatial bin is reweighted by
+     window), NOT a box filter; banded products as in step 1.
+  5. Descriptors are the aggregated maps at bin centers
+     frame + bin·binSize (frames lie `step` apart): the binning products
+     of step 4 are taken at those rows and columns alone (`_bin_rows`),
+     so the choice costs nothing; each spatial bin is reweighted by
      the mean of a Gaussian window (σ = 1.5·binSize) over its support,
      ×binSize (flat-window Gaussian reweighting).
   6. L2 normalize (+VL_EPSILON_F) → clamp 0.2 → renormalize; zero
@@ -39,8 +44,9 @@ ordered column-outer / row-inner. Golden-tested against the scalar-loop
 oracle `tests/descriptor_reference_impls.vl_dsift_multiscale` (which
 implements the literal transposed pipeline) on a real image.
 
-Descriptor counts per (image size, params) are static, so the whole
-extractor is one jitted program and vmaps over the batch.
+Descriptor counts per (image size, params) are static
+(`SIFTExtractor.num_descriptors`), so the whole extractor is one jitted
+program of a batch.
 """
 
 from __future__ import annotations
@@ -48,6 +54,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from ...data.dataset import HostDataset
 from ...workflow.pipeline import Transformer
@@ -84,64 +91,155 @@ def _bin_window_mean(bin_size: int, bin_index: int) -> float:
     return float(np.mean(np.exp(-0.5 * ((xs + delta) / sigma) ** 2))) * bin_size
 
 
-def _sep_conv_edge(maps, taps):
-    """Separable depthwise convolution with EDGE-REPLICATE padding
-    (VL_PAD_BY_CONTINUITY) over the two leading axes of (H, W, C)."""
-    from ...utils.images import depthwise_conv2d
+def _band_matrix(n: int, taps) -> np.ndarray:
+    """(n, n): row i holds ``taps`` centred on column i, the taps that
+    fall off either end added to the end's column: correlation with
+    symmetric taps under EDGE-REPLICATE padding (VL_PAD_BY_CONTINUITY)
+    as one matrix."""
+    r = (len(taps) - 1) // 2
+    cols = np.clip(np.arange(n)[:, None] + np.arange(len(taps)) - r, 0, n - 1)
+    M = np.zeros((n, n), np.float64)
+    np.add.at(M, (np.repeat(np.arange(n), len(taps)), cols.ravel()),
+              np.tile(np.asarray(taps, np.float64), n))
+    return M.astype(np.float32)
 
-    return depthwise_conv2d(maps, taps, taps, padding="edge")
+
+def _sep_conv_edge(x, taps):
+    """Separable convolution of the two last axes (rows, then columns)
+    as two products with banded matrices, in float32 at `highest`: on a
+    TPU a stencil of 7 to 19 taps as shifted sums keeps the vector unit
+    shuffling lanes (8.4 ms an image at VOC's size for the four scales,
+    my chip run, PR 40), and the matrix unit does the same sums as a
+    product with a matrix that is mostly zeros many times faster."""
+    h, w = x.shape[-2:]
+    rows = jnp.einsum("uh,...hw->...uw", _band_matrix(h, taps), x,
+                      precision=lax.Precision.HIGHEST)
+    return jnp.einsum("...uw,vw->...uv", rows, _band_matrix(w, taps),
+                      precision=lax.Precision.HIGHEST)
 
 
-def _sift_one_scale(gray, bin_size: int, step: int, off: int):
-    """All descriptors of one scale: (num_desc, 128) quantized floats."""
+def frame_grid(h: int, w: int, bin_size: int, step: int, off: int):
+    """(rows, columns) of one scale's frame grid: frames span
+    [off, dim-1] with footprint 3*binSize+1."""
+    span = bin_size * (GRID - 1) + 1
+    n_r = max(((h - 1) - span + 1 - off) // step + 1, 0)
+    n_c = max(((w - 1) - span + 1 - off) // step + 1, 0)
+    return n_r, n_c
+
+
+def _orientation_maps(gray, bin_size: int):
+    """Steps 1 to 3 for one scale of a batch of images (b, h, w): the
+    eight orientation maps before spatial binning, (b, 8, h, w)."""
     sigma = bin_size / MAGNIF
-    sm = _sep_conv_edge(gray[:, :, None], _gaussian_taps(sigma))[:, :, 0]
-    h, w = sm.shape
+    sm = _sep_conv_edge(gray, _gaussian_taps(sigma))
     # gradients: central interior, one-sided borders (vl semantics ==
     # jnp.gradient); dy is d/drow, dx is d/dcol
-    dy = jnp.gradient(sm, axis=0)
-    dx = jnp.gradient(sm, axis=1)
+    dy = jnp.gradient(sm, axis=1)
+    dx = jnp.gradient(sm, axis=2)
     mag = jnp.sqrt(dx * dx + dy * dy)
     ang = jnp.arctan2(dy, dx)
 
-    # soft orientation binning: linear interp between adjacent bins
+    # soft orientation binning: linear interp between adjacent bins.
+    # The orientation axis leads, so every map keeps (h, w) minor.
     t = jnp.mod(ang / (2.0 * jnp.pi) * NUM_ORIENTATIONS, NUM_ORIENTATIONS)
     lo = jnp.floor(t)
     frac = t - lo
     lo = lo.astype(jnp.int32) % NUM_ORIENTATIONS
     hi = (lo + 1) % NUM_ORIENTATIONS
-    maps = (
-        jax.nn.one_hot(lo, NUM_ORIENTATIONS) * (mag * (1.0 - frac))[..., None]
-        + jax.nn.one_hot(hi, NUM_ORIENTATIONS) * (mag * frac)[..., None]
-    )  # (h, w, 8)
+    w_lo, w_hi = mag * (1.0 - frac), mag * frac
+    return jnp.stack(
+        [jnp.where(lo == o, w_lo, 0.0) + jnp.where(hi == o, w_hi, 0.0)
+         for o in range(NUM_ORIENTATIONS)], axis=1)  # (b, 8, h, w)
 
-    # flat-window spatial binning: triangular conv per channel
-    agg = _sep_conv_edge(maps, _triangular_taps(bin_size))
 
-    # frames span [off, dim-1] with footprint 3·binSize+1
-    span = bin_size * (GRID - 1) + 1
-    n_r = max(((h - 1) - span + 1 - off) // step + 1, 0)
-    n_c = max(((w - 1) - span + 1 - off) // step + 1, 0)
-    rows = off + jnp.arange(n_r) * step
-    cols = off + jnp.arange(n_c) * step
-    bin_off = jnp.arange(GRID) * bin_size
-    rr = rows[:, None] + bin_off[None, :]  # (n_r, GRID) bin-center rows
-    cc = cols[:, None] + bin_off[None, :]
-    # gather, frames column-outer / row-inner (the reference's frame
-    # order): desc (n_c, n_r, GRID_row, GRID_col, 8)
-    desc = agg[rr[None, :, :, None, None], cc[:, None, None, :, None],
-               jnp.arange(NUM_ORIENTATIONS)[None, None, None, None, :]]
-    wmean = jnp.asarray([_bin_window_mean(bin_size, b) for b in range(GRID)])
-    desc = desc * wmean[None, None, :, None, None] * wmean[None, None, None, :, None]
-    desc = desc.reshape(n_c * n_r, GRID * GRID * NUM_ORIENTATIONS)
+def _aggregated_maps(gray, bin_size: int):
+    """Steps 1 to 4: the orientation maps after the flat-window spatial
+    binning (a triangular conv per channel), (b, 8, h, w)."""
+    return _sep_conv_edge(_orientation_maps(gray, bin_size),
+                          _triangular_taps(bin_size))
 
-    # vl normalization: L2+eps -> clamp 0.2 -> L2+eps; contrast zeroing
-    norm = jnp.linalg.norm(desc, axis=1, keepdims=True) + VL_EPSILON_F
-    desc = desc / norm
-    desc = jnp.minimum(desc, 0.2)
-    desc = desc / (jnp.linalg.norm(desc, axis=1, keepdims=True) + VL_EPSILON_F)
+
+def _bin_rows(n: int, count: int, bin_size: int, step: int, off: int):
+    """(4 * count, n): for each of the four bins along an axis of length
+    ``n``, the rows of the triangular band matrix at the bin's centres of
+    the ``count`` frames (``step`` apart from ``off``), each scaled by
+    the bin's window mean: binning, the strided choice of the frames'
+    bin centres and the flat window's reweighting as ONE matrix."""
+    band = _band_matrix(n, _triangular_taps(bin_size))
+    centres = off + step * np.arange(count)
+    return np.concatenate(
+        [_bin_window_mean(bin_size, i) * band[centres + i * bin_size]
+         for i in range(GRID)], axis=0).astype(np.float32)
+
+
+def _sift_one_scale(gray, bin_size: int, step: int, off: int):
+    """All raw descriptors of one scale of a batch of images (b, h, w):
+    (b, num_desc, 128), before normalization."""
+    maps = _orientation_maps(gray, bin_size)
+    b, _, h, w = maps.shape
+    n_r, n_c = frame_grid(h, w, bin_size, step, off)
+    if n_r == 0 or n_c == 0:
+        return jnp.zeros((b, 0, GRID * GRID * NUM_ORIENTATIONS), maps.dtype)
+    # A descriptor's bin (i, j) is the binned map at the frame's corner
+    # plus (i, j) * binSize, and the frames lie ``step`` apart: the
+    # products below bin the maps AT those places and nowhere else, so
+    # no map of all places is made and nothing is gathered or sliced out
+    # of one (sixteen slices of stride 3 along the lanes were 10 of a
+    # full pass's 12.4 ms an image on a v5e, a general gather of the
+    # same 9.45 million elements more: my chip runs, PR 40).
+    cols = jnp.einsum("bohw,vw->bohv", maps,
+                      _bin_rows(w, n_c, bin_size, step, off),
+                      precision=lax.Precision.HIGHEST)
+    bins = jnp.einsum("uh,bohv->bouv", _bin_rows(h, n_r, bin_size, step, off),
+                      cols, precision=lax.Precision.HIGHEST)
+    # (b, 8, (i, r), (j, c)) -> frames column-outer / row-inner (the
+    # reference's frame order), features [row-bin, col-bin, orientation]
+    desc = bins.reshape(b, NUM_ORIENTATIONS, GRID, n_r, GRID, n_c)
+    desc = desc.transpose(0, 5, 3, 2, 4, 1)
+    return desc.reshape(b, n_c * n_r, GRID * GRID * NUM_ORIENTATIONS)
+
+
+def _sift_some_frames(gray, bin_size: int, step: int, off: int, frames):
+    """The raw descriptors of the frames numbered ``frames`` (a static
+    sorted array of indices into the scale's column-outer, row-inner
+    frame order) and of no others: (b, len(frames), 128). A few hundred
+    frames of an image's tens of thousands: their bins are gathered
+    from the aggregated maps and no other descriptor is made."""
+    agg = _aggregated_maps(gray, bin_size)
+    h, w = agg.shape[2:]
+    n_r, _ = frame_grid(h, w, bin_size, step, off)
+    frames = np.asarray(frames)
+    bin_off = np.arange(GRID) * bin_size
+    rows = off + (frames % n_r)[:, None] * step + bin_off  # (m, 4)
+    cols = off + (frames // n_r)[:, None] * step + bin_off
+    wmean = np.asarray([_bin_window_mean(bin_size, i) for i in range(GRID)],
+                       np.float32)
+    # (b, 8, m, 4, 4): orientation o, frame, row-bin i, col-bin j
+    desc = agg[:, :, rows[:, :, None], cols[:, None, :]]
+    desc = desc * (wmean[:, None] * wmean[None, :])
+    return desc.transpose(0, 2, 3, 4, 1).reshape(
+        agg.shape[0], len(frames), GRID * GRID * NUM_ORIENTATIONS)
+
+
+def _row_sums(x):
+    """Every row's sum, in every one of the row's places: a product with
+    a matrix of ones, in float32 at `highest`. On a TPU a sum along the
+    128 lanes is seven rounds of lane shuffles a vector register and the
+    result has to be spread over the lanes again; the matrix unit gives
+    both at once."""
+    d = x.shape[-1]
+    return jnp.matmul(x, np.ones((d, d), np.float32),
+                      precision=lax.Precision.HIGHEST)
+
+
+def _normalize_quantize(desc):
+    """vl normalization of raw descriptors (..., 128): L2+eps -> clamp
+    0.2 -> L2+eps; contrast zeroing; the JNI short quantization
+    floor(512·v) clamped to 255."""
+    norm = jnp.sqrt(_row_sums(desc * desc)) + VL_EPSILON_F
+    desc = jnp.minimum(desc / norm, 0.2)
+    desc = desc / (jnp.sqrt(_row_sums(desc * desc)) + VL_EPSILON_F)
     desc = jnp.where(norm < CONTRAST_THRESHOLD, 0.0, desc)
-    # JNI short quantization: floor(512·v) clamped to 255
     return jnp.minimum(jnp.floor(512.0 * desc), 255.0)
 
 
@@ -158,7 +256,17 @@ class SIFTExtractor(SIFTExtractorInterface):
     Defaults mirror SIFTExtractor.scala:17 (step 3, bin 4, 4 scales,
     scale_step 1); the reference's VLFeatSuite/enceval configuration uses
     scale_step=0 (VLFeat.cxx:77-79 note).
+
+    One traced function serves a single image, a host bucket and a fused
+    stage: a batch (b, H, W) through all scales in one program. On a
+    device `Dataset` the batch path is a fused program of its own, so a
+    set of images goes through a microbatch at a time and never as one
+    vmap over the whole set.
     """
+
+    fusable = True
+    chunkable = True  # per-item host map: distributes over chunks
+    precision_tolerance = "exact"  # quantized descriptors: float32 stencils
 
     def __init__(self, step: int = 3, bin_size: int = 4, num_scales: int = 4,
                  scale_step: int = 1):
@@ -167,43 +275,89 @@ class SIFTExtractor(SIFTExtractorInterface):
         self.num_scales = num_scales
         self.scale_step = scale_step
 
-    def _fn(self):
-        step0, b0, S = self.step, self.bin_size, self.num_scales
-        scale_step = self.scale_step
+    def _scales(self):
+        """(bin size, step, offset) of every scale."""
+        S = self.num_scales
+        # the offset is clamped like vl_dsift clamps its bounds to the
+        # image: for num_scales >= 5 the raw offset goes negative
+        return [(self.bin_size + 2 * s, self.step + s * self.scale_step,
+                 max((1 + 2 * S) - 3 * s, 0)) for s in range(S)]
 
-        @jax.jit
-        def fn(gray):
-            if gray.ndim == 3:
-                gray = gray[:, :, 0]
-            parts = []
-            for s in range(S):
-                bin_size = b0 + 2 * s
-                step = step0 + s * scale_step
-                # clamp like vl_dsift clamps its bounds to the image:
-                # for num_scales >= 5 the raw offset goes negative, which
-                # would WRAP gather indices to the opposite image edge
-                off = max((1 + 2 * S) - 3 * s, 0)
-                parts.append(_sift_one_scale(gray, bin_size, step, off))
-            return jnp.concatenate(parts, axis=0)
+    def num_descriptors(self, h: int, w: int) -> int:
+        """Descriptors of an (h, w) image, over all scales."""
+        return sum(int(np.prod(frame_grid(h, w, b, st, off)))
+                   for b, st, off in self._scales())
 
+    def abstract_apply(self, elem):
+        from ...analysis.specs import SpecMismatchError, shape_struct
+
+        shape = tuple(elem.shape)
+        if len(shape) == 3 and shape[-1] == 1:
+            shape = shape[:2]
+        if len(shape) != 2:
+            raise SpecMismatchError(
+                "SIFT input element must be a grayscale (H, W) or "
+                f"(H, W, 1) image, got {tuple(elem.shape)}")
+        return shape_struct(
+            (self.num_descriptors(*shape), GRID * GRID * NUM_ORIENTATIONS),
+            np.float32)
+
+    def _batch(self, gray):
+        """(b, H, W) or (b, H, W, 1) → (b, num_descriptors, 128)."""
+        if gray.ndim == 4:
+            gray = gray[..., 0]
+        gray = gray.astype(jnp.float32)
+        with jax.named_scope("ks.sift"):
+            # the scales side by side first, so that the normalization is
+            # one pass over a microbatch's descriptors and not four
+            return _normalize_quantize(jnp.concatenate(
+                [_sift_one_scale(gray, b, st, off)
+                 for b, st, off in self._scales()], axis=1))
+
+    def _batch_rows(self, gray, rows):
+        """The descriptors numbered ``rows`` (static, sorted) of every
+        image of the batch, as `_batch(gray)[:, rows]` has them, without
+        making the others: what a sampler right behind this stage asks
+        for."""
+        if gray.ndim == 4:
+            gray = gray[..., 0]
+        gray = gray.astype(jnp.float32)
+        h, w = gray.shape[1:]
+        rows = np.asarray(rows)
+        parts, start = [], 0
+        with jax.named_scope("ks.sift"):
+            for b, st, off in self._scales():
+                count = int(np.prod(frame_grid(h, w, b, st, off)))
+                mine = rows[(rows >= start) & (rows < start + count)] - start
+                if len(mine):
+                    parts.append(_sift_some_frames(gray, b, st, off, mine))
+                start += count
+            return _normalize_quantize(jnp.concatenate(parts, axis=1))
+
+    def batch_fn(self):
+        return self._batch
+
+    def fuse(self):
+        return (("SIFT", self.step, self.bin_size, self.num_scales,
+                 self.scale_step), (), lambda p, xb: self._batch(xb))
+
+    def count_rows(self, elem, rows: int):
+        """`sift.images`, `sift.descriptors`: what one dispatch of a
+        program holding this stage extracts, from the shapes."""
+        from ...telemetry import counter
+
+        counter("sift.images").inc(rows)
+        counter("sift.descriptors").inc(
+            rows * self.abstract_apply(elem).shape[0])
+
+    def _jitted_batch(self):
+        fn = self.__dict__.get("_jitted")
+        if fn is None:
+            fn = self.__dict__["_jitted"] = jax.jit(self._batch)
         return fn
 
     def apply(self, image):
-        fn = self.__dict__.get("_jitted")
-        if fn is None:
-            fn = self._fn()
-            self.__dict__["_jitted"] = fn
-        return fn(jnp.asarray(image, jnp.float32))
-
-    chunkable = True  # per-item host map: distributes over chunks
-
-    def _batch_fn(self):
-        fn = self.__dict__.get("_jitted_batch")
-        if fn is None:
-            single = self._fn()
-            fn = jax.jit(jax.vmap(single))
-            self.__dict__["_jitted_batch"] = fn
-        return fn
+        return self._jitted_batch()(jnp.asarray(image, jnp.float32)[None])[0]
 
     def apply_batch(self, data):
         if isinstance(data, HostDataset):
@@ -211,13 +365,15 @@ class SIFTExtractor(SIFTExtractorInterface):
             from ...utils import batching
 
             return HostDataset(
-                batching.map_host_batched(data.items, self._batch_fn())
-            )
-        return data.map_batches(self._batch_fn(), jitted=False)
+                batching.map_host_batched(data.items, self._jitted_batch()))
+        from ..util.fusion import FusedBatchTransformer
+
+        return FusedBatchTransformer([self]).apply_batch(data)
 
     def apply_batch_stream(self, data):
         # overlap engine: double-buffered dispatch, chunks stream to the
         # consumer as they drain (see utils/batching.py)
         from ...utils import batching
 
-        return batching.map_host_batched_stream(data.items, self._batch_fn())
+        return batching.map_host_batched_stream(
+            data.items, self._jitted_batch())

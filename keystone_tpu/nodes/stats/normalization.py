@@ -87,21 +87,76 @@ class Sampler(Transformer):
         return Dataset(picked, count=self.size, mesh=data.mesh)
 
 
+def sample_rows(n: int, num: int, seed: int) -> np.ndarray:
+    """The sorted rows a `ColumnSampler(num, seed)` keeps of an
+    ``n``-row matrix: a seeded choice without replacement, the same for
+    every matrix of that height. A handful of integers made on the host;
+    the matrices themselves never leave the device."""
+    idx = np.random.default_rng(seed).choice(n, num, replace=False)
+    idx.sort()
+    return idx.astype(np.int32)
+
+
 class ColumnSampler(Transformer):
     """Sample ≤ num_cols columns from each item's (cols × dim) matrix —
-    used to subsample descriptors per image (Sampling.scala:12-25)."""
+    used to subsample descriptors per image (Sampling.scala:12-25).
 
+    Traceable: inside a fused program the kept rows are taken on the
+    device from the descriptors the program has just made, so what
+    leaves the program is the sample and never the descriptor matrix
+    (`sampler.rows_kept`; `sampler.host_bytes` counts what the host
+    path pulls across)."""
+
+    fusable = True
     chunkable = True  # pure per-item fn: distributes over chunks (KP302)
+    precision_tolerance = "tolerant"  # a choice of rows: values untouched
 
     def __init__(self, num_cols: int, seed: int = 0):
         self.num_cols = num_cols
         self.seed = seed
 
+    def abstract_apply(self, elem):
+        from ...analysis.specs import shape_struct
+
+        shape = tuple(elem.shape)
+        return shape_struct((min(shape[0], self.num_cols),) + shape[1:],
+                            elem.dtype)
+
     def apply(self, x):
-        x = np.asarray(x)
         n = x.shape[0]
         if n <= self.num_cols:
             return x
-        idx = np.random.default_rng(self.seed).choice(n, self.num_cols, replace=False)
-        idx.sort()
+        idx = sample_rows(n, self.num_cols, self.seed)
+        if isinstance(x, jax.Array):
+            return jnp.take(x, idx, axis=0)
+        from ...telemetry import counter
+
+        x = np.asarray(x)
+        counter("sampler.host_bytes").inc(x.nbytes)
         return x[idx]
+
+    def apply_batch(self, data):
+        if isinstance(data, Dataset):
+            # the structurally cached program, not a jit of this instance
+            from ..util.fusion import FusedBatchTransformer
+
+            return FusedBatchTransformer([self]).apply_batch(data)
+        return super().apply_batch(data)
+
+    def fuse(self):
+        num, seed = self.num_cols, self.seed
+
+        def fn(p, xb):
+            n = xb.shape[1]
+            if n <= num:
+                return xb
+            with jax.named_scope("ks.sift.sample"):
+                return jnp.take(xb, sample_rows(n, num, seed), axis=1)
+
+        return (("ColumnSampler", num, seed), (), fn)
+
+    def count_rows(self, elem, rows: int):
+        from ...telemetry import counter
+
+        counter("sampler.rows_kept").inc(
+            rows * min(elem.shape[0], self.num_cols))

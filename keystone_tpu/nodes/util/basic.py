@@ -203,6 +203,9 @@ class Cacher(Transformer):
     def label(self) -> str:
         return f"Cacher[{self.name}]"
 
+    def abstract_apply(self, elem):
+        return elem
+
     def apply(self, x):
         return x
 

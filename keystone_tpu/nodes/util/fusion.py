@@ -143,16 +143,85 @@ class _ConvRectifyPoolStage(Transformer):
         )
 
 
+class _SampledSIFTStage(Transformer):
+    """Peephole-fused SIFTExtractor >> ColumnSampler: the rows the
+    sampler keeps are known before a descriptor is made (a seeded choice
+    by the matrix's height), so only those frames' bins are gathered
+    from the aggregated maps, normalized and quantized: a sampling pass
+    over a set of images costs the stencils and never holds, transposes
+    or normalizes the 73,866 descriptors an image it would throw away.
+    The values are the unfused pair's."""
+
+    fusable = True
+    chunkable = True
+    precision_tolerance = "exact"  # SIFT's
+
+    def __init__(self, sift, sampler):
+        self.sift = sift
+        self.sampler = sampler
+
+    @property
+    def label(self) -> str:
+        return f"{self.sift.label}>>{self.sampler.label}"
+
+    def abstract_apply(self, elem):
+        return self.sampler.abstract_apply(self.sift.abstract_apply(elem))
+
+    def apply(self, x):
+        return self.sampler.apply(self.sift.apply(x))
+
+    def fuse(self):
+        from ..stats.normalization import sample_rows
+
+        sift, num, seed = self.sift, self.sampler.num_cols, self.sampler.seed
+
+        def fn(p, xb):
+            h, w = xb.shape[1:3]
+            nd = sift.num_descriptors(h, w)
+            if nd <= num:
+                return sift._batch(xb)
+            with jax.named_scope("ks.sift.sample"):
+                return sift._batch_rows(xb, sample_rows(nd, num, seed))
+
+        return (("SampledSIFT", sift.fuse()[0], num, seed), (), fn)
+
+    def count_rows(self, elem, rows: int):
+        from ...telemetry import counter
+
+        kept = self.abstract_apply(elem).shape[0]
+        counter("sift.images").inc(rows)
+        counter("sift.descriptors").inc(rows * kept)
+        counter("sampler.rows_kept").inc(rows * kept)
+
+
 def _peephole(stages):
     """Merge adjacent (Convolver?, SymmetricRectifier, Pooler[sum])
     stages so the conv output and the channel-doubled rectified tensor
-    never materialize (see ops/)."""
+    never materialize (see ops/); move a ColumnSampler in front of the
+    PCA projection it follows (a choice of rows commutes with a map of
+    each row) and merge it into the SIFTExtractor it then follows
+    (`_SampledSIFTStage`)."""
     from ..images.core import Convolver, Pooler, SymmetricRectifier
+    from ..images.sift import SIFTExtractor
+    from ..learning.pca import PCATransformer
+    from ..stats.normalization import ColumnSampler
 
+    stages = list(stages)
+    for i in range(len(stages) - 1):
+        if isinstance(stages[i], PCATransformer) \
+                and type(stages[i + 1]) is ColumnSampler:
+            stages[i], stages[i + 1] = stages[i + 1], stages[i]
     out, i = [], 0
     while i < len(stages):
         s = stages[i]
         if (
+            type(s) is SIFTExtractor
+            and i + 1 < len(stages)
+            and type(stages[i + 1]) is ColumnSampler
+        ):
+            out.append(_SampledSIFTStage(s, stages[i + 1]))
+            i += 2
+        elif (
             isinstance(s, Convolver)
             and i + 2 < len(stages)
             and isinstance(stages[i + 1], SymmetricRectifier)
@@ -230,6 +299,53 @@ def _stage_fuse(stage: Transformer):
     # program's metadata and so in a device trace. Metadata only: the
     # jaxpr, the compiled code and the program cache keys are as without
     return key, params, jax.named_scope(scope_name(stage.label))(fn)
+
+
+#: a shard's input of this many bytes or more goes through the chunk loop
+#: where it lies (`_overlapped_chunks`): below it a copy is cheap
+COPY_FREE_BYTES = 1 << 30
+#: rows of a slab: a TPU lays a large array out with its rows on the 128
+#: lanes where that pads least, and cuts of whole lane tiles are cheap
+SLAB_ROWS = 128
+
+
+def _overlapped_chunks(chunk_fn, params, xs, ms, chunk: int):
+    """The chunk loop over rows that are no whole number of chunks, or
+    too many bytes to be copied: every chunk is cut from the rows where
+    they lie and the last one from their end, so it overlaps the one
+    before (those rows are made twice, the same) and the input is
+    neither padded nor copied: a padded copy of a shard's input is the
+    whole of it again, 3.8 GB where the input is 5,011 cached images.
+
+    Where a chunk is a fraction of `SLAB_ROWS`, the rows go through in
+    slabs of `SLAB_ROWS` and a slab in chunks: the v5e compiler, asked
+    for eight rows at a time of an array it keeps with its rows on the
+    lanes, otherwise first copies the whole array into a layout of its
+    liking."""
+    local_n = xs.shape[0]
+    if chunk < SLAB_ROWS <= local_n and SLAB_ROWS % chunk == 0:
+        inner = SLAB_ROWS // chunk
+
+        def slab_fn(ps, xb, mb):
+            yb = lax.map(
+                lambda xm: chunk_fn(ps, xm[0], xm[1]),
+                (xb.reshape((inner, chunk) + xb.shape[1:]),
+                 mb.reshape((inner, chunk))))
+            return yb.reshape((SLAB_ROWS,) + yb.shape[2:])
+
+        return _overlapped_chunks(slab_fn, params, xs, ms, SLAB_ROWS)
+    out = jax.eval_shape(lambda x, m: chunk_fn(params, x, m),
+                         xs[:chunk], ms[:chunk])
+
+    def body(i, ys):
+        start = jnp.minimum(i * chunk, local_n - chunk)
+        yb = chunk_fn(params, lax.dynamic_slice_in_dim(xs, start, chunk, 0),
+                      lax.dynamic_slice_in_dim(ms, start, chunk, 0))
+        return lax.dynamic_update_slice_in_dim(ys, yb, start, 0)
+
+    return lax.fori_loop(
+        0, -(-local_n // chunk), body,
+        jnp.zeros((local_n,) + out.shape[1:], out.dtype))
 
 
 # (structure key) -> jitted program. Programs take (flat_params, xs) so
@@ -340,12 +456,66 @@ class _GatherConcatStage(Transformer):
         return (("GatherConcat",) + statics, params, fn, _MASK_AWARE)
 
 
+#: the most rows a microbatch takes however small a row is: what every
+#: fused program ran at before the rows' bytes were looked at
+MAX_MICROBATCH = 2048
+
+# (chain structure, parameter shapes, element, budget) -> rows a microbatch
+_MICROBATCH_CACHE: dict = {}
+
+
+def _made_bytes(jaxpr, out: list) -> list:
+    """Bytes of every value the equations of ``jaxpr`` make, in order,
+    those of the programs inside it too (a Pallas kernel's values live in
+    VMEM and are left out)."""
+    for eqn in jaxpr.eqns:
+        for v in eqn.outvars:
+            aval = v.aval
+            size = getattr(aval, "size", None)
+            out.append(int(size) * aval.dtype.itemsize if size is not None
+                       else 0)
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    _made_bytes(inner, out)
+    return out
+
+
+def chain_row_bytes(fns, params, elem_shape, dtype) -> int:
+    """The bytes of the largest value one more row makes anywhere in the
+    chain ``fns``: the chain is traced abstractly for one row and for
+    two, and a value's share of a row is what it grew by (a value made
+    of the parameters alone does not grow). Nothing runs and nothing is
+    compiled."""
+    def made(rows):
+        def chain(ps, xb, mb):
+            for f, p in zip(fns, ps):
+                xb = f(p, xb, mb)
+            return xb
+
+        closed = jax.make_jaxpr(chain)(
+            params, jax.ShapeDtypeStruct((rows,) + tuple(elem_shape), dtype),
+            jax.ShapeDtypeStruct((rows,), jnp.bool_))
+        return _made_bytes(closed.jaxpr, [])
+
+    one, two = made(1), made(2)
+    if len(one) != len(two):  # the trace depends on the rows: be careful
+        return max(one, default=0)
+    return max((b - a for a, b in zip(one, two)), default=0)
+
+
 class FusedBatchTransformer(Transformer):
     """Compose device transformer stages into one microbatched program.
 
     stages: transformers whose batch path is a pure array→array function
     (exposed via ``batch_fn()`` or vmap of ``apply``).
-    microbatch: rows processed per step per shard.
+    microbatch: rows processed per step per shard. None (the default)
+    derives it from the bytes a row makes in this chain under the
+    planner's HBM budget (`analysis.plan_ir.microbatch_rows`), at most
+    `MAX_MICROBATCH`; a number is taken as given.
     """
 
     #: a fused chain is itself a traceable single-dep stage, so later
@@ -416,7 +586,8 @@ class FusedBatchTransformer(Transformer):
     #: kernel carried a static proof.
     planned_kernel_statically_verified = None
 
-    def __init__(self, stages: Sequence[Transformer], microbatch: int = 2048):
+    def __init__(self, stages: Sequence[Transformer],
+                 microbatch: Optional[int] = None):
         self.stages = list(stages)
         self.microbatch = microbatch
 
@@ -499,8 +670,39 @@ class FusedBatchTransformer(Transformer):
             return None
         return start, stop, fn
 
+    def _chunk_rows(self, decomposition, array_shape, dtype_name,
+                    local_n: int) -> int:
+        """Rows a microbatch of this chain takes on ``local_n`` rows a
+        shard: the number handed over if one was, else derived once a
+        chain structure, element and budget from the bytes a row makes
+        (`chain_row_bytes`) and remembered."""
+        if self.microbatch is not None:
+            return max(1, min(self.microbatch, local_n))
+        from ...analysis.plan_ir import hbm_budget_bytes, microbatch_rows
+
+        statics, flat, treedef, fns = decomposition
+        budget = hbm_budget_bytes()
+        key = (statics, treedef,
+               tuple((tuple(p.shape), _leaf_dtype_name(p)) for p in flat),
+               tuple(array_shape[1:]), dtype_name, budget)
+        cache = (self.__dict__.setdefault("_instance_microbatch", {})
+                 if _contains_opaque(statics) else _MICROBATCH_CACHE)
+        rows = cache.get(key)
+        if rows is None:
+            params = jax.tree_util.tree_unflatten(
+                treedef, [jax.ShapeDtypeStruct(jnp.shape(p),
+                                               _leaf_dtype_name(p))
+                          for p in flat])
+            rows = cache[key] = microbatch_rows(
+                chain_row_bytes(fns, params, array_shape[1:], dtype_name),
+                budget, MAX_MICROBATCH)
+        return max(1, min(rows, local_n))
+
     def _program_key(self, statics, flat, treedef, array_shape, dtype_name,
-                     padded_count, n_shards, mesh):
+                     padded_count, n_shards, mesh, chunk=None):
+        if chunk is None:  # asked by hand: as `_build_program` builds by hand
+            chunk = min(self.microbatch or MAX_MICROBATCH,
+                        padded_count // n_shards)
         return (
             statics,
             treedef,
@@ -509,7 +711,7 @@ class FusedBatchTransformer(Transformer):
             dtype_name,
             padded_count,
             n_shards,
-            min(self.microbatch, padded_count // n_shards),
+            chunk,
             mesh,
             self.planned_out_spec,
             self.planned_precision,
@@ -533,10 +735,13 @@ class FusedBatchTransformer(Transformer):
                 data = s.apply_batch(data)
             return data
 
-        statics, flat, treedef, fns = self._decompose()
+        statics, flat, treedef, fns = decomposition = self._decompose()
+        chunk = self._chunk_rows(
+            decomposition, data.array.shape, data.array.dtype.name,
+            data.padded_count // data.n_shards)
         key = self._program_key(
             statics, flat, treedef, data.array.shape, data.array.dtype.name,
-            data.padded_count, data.n_shards, data.mesh)
+            data.padded_count, data.n_shards, data.mesh, chunk)
         cache = self._program_cache(statics)
         program = cache.get(key)
         if program is None:
@@ -553,11 +758,12 @@ class FusedBatchTransformer(Transformer):
         if program is None:
             program = self._build_program(
                 data.mesh, data.n_shards, data.padded_count,
-                treedef, fns, statics=statics)
+                treedef, fns, statics=statics, chunk=chunk)
             cache[key] = program
         from ...telemetry import counter, dispatch, span
 
         self._count_gather_bytes(data)
+        self._count_stage_rows(data)
         swap = self._kernel_swap(statics)
         if swap is not None:
             # the planned chain megakernel is live in this program:
@@ -608,6 +814,25 @@ class FusedBatchTransformer(Transformer):
                 per_row += math.prod(elem.shape) * elem.dtype.itemsize
         counter("gather.concat_bytes").inc(data.padded_count * per_row)
 
+    def _count_stage_rows(self, data):
+        """Lets every stage that counts what a dispatch works through
+        (`count_rows(element, rows)`: SIFT's images and descriptors, the
+        sampler's kept rows, the Fisher encoder's images) do so, from
+        the shapes, once a call."""
+        stages = list(self._flat_stages())
+        if not any(hasattr(s, "count_rows") for s in stages):
+            return
+        stages = _peephole(stages)  # as the program runs them
+        last = max(i for i, s in enumerate(stages)
+                   if hasattr(s, "count_rows"))
+        from ...workflow.operators import fitted_elem_fn
+
+        elem = jax.ShapeDtypeStruct(data.array.shape[1:], data.array.dtype)
+        for s in stages[:last + 1]:
+            if hasattr(s, "count_rows"):
+                s.count_rows(elem, data.count)
+            elem = fitted_elem_fn(s)(elem)
+
     def warmup(self, element, count: int, mesh=None) -> Optional[str]:
         """AOT-compile this chain's batch program from a static spec —
         no data touched. ``element`` is the per-item
@@ -630,10 +855,12 @@ class FusedBatchTransformer(Transformer):
         padded = -(-count // shards) * shards
         array_shape = (padded,) + tuple(element.shape)
         dtype = jnp.dtype(element.dtype)
-        statics, flat, treedef, fns = self._decompose()
+        statics, flat, treedef, fns = decomposition = self._decompose()
+        chunk = self._chunk_rows(decomposition, array_shape, dtype.name,
+                                 padded // shards)
         key = self._program_key(
             statics, flat, treedef, array_shape, dtype.name,
-            padded, shards, mesh)
+            padded, shards, mesh, chunk)
         cache = self._program_cache(statics)
         if key in cache:
             return "cached"
@@ -651,7 +878,8 @@ class FusedBatchTransformer(Transformer):
             with span("aot_warmup", cat="compile", layer="compile",
                       label=self.label, rows=padded):
                 jitted = self._build_program(mesh, shards, padded,
-                                             treedef, fns, statics=statics)
+                                             treedef, fns, statics=statics,
+                                             chunk=chunk)
                 xs_aval = jax.ShapeDtypeStruct(
                     array_shape, dtype,
                     sharding=leaf_sharding(mesh, array_shape))
@@ -685,9 +913,10 @@ class FusedBatchTransformer(Transformer):
         return lax.map(lambda xm: chunk_fn(params, xm[0], xm[1]), (xs, ms))
 
     def _build_program(self, mesh, shards, padded_count, treedef, fns,
-                       statics=None):
+                       statics=None, chunk=None):
         local_n = padded_count // shards
-        chunk = min(self.microbatch, local_n)
+        if chunk is None:  # built by hand: the number given, or the ceiling
+            chunk = min(self.microbatch or MAX_MICROBATCH, local_n)
         n_chunks = -(-local_n // chunk)
         padded_local = n_chunks * chunk
 
@@ -748,10 +977,8 @@ class FusedBatchTransformer(Transformer):
         def per_shard(flat_params, xs, ms):
             # xs: (local_n, ...) shard rows; ms: (local_n,) valid mask
             params = jax.tree_util.tree_unflatten(treedef, flat_params)
-            if padded_local != local_n:
-                pad = [(0, padded_local - local_n)] + [(0, 0)] * (xs.ndim - 1)
-                xs = jnp.pad(xs, pad)
-                ms = jnp.pad(ms, [(0, padded_local - local_n)])
+            if padded_local != local_n or xs.nbytes >= COPY_FREE_BYTES:
+                return _overlapped_chunks(chunk_fn, params, xs, ms, chunk)
             xs = xs.reshape((n_chunks, chunk) + xs.shape[1:])
             ms = ms.reshape((n_chunks, chunk))
             # sequential chunks: bounded HBM
@@ -814,9 +1041,13 @@ class MegafusedBatchTransformer(FusedBatchTransformer):
     #: the one-program apply path
     megafused = True
 
-    def _n_trips(self, padded_count: int, n_shards: int) -> int:
-        local_n = max(1, padded_count // max(1, n_shards))
-        chunk = min(self.microbatch, local_n)
+    def _n_trips(self, data) -> int:
+        local_n = max(1, data.padded_count // max(1, data.n_shards))
+        # a microbatch handed over needs no look at the chain
+        decomposition = (None if self.microbatch is not None
+                         else self._decompose())
+        chunk = self._chunk_rows(decomposition, data.array.shape,
+                                 data.array.dtype.name, local_n)
         return -(-local_n // chunk)
 
     def _program_key(self, *args, **kwargs):
@@ -829,7 +1060,7 @@ class MegafusedBatchTransformer(FusedBatchTransformer):
             return super().apply_batch(data)
         from ...telemetry import counter, span
 
-        trips = self._n_trips(data.padded_count, data.n_shards)
+        trips = self._n_trips(data)
         with span("megafused_program", cat="node", megafused=True,
                   scan_trips=trips, rows=data.count, label=self.label):
             out = super().apply_batch(data)

@@ -179,11 +179,14 @@ def _gray(key, params):
         return (jnp.asarray([0.299, 0.587, 0.114],  # keystone: ignore[KJ011]
                             jnp.float32).reshape(1, 3),)
 
+    # the key's second entry: whether the one channel stays an axis
+    channel = key[1] if isinstance(key, tuple) and len(key) > 1 else True
+
     def body(x, ops):
         if x.shape[-1] == 1:
-            return x
+            return x if channel else x[..., 0]
         return jnp.sum(jnp.asarray(x, jnp.float32) * ops[0],  # keystone: ignore[KJ011]
-                       axis=-1, keepdims=True)
+                       axis=-1, keepdims=channel)
 
     return prep, body
 

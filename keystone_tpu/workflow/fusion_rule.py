@@ -30,6 +30,8 @@ is independent of node-id iteration order.
 
 from __future__ import annotations
 
+import logging
+import math
 from typing import Dict, List, Sequence, Tuple
 
 from .analysis import children
@@ -50,6 +52,8 @@ from .operators import (
     _streamed_batch,
 )
 from .optimizer import Plan, Rule
+
+logger = logging.getLogger(__name__)
 
 
 def _record_fusion_decision(kind: str, rule: str, chain, labels,
@@ -131,7 +135,7 @@ class FusedChainOperator(Operator):
 
     may_consume_chunks = True
 
-    def __init__(self, stage_specs: Sequence, microbatch: int = 2048):
+    def __init__(self, stage_specs: Sequence, microbatch=None):
         self.stage_specs = list(stage_specs)
         self.microbatch = microbatch
 
@@ -388,7 +392,7 @@ class MegafusionRule(Rule):
     exactly.
     """
 
-    def __init__(self, microbatch: int = 2048):
+    def __init__(self, microbatch=None):
         self.microbatch = microbatch
 
     # ---------------------------------------------------- member predicate
@@ -596,8 +600,122 @@ def megafusion_blockers(graph: Graph) -> List[Tuple[NodeId, str, str]]:
     return blockers
 
 
+def _declared_output(graph: Graph, vid, memo: Dict):
+    """(element, count) of what ``vid`` puts out, where every stage from
+    the dataset down to it says its output's shape without a trace (an
+    ``abstract_apply`` hook on a transformer, ``abstract_fit`` on the
+    estimator behind an apply boundary), else None. Cheap by design:
+    this is asked of every plan, and a plan with a stage that declares
+    nothing is left as it is."""
+    if vid in memo:
+        return memo[vid]
+    memo[vid] = out = None
+    if not isinstance(vid, NodeId):
+        return None
+    op = graph.get_operator(vid)
+    deps = graph.get_dependencies(vid)
+    try:
+        from .operators import DatasetOperator
+
+        if isinstance(op, DatasetOperator):
+            array = getattr(op.dataset, "array", None)
+            if hasattr(array, "shape") and hasattr(op.dataset, "count"):
+                import jax
+
+                out = (jax.ShapeDtypeStruct(array.shape[1:], array.dtype),
+                       int(op.dataset.count))
+        elif isinstance(op, DelegatingOperator) and len(deps) == 2:
+            src = _declared_output(graph, deps[1], memo)
+            est = (graph.get_operator(deps[0])
+                   if isinstance(deps[0], NodeId) else None)
+            fit = getattr(est, "abstract_fit", None)
+            if src is not None and fit is not None:
+                elem = fit([]).apply_element(src[0])
+                if hasattr(elem, "shape"):
+                    out = (elem, src[1])
+        elif len(deps) == 1 and hasattr(op, "abstract_apply"):
+            src = _declared_output(graph, deps[0], memo)
+            if src is not None:
+                out = (op.abstract_apply(src[0]), src[1])
+    except Exception:
+        out = None
+    memo[vid] = out
+    return out
+
+
+def _refuse_oversized(rule: "NodeFusionRule", plan: Plan) -> Plan:
+    """Keep off the device what cannot lie on it. A dataset a plan would
+    hold whole is priced from the shapes its stages declare
+    (`_declared_output`) against the planner's HBM budget
+    (`analysis.plan_ir.resident_fits`):
+
+      - a `Cacher` whose output does not fit is refused: it is taken out
+        of the plan and its consumers read what fed it
+        (``planner.caches_refused``);
+      - a fusable stage with several consumers, whose output would be
+        held whole between them and does not fit, is planted once a
+        consumer (``planner.recomputes_planted``): each consumer's chain
+        then fuses down to it and makes the stage's rows again, a
+        microbatch at a time, instead of reading 100 GB that no chip
+        holds.
+
+    From the sinks up, so a stage that feeds an oversized stage sees its
+    new consumers."""
+    from ..analysis.plan_ir import resident_fits
+    from ..analysis.propagate import toposort
+    from ..nodes.util.basic import Cacher
+    from ..telemetry import counter
+
+    graph, prefixes = plan
+    memo: Dict = {}
+
+    def oversized(node) -> bool:
+        out = _declared_output(graph, node, memo)
+        if out is None:
+            return False
+        elem, count = out
+        nbytes = count * math.prod(elem.shape) * elem.dtype.itemsize
+        return not resident_fits(nbytes)
+
+    # most plans hold nothing of the kind: ask the few vertices that could
+    # (a cache point, a stage with several consumers) before any rewrite
+    if not any(oversized(n) for n, op in graph.operators.items()
+               if isinstance(op, Cacher) or len(children(graph, n)) > 1):
+        return plan
+    order, _ = toposort(graph)
+    for node in reversed([v for v in order if isinstance(v, NodeId)]):
+        if node not in graph.operators:
+            continue
+        op = graph.get_operator(node)
+        deps = graph.get_dependencies(node)
+        if isinstance(op, Cacher) and len(deps) == 1:
+            if oversized(node):
+                logger.info("refusing %s: its output does not fit the "
+                            "HBM budget", op.label)
+                graph = graph.replace_dependency(node, deps[0])
+                graph = graph.remove_node(node)
+                prefixes.pop(node, None)
+                counter("planner.caches_refused").inc()
+            continue
+        kids = sorted((k for k in children(graph, node)),
+                      key=lambda k: (isinstance(k, NodeId), k.id))
+        if len(kids) < 2 or not rule._fusable(graph, node) \
+                or not oversized(node):
+            continue
+        for kid in kids[1:]:
+            graph, twin = graph.add_node(op, deps)
+            if isinstance(kid, NodeId):
+                graph = graph.set_dependencies(kid, tuple(
+                    twin if d == node else d
+                    for d in graph.get_dependencies(kid)))
+            else:
+                graph = graph.set_sink_dependency(kid, twin)
+            counter("planner.recomputes_planted").inc()
+    return graph, prefixes
+
+
 class NodeFusionRule(Rule):
-    def __init__(self, microbatch: int = 2048, fuse_apply: bool = True):
+    def __init__(self, microbatch=None, fuse_apply: bool = True):
         self.microbatch = microbatch
         #: PR-4 expanded coverage: fuse through fan-out-free estimator
         #: apply boundaries AND collapse fusable gather/combiner
@@ -708,6 +826,7 @@ class NodeFusionRule(Rule):
         return graph, prefixes
 
     def apply(self, plan: Plan) -> Plan:
+        plan = _refuse_oversized(self, plan)
         plan = self._fuse_linear(plan)
         if self.fuse_apply:
             # gather diamonds need the linear pass FIRST (each branch

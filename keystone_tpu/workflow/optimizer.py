@@ -22,7 +22,7 @@ import logging
 import re
 import weakref
 from dataclasses import dataclass, field
-from typing import Dict, List, Tuple
+from typing import Dict, List, Optional, Tuple
 
 from .analysis import ancestors, linearize
 from .env import PipelineEnv, Prefix, compute_prefix
@@ -1041,10 +1041,14 @@ class Optimizer(RuleExecutor):
 class DefaultOptimizer(Optimizer):
     """Batches mirror DefaultOptimizer.scala:8-31 (saved-state reuse and
     dead-branch removal once; CSE to fixpoint; node-level optimization
-    once) plus the TPU-native stage-fusion pass (see fusion_rule.py)."""
+    once) plus the TPU-native stage-fusion pass (see fusion_rule.py).
+    ``fusion_microbatch`` None (the default) leaves every fused program's
+    microbatch to the bytes a row makes in it under the HBM budget
+    (`analysis.plan_ir.microbatch_rows`); a number overrides that."""
 
     def __init__(self, samples_per_shard: int = 3, fuse: bool = True,
-                 fusion_microbatch: int = 2048, fuse_apply: bool = True,
+                 fusion_microbatch: Optional[int] = None,
+                 fuse_apply: bool = True,
                  megafuse: bool = True, sharding_planner: bool = True,
                  precision_planner: bool = True,
                  unified_planner: bool = True):
@@ -1055,7 +1059,7 @@ class DefaultOptimizer(Optimizer):
                 "state",
                 [ExtractSaveablePrefixes(), SavedStateLoadRule(), UnusedBranchRemovalRule()],
             ),
-            Batch("cse", [EquivalentNodeMergeRule()], max_iterations=10),
+            Batch("cse", [EquivalentNodeMergeRule()], max_iterations=64),
         ]
         if fuse:
             # fuse_apply=False reproduces the PR-3 plan (transformer

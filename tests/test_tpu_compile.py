@@ -415,3 +415,52 @@ def test_the_kernel_apply_fits_the_chip_at_cifar_kernel_fit_s_size(
     assert "ks.krr.apply" in compiled.as_text()
     for metric in ("kernel_apply_ms_per_fit", "kernel_apply_roofline"):
         assert _metric_pattern(metric).search(_module_name(compiled))
+
+
+def test_voc_fit_s_fisher_program_fits_beside_what_a_fit_keeps(one_chip, topo):
+    """`voc_fit`'s heaviest program as the optimizer builds it: SIFT, the
+    PCA projection, the Fisher encoding and the three normalizations
+    over the 5,011 cached grayscale images (375 x 500), one fused
+    program at the microbatch the rule derives (8 images: 605 MB of
+    posteriors). Its output is the (5,011, 40,960) features; its
+    temporaries stay a microbatch's (under 1.5 GB where a copy of the
+    cached images in a layout of the compiler's liking was 3.76 GB more:
+    the v5e keeps (5011, 375, 500) with the images on the lanes), so the
+    images, the samples, the features and the program fit 16 GB."""
+    from keystone_tpu.nodes.images.fisher_vector import FisherVector
+    from keystone_tpu.nodes.images.sift import SIFTExtractor
+    from keystone_tpu.nodes.learning.gmm import GaussianMixtureModel
+    from keystone_tpu.nodes.learning.pca import PCATransformer
+    from keystone_tpu.nodes.stats import NormalizeRows, SignedHellingerMapper
+    from keystone_tpu.nodes.util import MatrixVectorizer
+    from keystone_tpu.nodes.util.fusion import FusedBatchTransformer
+    from keystone_tpu.workflow.env import ExecutionConfig, set_execution_config
+
+    n, h, w = 5011, 375, 500
+    pca = PCATransformer.__new__(PCATransformer)
+    pca.components = _aval((128, 80), jnp.float32, one_chip)
+    gmm = GaussianMixtureModel.__new__(GaussianMixtureModel)
+    gmm.means = _aval((256, 80), jnp.float32, one_chip)
+    gmm.variances = _aval((256, 80), jnp.float32, one_chip)
+    gmm.weights = _aval((256,), jnp.float32, one_chip)
+    op = FusedBatchTransformer([
+        SIFTExtractor(3, 4, 4, 0), pca, FisherVector(gmm), MatrixVectorizer(),
+        NormalizeRows(), SignedHellingerMapper(), NormalizeRows()])
+    decomposition = statics, flat, treedef, fns = op._decompose()
+    set_execution_config(ExecutionConfig(hbm_budget_bytes=16 << 30))
+    try:
+        chunk = op._chunk_rows(decomposition, (n, h, w), "float32", n)
+    finally:
+        set_execution_config(None)
+    assert chunk == 8
+    mesh = Mesh(np.asarray(topo.devices[:1]), ("data",))
+    program = op._build_program(mesh, 1, n, treedef, fns, statics=statics,
+                                chunk=chunk)
+    compiled = program.lower(
+        flat, _aval((n, h, w), jnp.float32, one_chip),
+        _aval((n,), jnp.bool_, one_chip)).compile()
+    memory = compiled.memory_analysis()
+    assert memory.output_size_in_bytes >= 4 * n * 40960
+    assert memory.temp_size_in_bytes < 1.5e9
+    hlo = compiled.as_text()
+    assert "ks.sift" in hlo and "ks.pca.apply" in hlo and "ks.fisher" in hlo
