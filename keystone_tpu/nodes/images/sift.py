@@ -51,6 +51,8 @@ program of a batch.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -75,11 +77,13 @@ def _gaussian_taps(sigma: float) -> np.ndarray:
     return (k / k.sum()).astype(np.float32)
 
 
-def _triangular_taps(bin_size: int) -> np.ndarray:
-    """vl_imconvcoltri_f kernel: unit integral, taps (bs−|k|)/bs²."""
+def _triangle(bin_size: int) -> np.ndarray:
+    """vl_imconvcoltri_f kernel times bs²: the integer taps bs−|k| (the
+    kernel's are (bs−|k|)/bs², of unit integral). A band of them with
+    its edge folds holds integers up to bs(bs+1)/2, exact in bf16 for
+    every bin size up to 24."""
     bs = bin_size
-    k = (bs - np.abs(np.arange(-(bs - 1), bs))).astype(np.float64)
-    return (k / (bs * bs)).astype(np.float32)
+    return (bs - np.abs(np.arange(-(bs - 1), bs))).astype(np.float32)
 
 
 def _bin_window_mean(bin_size: int, bin_index: int) -> float:
@@ -89,6 +93,14 @@ def _bin_window_mean(bin_size: int, bin_index: int) -> float:
     sigma = bin_size * WINDOW_SIZE
     xs = np.arange(-bin_size + 1, bin_size, dtype=np.float64)
     return float(np.mean(np.exp(-0.5 * ((xs + delta) / sigma) ** 2))) * bin_size
+
+
+def _bin_scales(bin_size: int) -> np.ndarray:
+    """(4,): what a bin made with the integer band `_triangle` is
+    multiplied by, along each axis: the triangle's 1/bs² and the flat
+    window's mean of the bin, in float64 and rounded once."""
+    s = np.asarray([_bin_window_mean(bin_size, i) for i in range(GRID)])
+    return (s / (bin_size * bin_size)).astype(np.float32)
 
 
 def _band_matrix(n: int, taps) -> np.ndarray:
@@ -104,18 +116,43 @@ def _band_matrix(n: int, taps) -> np.ndarray:
     return M.astype(np.float32)
 
 
+def _exact_in_bf16(m: np.ndarray) -> bool:
+    return bool((m == m.astype(jnp.bfloat16).astype(np.float32)).all())
+
+
+def _exact_operand_product(x, m: np.ndarray, axis: int):
+    """``x`` (float32) contracted along ``axis`` with the columns of the
+    constant matrix ``m`` (out, in), whose rows take the axis's place.
+    On a TPU a float32 product at `highest` is six bf16 passes, each a
+    pair of the operands' bf16 pieces (hi·hi, hi·mid, mid·hi, hi·lo,
+    lo·hi, mid·mid). Where ``m`` is exact in bf16 its mid and low pieces
+    are zero, and so are three of the passes: ``m`` is then taken at
+    `default` (one piece) against ``x`` at `highest` (three pieces, which
+    hold float32 exactly), the same three nonzero partial products in
+    float32. Any other ``m`` is taken at `highest` on both sides.
+    `lax.dot_general` and not `jnp.einsum`, which may swap the operands
+    and not the precisions with them."""
+    axis = axis % x.ndim
+    precision = ((lax.Precision.HIGHEST, lax.Precision.DEFAULT)
+                 if _exact_in_bf16(m) else lax.Precision.HIGHEST)
+    out = lax.dot_general(x, m, (((axis,), (1,)), ((), ())),
+                          precision=precision,
+                          preferred_element_type=jnp.float32)
+    return jnp.moveaxis(out, -1, axis)
+
+
 def _sep_conv_edge(x, taps):
     """Separable convolution of the two last axes (rows, then columns)
-    as two products with banded matrices, in float32 at `highest`: on a
-    TPU a stencil of 7 to 19 taps as shifted sums keeps the vector unit
-    shuffling lanes (8.4 ms an image at VOC's size for the four scales,
-    my chip run, PR 40), and the matrix unit does the same sums as a
-    product with a matrix that is mostly zeros many times faster."""
+    as two products with banded matrices, in float32
+    (`_exact_operand_product`: six bf16 passes, three where the band is
+    exact in bf16): on a TPU a stencil of 7 to 19 taps as shifted sums
+    keeps the vector unit shuffling lanes (8.4 ms an image at VOC's size
+    for the four scales, my chip run, PR 40), and the matrix unit does
+    the same sums as a product with a matrix that is mostly zeros many
+    times faster."""
     h, w = x.shape[-2:]
-    rows = jnp.einsum("uh,...hw->...uw", _band_matrix(h, taps), x,
-                      precision=lax.Precision.HIGHEST)
-    return jnp.einsum("...uw,vw->...uv", rows, _band_matrix(w, taps),
-                      precision=lax.Precision.HIGHEST)
+    rows = _exact_operand_product(x, _band_matrix(h, taps), -2)
+    return _exact_operand_product(rows, _band_matrix(w, taps), -1)
 
 
 def frame_grid(h: int, w: int, bin_size: int, step: int, off: int):
@@ -154,22 +191,22 @@ def _orientation_maps(gray, bin_size: int):
 
 def _aggregated_maps(gray, bin_size: int):
     """Steps 1 to 4: the orientation maps after the flat-window spatial
-    binning (a triangular conv per channel), (b, 8, h, w)."""
+    binning (a triangular conv per channel) with the integer band
+    `_triangle`, so bs² times the unit-integral one's, (b, 8, h, w)."""
     return _sep_conv_edge(_orientation_maps(gray, bin_size),
-                          _triangular_taps(bin_size))
+                          _triangle(bin_size))
 
 
 def _bin_rows(n: int, count: int, bin_size: int, step: int, off: int):
     """(4 * count, n): for each of the four bins along an axis of length
-    ``n``, the rows of the triangular band matrix at the bin's centres of
-    the ``count`` frames (``step`` apart from ``off``), each scaled by
-    the bin's window mean: binning, the strided choice of the frames'
-    bin centres and the flat window's reweighting as ONE matrix."""
-    band = _band_matrix(n, _triangular_taps(bin_size))
+    ``n``, the rows of the integer triangular band matrix at the bin's
+    centres of the ``count`` frames (``step`` apart from ``off``):
+    binning and the strided choice of the frames' bin centres as ONE
+    matrix, exact in bf16; the scales are `_bin_scales`'."""
+    band = _band_matrix(n, _triangle(bin_size))
     centres = off + step * np.arange(count)
-    return np.concatenate(
-        [_bin_window_mean(bin_size, i) * band[centres + i * bin_size]
-         for i in range(GRID)], axis=0).astype(np.float32)
+    return np.concatenate([band[centres + i * bin_size] for i in range(GRID)],
+                          axis=0)
 
 
 def _sift_one_scale(gray, bin_size: int, step: int, off: int):
@@ -187,11 +224,12 @@ def _sift_one_scale(gray, bin_size: int, step: int, off: int):
     # of one (sixteen slices of stride 3 along the lanes were 10 of a
     # full pass's 12.4 ms an image on a v5e, a general gather of the
     # same 9.45 million elements more: my chip runs, PR 40).
-    cols = jnp.einsum("bohw,vw->bohv", maps,
-                      _bin_rows(w, n_c, bin_size, step, off),
-                      precision=lax.Precision.HIGHEST)
-    bins = jnp.einsum("uh,bohv->bouv", _bin_rows(h, n_r, bin_size, step, off),
-                      cols, precision=lax.Precision.HIGHEST)
+    cols = _exact_operand_product(
+        maps, _bin_rows(w, n_c, bin_size, step, off), 3) \
+        * np.repeat(_bin_scales(bin_size), n_c)
+    bins = _exact_operand_product(
+        cols, _bin_rows(h, n_r, bin_size, step, off), 2) \
+        * np.repeat(_bin_scales(bin_size), n_r)[:, None]
     # (b, 8, (i, r), (j, c)) -> frames column-outer / row-inner (the
     # reference's frame order), features [row-bin, col-bin, orientation]
     desc = bins.reshape(b, NUM_ORIENTATIONS, GRID, n_r, GRID, n_c)
@@ -212,24 +250,23 @@ def _sift_some_frames(gray, bin_size: int, step: int, off: int, frames):
     bin_off = np.arange(GRID) * bin_size
     rows = off + (frames % n_r)[:, None] * step + bin_off  # (m, 4)
     cols = off + (frames // n_r)[:, None] * step + bin_off
-    wmean = np.asarray([_bin_window_mean(bin_size, i) for i in range(GRID)],
-                       np.float32)
+    scales = _bin_scales(bin_size)
     # (b, 8, m, 4, 4): orientation o, frame, row-bin i, col-bin j
     desc = agg[:, :, rows[:, :, None], cols[:, None, :]]
-    desc = desc * (wmean[:, None] * wmean[None, :])
+    desc = desc * scales[None, :] * scales[:, None]
     return desc.transpose(0, 2, 3, 4, 1).reshape(
         agg.shape[0], len(frames), GRID * GRID * NUM_ORIENTATIONS)
 
 
 def _row_sums(x):
     """Every row's sum, in every one of the row's places: a product with
-    a matrix of ones, in float32 at `highest`. On a TPU a sum along the
-    128 lanes is seven rounds of lane shuffles a vector register and the
-    result has to be spread over the lanes again; the matrix unit gives
-    both at once."""
+    a matrix of ones, in float32 by three bf16 passes (the ones are exact:
+    `_exact_operand_product`). On a TPU a sum along the 128 lanes is
+    seven rounds of lane shuffles a vector register and the result has
+    to be spread over the lanes again; the matrix unit gives both at
+    once."""
     d = x.shape[-1]
-    return jnp.matmul(x, np.ones((d, d), np.float32),
-                      precision=lax.Precision.HIGHEST)
+    return _exact_operand_product(x, np.ones((d, d), np.float32), -1)
 
 
 def _normalize_quantize(desc):
@@ -241,6 +278,42 @@ def _normalize_quantize(desc):
     desc = desc / (jnp.sqrt(_row_sums(desc * desc)) + VL_EPSILON_F)
     desc = jnp.where(norm < CONTRAST_THRESHOLD, 0.0, desc)
     return jnp.minimum(jnp.floor(512.0 * desc), 255.0)
+
+
+def _frames_by_scale(scales, h: int, w: int, rows):
+    """(scale, its frames among ``rows``) for each scale with some:
+    ``rows`` number the descriptors of all scales side by side."""
+    rows = np.asarray(rows)
+    start = 0
+    for b, st, off in scales:
+        count = int(np.prod(frame_grid(h, w, b, st, off)))
+        mine = rows[(rows >= start) & (rows < start + count)] - start
+        if len(mine):
+            yield (b, st, off), mine
+        start += count
+
+
+@functools.lru_cache(maxsize=16)
+def _split_products(scales, h: int, w: int, rows) -> int:
+    """`SIFTExtractor.split_products`: the two binning products of each
+    scale that has descriptors (with ``rows``, of each scale that has
+    frames among them) and the two row sums, where the matrix is exact
+    in bf16. The Gaussian smoothing's bands never are."""
+    bands = []
+    if rows is None:
+        for b, st, off in scales:
+            n_r, n_c = frame_grid(h, w, b, st, off)
+            if n_r and n_c:
+                bands += [_bin_rows(w, n_c, b, st, off),
+                          _bin_rows(h, n_r, b, st, off)]
+    else:
+        for (b, _, _), _ in _frames_by_scale(scales, h, w, rows):
+            bands += [_band_matrix(h, _triangle(b)),
+                      _band_matrix(w, _triangle(b))]
+    if bands:
+        d = GRID * GRID * NUM_ORIENTATIONS
+        bands += 2 * [np.ones((d, d), np.float32)]
+    return sum(map(_exact_in_bf16, bands))
 
 
 class SIFTExtractorInterface(Transformer):
@@ -323,16 +396,11 @@ class SIFTExtractor(SIFTExtractorInterface):
             gray = gray[..., 0]
         gray = gray.astype(jnp.float32)
         h, w = gray.shape[1:]
-        rows = np.asarray(rows)
-        parts, start = [], 0
         with jax.named_scope("ks.sift"):
-            for b, st, off in self._scales():
-                count = int(np.prod(frame_grid(h, w, b, st, off)))
-                mine = rows[(rows >= start) & (rows < start + count)] - start
-                if len(mine):
-                    parts.append(_sift_some_frames(gray, b, st, off, mine))
-                start += count
-            return _normalize_quantize(jnp.concatenate(parts, axis=1))
+            return _normalize_quantize(jnp.concatenate(
+                [_sift_some_frames(gray, *scale, mine) for scale, mine
+                 in _frames_by_scale(tuple(self._scales()), h, w, rows)],
+                axis=1))
 
     def batch_fn(self):
         return self._batch
@@ -341,14 +409,25 @@ class SIFTExtractor(SIFTExtractorInterface):
         return (("SIFT", self.step, self.bin_size, self.num_scales,
                  self.scale_step), (), lambda p, xb: self._batch(xb))
 
+    def split_products(self, h: int, w: int, rows=None) -> int:
+        """Products an (h, w) image takes in the three-pass form
+        (`_exact_operand_product`) in `_batch`, or with ``rows`` in
+        `_batch_rows`: from the shapes and the matrices alone."""
+        return _split_products(
+            tuple(self._scales()), h, w,
+            None if rows is None else tuple(np.asarray(rows).tolist()))
+
     def count_rows(self, elem, rows: int):
-        """`sift.images`, `sift.descriptors`: what one dispatch of a
-        program holding this stage extracts, from the shapes."""
+        """`sift.images`, `sift.descriptors`, `sift.split_products`: what
+        one dispatch of a program holding this stage extracts, from the
+        shapes."""
         from ...telemetry import counter
 
         counter("sift.images").inc(rows)
         counter("sift.descriptors").inc(
             rows * self.abstract_apply(elem).shape[0])
+        counter("sift.split_products").inc(
+            rows * self.split_products(*elem.shape[:2]))
 
     def _jitted_batch(self):
         fn = self.__dict__.get("_jitted")

@@ -170,27 +170,36 @@ class _SampledSIFTStage(Transformer):
     def apply(self, x):
         return self.sampler.apply(self.sift.apply(x))
 
-    def fuse(self):
+    def _rows(self, h: int, w: int):
+        """The descriptors the sampler keeps, or None where it keeps all
+        and the stage is `SIFTExtractor._batch`."""
         from ..stats.normalization import sample_rows
 
-        sift, num, seed = self.sift, self.sampler.num_cols, self.sampler.seed
+        nd, num = self.sift.num_descriptors(h, w), self.sampler.num_cols
+        return None if nd <= num else sample_rows(nd, num, self.sampler.seed)
+
+    def fuse(self):
+        sift = self.sift
 
         def fn(p, xb):
-            h, w = xb.shape[1:3]
-            nd = sift.num_descriptors(h, w)
-            if nd <= num:
+            rows = self._rows(*xb.shape[1:3])
+            if rows is None:
                 return sift._batch(xb)
             with jax.named_scope("ks.sift.sample"):
-                return sift._batch_rows(xb, sample_rows(nd, num, seed))
+                return sift._batch_rows(xb, rows)
 
-        return (("SampledSIFT", sift.fuse()[0], num, seed), (), fn)
+        return (("SampledSIFT", sift.fuse()[0], self.sampler.num_cols,
+                 self.sampler.seed), (), fn)
 
     def count_rows(self, elem, rows: int):
         from ...telemetry import counter
 
+        h, w = elem.shape[:2]
         kept = self.abstract_apply(elem).shape[0]
         counter("sift.images").inc(rows)
         counter("sift.descriptors").inc(rows * kept)
+        counter("sift.split_products").inc(
+            rows * self.sift.split_products(h, w, self._rows(h, w)))
         counter("sampler.rows_kept").inc(rows * kept)
 
 

@@ -464,3 +464,41 @@ def test_voc_fit_s_fisher_program_fits_beside_what_a_fit_keeps(one_chip, topo):
     assert memory.temp_size_in_bytes < 1.5e9
     hlo = compiled.as_text()
     assert "ks.sift" in hlo and "ks.pca.apply" in hlo and "ks.fisher" in hlo
+
+
+def test_sift_s_full_pass_takes_its_exact_products_in_three_passes(
+        one_chip, monkeypatch):
+    """SIFT's full pass over a microbatch of 8 VOC images (375 x 500,
+    `voc_fit`'s configuration): its 8 binning products and 2 row sums
+    keep `default` on their exact constant and `highest` on the maps
+    (three bf16 passes where six ran), the 8 Gaussian products keep
+    `highest` on both; no more convolutions, bytes accessed or
+    temporaries than the same program at `highest` everywhere."""
+    import re
+
+    from keystone_tpu.nodes.images import sift
+
+    gray = _aval((8, 375, 500), jnp.float32, one_chip)
+    extractor = sift.SIFTExtractor(3, 4, 4, 0)
+
+    def compiled():
+        return jax.jit(extractor._batch).lower(gray).compile()
+
+    split = compiled()
+    monkeypatch.setattr(sift, "_exact_in_bf16", lambda m: False)
+    six = compiled()
+    precisions = re.findall(r"operand_precision=\{(\w+),(\w+)\}",
+                            split.as_text())
+    assert sorted(precisions) == \
+        10 * [("highest", "default")] + 8 * [("highest", "highest")]
+    assert split.as_text().count("convolution(") \
+        <= six.as_text().count("convolution(")
+
+    def cost(c):
+        costs = c.cost_analysis()
+        return (costs[0] if isinstance(costs, list) else costs)[
+            "bytes accessed"]
+
+    assert cost(split) <= cost(six)
+    assert split.memory_analysis().temp_size_in_bytes \
+        <= six.memory_analysis().temp_size_in_bytes + 0.25e9

@@ -108,7 +108,7 @@ def _gather_form(gray, extractor):
         maps = jnp.stack(
             [jnp.where(lo == o, mag * (1.0 - frac), 0.0)
              + jnp.where(hi == o, mag * frac, 0.0) for o in range(8)])
-        agg = sift._sep_conv_edge(maps, sift._triangular_taps(bs))
+        agg = sift._sep_conv_edge(maps, sift._triangle(bs) / (bs * bs))
         n_r, n_c = sift.frame_grid(*gray.shape, bs, step, off)
         rr = (off + jnp.arange(n_r) * step)[:, None] + jnp.arange(4) * bs
         cc = (off + jnp.arange(n_c) * step)[:, None] + jnp.arange(4) * bs
