@@ -59,6 +59,7 @@ import numpy as np
 from jax import lax
 
 from ...data.dataset import HostDataset
+from ...ops.pallas_kernels import sift_normalize_pallas, use_sift_normalize
 from ...workflow.pipeline import Transformer
 
 NUM_ORIENTATIONS = 8
@@ -269,7 +270,7 @@ def _row_sums(x):
     return _exact_operand_product(x, np.ones((d, d), np.float32), -1)
 
 
-def _normalize_quantize(desc):
+def _normalize_quantize_reference(desc):
     """vl normalization of raw descriptors (..., 128): L2+eps -> clamp
     0.2 -> L2+eps; contrast zeroing; the JNI short quantization
     floor(512·v) clamped to 255."""
@@ -278,6 +279,23 @@ def _normalize_quantize(desc):
     desc = desc / (jnp.sqrt(_row_sums(desc * desc)) + VL_EPSILON_F)
     desc = jnp.where(norm < CONTRAST_THRESHOLD, 0.0, desc)
     return jnp.minimum(jnp.floor(512.0 * desc), 255.0)
+
+
+def _normalize_quantize(*parts):
+    """`_normalize_quantize_reference` of the raw descriptors (b, n_p,
+    128) of ``parts`` side by side along the rows. Where
+    `use_sift_normalize` takes their rows (a TPU, and at least one tile
+    of descriptors an image: the full pass) one Pallas kernel reads each
+    raw row once and writes it quantized once, at its place among the
+    parts'; the reference first writes the parts' concatenation, and its
+    two row sums are arrays as large as the descriptors, each a pass
+    through HBM. The kernel's sums run in another order, which may move
+    a value across a `floor` boundary by one unit."""
+    if use_sift_normalize(sum(p.shape[-2] for p in parts)):
+        return sift_normalize_pallas(
+            [p for p in parts if p.shape[-2]], eps=VL_EPSILON_F, clamp=0.2,
+            contrast=CONTRAST_THRESHOLD)
+    return _normalize_quantize_reference(jnp.concatenate(parts, axis=-2))
 
 
 def _frames_by_scale(scales, h: int, w: int, rows):
@@ -294,11 +312,12 @@ def _frames_by_scale(scales, h: int, w: int, rows):
 
 
 @functools.lru_cache(maxsize=16)
-def _split_products(scales, h: int, w: int, rows) -> int:
+def _split_products(scales, h: int, w: int, rows, row_sums: bool) -> int:
     """`SIFTExtractor.split_products`: the two binning products of each
     scale that has descriptors (with ``rows``, of each scale that has
-    frames among them) and the two row sums, where the matrix is exact
-    in bf16. The Gaussian smoothing's bands never are."""
+    frames among them) and, with ``row_sums``, the two row sums, where
+    the matrix is exact in bf16. The Gaussian smoothing's bands never
+    are."""
     bands = []
     if rows is None:
         for b, st, off in scales:
@@ -310,7 +329,7 @@ def _split_products(scales, h: int, w: int, rows) -> int:
         for (b, _, _), _ in _frames_by_scale(scales, h, w, rows):
             bands += [_band_matrix(h, _triangle(b)),
                       _band_matrix(w, _triangle(b))]
-    if bands:
+    if bands and row_sums:
         d = GRID * GRID * NUM_ORIENTATIONS
         bands += 2 * [np.ones((d, d), np.float32)]
     return sum(map(_exact_in_bf16, bands))
@@ -381,11 +400,10 @@ class SIFTExtractor(SIFTExtractorInterface):
             gray = gray[..., 0]
         gray = gray.astype(jnp.float32)
         with jax.named_scope("ks.sift"):
-            # the scales side by side first, so that the normalization is
-            # one pass over a microbatch's descriptors and not four
-            return _normalize_quantize(jnp.concatenate(
-                [_sift_one_scale(gray, b, st, off)
-                 for b, st, off in self._scales()], axis=1))
+            # the scales' descriptors side by side, normalized in one pass
+            return _normalize_quantize(
+                *[_sift_one_scale(gray, b, st, off)
+                  for b, st, off in self._scales()])
 
     def _batch_rows(self, gray, rows):
         """The descriptors numbered ``rows`` (static, sorted) of every
@@ -397,10 +415,9 @@ class SIFTExtractor(SIFTExtractorInterface):
         gray = gray.astype(jnp.float32)
         h, w = gray.shape[1:]
         with jax.named_scope("ks.sift"):
-            return _normalize_quantize(jnp.concatenate(
-                [_sift_some_frames(gray, *scale, mine) for scale, mine
-                 in _frames_by_scale(tuple(self._scales()), h, w, rows)],
-                axis=1))
+            return _normalize_quantize(
+                *[_sift_some_frames(gray, *scale, mine) for scale, mine
+                  in _frames_by_scale(tuple(self._scales()), h, w, rows)])
 
     def batch_fn(self):
         return self._batch
@@ -412,22 +429,33 @@ class SIFTExtractor(SIFTExtractorInterface):
     def split_products(self, h: int, w: int, rows=None) -> int:
         """Products an (h, w) image takes in the three-pass form
         (`_exact_operand_product`) in `_batch`, or with ``rows`` in
-        `_batch_rows`: from the shapes and the matrices alone."""
+        `_batch_rows`: from the shapes and the matrices alone. The row
+        sums count where the reference normalizes."""
         return _split_products(
             tuple(self._scales()), h, w,
-            None if rows is None else tuple(np.asarray(rows).tolist()))
+            None if rows is None else tuple(np.asarray(rows).tolist()),
+            not self.rows_normalized_one_pass(h, w, rows))
+
+    def rows_normalized_one_pass(self, h: int, w: int, rows=None) -> int:
+        """Descriptors of an (h, w) image that `_normalize_quantize`
+        hands to the one-pass kernel (`use_sift_normalize`) in `_batch`,
+        or with ``rows`` in `_batch_rows`: all of them or none."""
+        n = self.num_descriptors(h, w) if rows is None else len(rows)
+        return n if use_sift_normalize(n) else 0
 
     def count_rows(self, elem, rows: int):
-        """`sift.images`, `sift.descriptors`, `sift.split_products`: what
-        one dispatch of a program holding this stage extracts, from the
-        shapes."""
+        """`sift.images`, `sift.descriptors`, `sift.split_products`,
+        `sift.rows_normalized_one_pass`: what one dispatch of a program
+        holding this stage extracts, from the shapes."""
         from ...telemetry import counter
 
+        h, w = elem.shape[:2]
         counter("sift.images").inc(rows)
         counter("sift.descriptors").inc(
             rows * self.abstract_apply(elem).shape[0])
-        counter("sift.split_products").inc(
-            rows * self.split_products(*elem.shape[:2]))
+        counter("sift.split_products").inc(rows * self.split_products(h, w))
+        counter("sift.rows_normalized_one_pass").inc(
+            rows * self.rows_normalized_one_pass(h, w))
 
     def _jitted_batch(self):
         fn = self.__dict__.get("_jitted")

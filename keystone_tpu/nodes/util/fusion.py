@@ -196,10 +196,13 @@ class _SampledSIFTStage(Transformer):
 
         h, w = elem.shape[:2]
         kept = self.abstract_apply(elem).shape[0]
+        mine = self._rows(h, w)
         counter("sift.images").inc(rows)
         counter("sift.descriptors").inc(rows * kept)
         counter("sift.split_products").inc(
-            rows * self.sift.split_products(h, w, self._rows(h, w)))
+            rows * self.sift.split_products(h, w, mine))
+        counter("sift.rows_normalized_one_pass").inc(
+            rows * self.sift.rows_normalized_one_pass(h, w, mine))
         counter("sampler.rows_kept").inc(rows * kept)
 
 

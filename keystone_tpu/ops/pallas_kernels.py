@@ -16,6 +16,14 @@ Two ops dominate HBM traffic in the flagship pipelines:
    the (m, b) block ever leaves VMEM, instead of round-tripping the
    GEMM output through HBM for a separate elementwise kernel.
 
+3. **SIFT's descriptor normalization** (L2, clamp, L2, contrast
+   zeroing, quantization; reference VLFeat.cxx via SIFTExtractor.scala).
+   XLA's path concatenates the scales' descriptors and takes each row
+   sum as a product with ones that returns it in all 128 places: passes
+   through HBM as large as the descriptors. The Pallas kernel reads each
+   scale's raw rows once and writes the quantized rows once, at their
+   place in the concatenation.
+
 Every op has `*_reference` (pure jnp — the XLA path, also the CPU/test
 oracle) and a dispatcher. Kernels are runnable in interpret mode on CPU
 for unit tests.
@@ -962,3 +970,176 @@ def conv_rectify_pool_pallas(
     out = out.reshape(n_pad, r_img, k_blocks, 2, tk)[:n, :cells]
     out = out.transpose(0, 1, 3, 2, 4).reshape(n, cells, 2, k_pad)[..., :k]
     return out.reshape(n, gy, gx, 2 * k)
+
+
+# ---------------------------------------------------------------------------
+# SIFT's descriptor normalization and quantization in one pass
+# ---------------------------------------------------------------------------
+
+# Measured on one TPU v5 lite (voc_fit's traced runs, 375 x 500 images,
+# 73,866 descriptors an image, microbatches of 8): the kernel 0.134 ms
+# an image, where XLA's passes and the scales' concatenate took 0.549;
+# 0.092 ms is the raw rows' read and the quantized rows' write at
+# 819 GB/s. The compiler's schedule of a grid step of 2,048 rows in
+# slabs of 256 is about 4,200 bundles, so the kernel is about as bound
+# by the vector unit as by HBM. Quantized values agree with the
+# reference's on all but 1.8e-6 of the entries, by one unit.
+SIFT_NORMALIZE_TILE = 2048  # descriptors a grid step
+_SIFT_NORMALIZE_SLAB = 256  # descriptors a step of the loop inside one
+
+
+def use_sift_normalize(rows: int) -> bool:
+    """Trace-time gate of `sift_normalize_pallas`: on a TPU, for ``rows``
+    descriptors an image of at least one tile (SIFT's full pass). A
+    sampling pass's few hundred an image, and every other backend, take
+    the jnp form (`nodes/images/sift.py`, `_normalize_quantize_reference`)."""
+    if not _kernels_enabled():
+        return False
+    return jax.default_backend() == "tpu" and rows >= SIFT_NORMALIZE_TILE
+
+
+def _normalize_slabs(x_ref, o_ref, *, eps, clamp, contrast, slab):
+    """``x_ref``'s rows normalized and quantized into ``o_ref``, a slab
+    of rows at a time, so that the row, its two sums and the clamped row
+    stay in vector registers; nothing but the quantized row is stored.
+    Unrolled, so that one slab's loads and lane sums overlap the last
+    one's arithmetic."""
+    def step(i, carry):
+        rows = pl.ds(pl.multiple_of(i * slab, slab), slab)
+        x = x_ref[rows, :]
+        norm = jnp.sqrt(jnp.sum(x * x, axis=-1, keepdims=True)) + eps
+        y = jnp.minimum(x / norm, clamp)
+        y = y / (jnp.sqrt(jnp.sum(y * y, axis=-1, keepdims=True)) + eps)
+        y = jnp.where(jnp.broadcast_to(norm, y.shape) < contrast, 0.0, y)
+        o_ref[rows, :] = jnp.minimum(jnp.floor(512.0 * y), 255.0)
+        return carry
+
+    lax.fori_loop(0, x_ref.shape[0] // slab, step, 0, unroll=True)
+
+
+class _PartTiles(NamedTuple):
+    """Where each part's tiles lie among an image's grid steps and in
+    the output's rows: part p is steps ``first[p]`` to ``first[p] +
+    count[p] - 1``, its tile t the output's rows ``offset[p] + t *
+    tile`` on, ``tile`` of them but ``last[p]`` in its last tile."""
+    tile: int
+    first: tuple
+    count: tuple
+    offset: tuple
+    last: tuple
+
+    @property
+    def steps(self) -> int:
+        return self.first[-1] + self.count[-1]
+
+
+def _part_tiles(sizes, tile: int) -> _PartTiles:
+    count = [-(-n // tile) for n in sizes]
+    first = [0]
+    offset = [0]
+    for n, c in zip(sizes[:-1], count[:-1]):
+        first.append(first[-1] + c)
+        offset.append(offset[-1] + n)
+    last = [n - (c - 1) * tile for n, c in zip(sizes, count)]
+    return _PartTiles(tile, tuple(first), tuple(count), tuple(offset),
+                      tuple(last))
+
+
+def _sift_normalize_kernel(*refs, plan: _PartTiles, **math):
+    # refs: one (tile, d) block of each part, the output in HBM, two
+    # VMEM tiles, a DMA semaphore for each. Grid step (i, j) normalizes
+    # the tile of whichever part step j falls in into one of the two
+    # tiles, and copies it to the output rows that the part's place in
+    # the concatenation gives them, while the next step computes into
+    # the other: no concatenated copy of the raw rows is ever made.
+    *x_refs, o_hbm, buf, sem = refs
+    i, j = pl.program_id(0), pl.program_id(1)
+    steps = pl.num_programs(1)
+    g = i * steps + j
+    slot = lax.rem(g, 2)
+    for p, x_ref in enumerate(x_refs):
+        @pl.when((j >= plan.first[p]) & (j < plan.first[p] + plan.count[p]))
+        def _(x_ref=x_ref):
+            _normalize_slabs(x_ref, buf.at[slot], **math)
+
+    def copies(i_, j_, slot_, act):
+        # the copy of step (i_, j_)'s tile: its length is static in each
+        # branch, where the part and the tile are known
+        for p in range(len(x_refs)):
+            lo, hi = plan.first[p], plan.first[p] + plan.count[p] - 1
+            branches = [(plan.last[p], j_ == hi)]
+            if hi > lo:
+                branches.append((plan.tile, (j_ >= lo) & (j_ < hi)))
+            for rows, when in branches:
+                @pl.when(when)
+                def _(p=p, rows=rows):
+                    start = plan.offset[p] + (j_ - lo) * plan.tile
+                    act(pltpu.make_async_copy(
+                        buf.at[slot_, pl.ds(0, rows)],
+                        o_hbm.at[i_, pl.ds(start, rows)], sem.at[slot_]))
+
+    @pl.when(g > 0)
+    def _():
+        first = j == 0
+        copies(jnp.where(first, i - 1, i), jnp.where(first, steps - 1, j - 1),
+               1 - slot, lambda c: c.wait())
+
+    copies(i, j, slot, lambda c: c.start())
+
+    @pl.when(g == pl.num_programs(0) * steps - 1)
+    def _():
+        copies(i, j, slot, lambda c: c.wait())
+
+
+@partial(jax.jit,
+         static_argnames=("eps", "clamp", "contrast", "tile", "interpret"))
+@jax.named_scope("ks.sift.normalize")
+def sift_normalize_pallas(parts, *, eps: float, clamp: float,
+                          contrast: float, tile: int = SIFT_NORMALIZE_TILE,
+                          interpret: bool = False):
+    """Raw descriptors in parts (b, n_p, d) float32 -> their
+    concatenation along the rows (b, sum n_p, d), each row L2-normalized
+    (+eps), clamped at ``clamp``, normalized again, zeroed where the
+    first norm is under ``contrast``, and quantized to
+    min(floor(512 v), 255), in float32: vl_dsift's normalization and the
+    JNI's short quantization. Each tile of raw rows is read from HBM
+    once and its quantized rows written once, at their place in the
+    concatenation; both row sums are lane sums in float32 and never
+    leave the core. A part's last tile computes on what lies past its
+    end, row by row, and copies out its own rows alone. Jitted, so that
+    the planner's repeated abstract passes over a chain find the kernel
+    traced."""
+    parts = [p.astype(jnp.float32) for p in parts]
+    b, _, d = parts[0].shape
+    sizes = [p.shape[1] for p in parts]
+    slab = min(_SIFT_NORMALIZE_SLAB, _round_up(max(sizes), 8))
+    tile = min(_round_up(tile, slab), _round_up(max(sizes), slab))
+    plan = _part_tiles(sizes, tile)
+    # a block may not be longer than its array: a part under one tile
+    # is padded to one (what lies past its rows is never copied out)
+    parts = [jnp.pad(p, ((0, 0), (0, tile - n), (0, 0))) if n < tile else p
+             for p, n in zip(parts, sizes)]
+
+    def block(p):
+        lo, hi = plan.first[p], plan.first[p] + plan.count[p] - 1
+        # outside its own steps a part's block stays where it was or
+        # where it will start, so it is fetched once an image
+        return pl.BlockSpec(
+            (None, tile, d),
+            lambda i, j: (i, jnp.clip(j, lo, hi) - lo, 0),
+            memory_space=pltpu.VMEM)
+
+    return pl.pallas_call(
+        partial(_sift_normalize_kernel, plan=plan, eps=float(eps),
+                clamp=float(clamp), contrast=float(contrast), slab=slab),
+        grid=(b, plan.steps),
+        in_specs=[block(p) for p in range(len(parts))],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        out_shape=jax.ShapeDtypeStruct((b, sum(sizes), d), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((2, tile, d), jnp.float32),
+                        pltpu.SemaphoreType.DMA((2,))],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+        name="ks_sift_normalize",
+    )(*parts)

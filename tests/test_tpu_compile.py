@@ -502,3 +502,36 @@ def test_sift_s_full_pass_takes_its_exact_products_in_three_passes(
     assert cost(split) <= cost(six)
     assert split.memory_analysis().temp_size_in_bytes \
         <= six.memory_analysis().temp_size_in_bytes + 0.25e9
+
+
+def test_sift_s_full_pass_normalizes_its_descriptors_in_one_kernel(
+        one_chip, monkeypatch):
+    """SIFT's full pass over a microbatch of 8 VOC images (375 x 500,
+    `voc_fit`'s configuration) with the normalization's gate as the chip
+    takes it: the four scales' raw descriptors go into one Mosaic call,
+    `ks_sift_normalize`, which writes them quantized at their places
+    among the scales'. No row sums (the reference's two products with
+    ones), no concatenate and no other array of the descriptors' size
+    but the call's output and its copy to the entry's layout are left in
+    the program; the eight binning products keep their three passes."""
+    import re
+
+    from benchmark.trace_reduce import op_name
+    from keystone_tpu.nodes.images import sift
+    from keystone_tpu.ops import pallas_kernels as pk
+
+    monkeypatch.setattr(
+        sift, "use_sift_normalize",
+        lambda rows: rows >= pk.SIFT_NORMALIZE_TILE)
+    gray = _aval((8, 375, 500), jnp.float32, one_chip)
+    hlo = jax.jit(sift.SIFTExtractor(3, 4, 4, 0)._batch).lower(
+        gray).compile().as_text()
+    (call,) = [line.strip() for line in hlo.splitlines()
+               if "tpu_custom_call" in line and " = " in line]
+    assert op_name(call).startswith("ks_sift_normalize"), op_name(call)
+    assert re.search(r"custom-call\((%[\w.]+, ){3}%[\w.]+\)", call), call
+    made = re.findall(r"= f32\[8,73866,128\]\{[^}]*\} ([\w-]+)\(", hlo)
+    assert sorted(made) == ["copy", "custom-call"], made
+    precisions = re.findall(r"operand_precision=\{(\w+),(\w+)\}", hlo)
+    assert sorted(precisions) == \
+        8 * [("highest", "default")] + 8 * [("highest", "highest")]
