@@ -3,8 +3,9 @@
 Reference: nodes/images/FisherVector.scala:14-94 (Sanchez et al. closed
 form over GMM posteriors :33-53) and the native enceval variant
 (external/FisherVector.scala:17-55, EncEval.cxx `calcAndGetFVs`). The
-C++ encoder is replaced by a jitted einsum program — per image:
-posteriors (nd×k GEMM), then first/second-order aggregated gradients.
+C++ encoder is replaced by a jitted program — per image: posteriors
+(nd×k GEMM), then first/second-order aggregated gradients; on a TPU the
+posteriors and moments are one Pallas kernel (`ops.fisher_moments_pallas`).
 
 `GMMFisherVectorEstimator` keeps the reference's optimizable shape
 (FisherVector.scala:86-94 picks native iff k ≥ 32); here both routes are
@@ -18,45 +19,62 @@ import jax.numpy as jnp
 import numpy as np
 
 from ...data.dataset import HostDataset
+from ...ops.pallas_kernels import fisher_moments_pallas, use_fisher_kernel
 from ...workflow.pipeline import Estimator, OptimizableEstimator, Transformer
 from ..learning.gmm import GaussianMixtureModel, GaussianMixtureModelEstimator
+
+
+def _fisher_moments_reference(X, means, variances, weights):
+    """The jnp form of `fisher_moments_pallas`: S0 (b, k) and S1, S2
+    transposed (b, d, k) of descriptor matrices X (b, nd, d). Two
+    products an image: the posteriors' Mahalanobis form as [x², x]
+    (nd, 2d) against [1/var; -2 mu/var], and both moments as q' [x, x²];
+    XLA writes the (nd, k) log-densities to HBM and reads them back for
+    the softmax's sum, the moments and S0."""
+    d = X.shape[2]
+    moments_in = jnp.concatenate([X, X * X], axis=2)  # (b, nd, 2d)
+    inv = 1.0 / variances  # (k, d)
+    # ||x-m||²_inv = x²·inv - 2x·(m·inv) + m²·inv
+    quad = (
+        moments_in @ jnp.concatenate([-2.0 * means * inv, inv], axis=1).T
+        + jnp.sum(means * means * inv, axis=1)
+    )
+    logp = jnp.log(weights) - 0.5 * (
+        quad + jnp.sum(jnp.log(variances), axis=1)
+        + d * jnp.log(2.0 * jnp.pi))
+    q = jax.nn.softmax(logp, axis=2)  # (b, nd, k)
+    # S0_k = sum_i q_ik ; S1_k = sum_i q_ik x_i ; S2_k = sum_i q_ik x_i²
+    S = jnp.einsum("bnk,bnd->bkd", q, moments_in)
+    return (jnp.sum(q, axis=1), S[:, :, :d].transpose(0, 2, 1),
+            S[:, :, d:].transpose(0, 2, 1))
 
 
 def _fisher_batch(X, means, variances, weights):
     """FVs of a batch of descriptor matrices X (b, nd, d) → (b, d, 2k)
     (each matching the reference's DenseMatrix[d, 2k] layout,
-    FisherVector.scala:33-53). Two products an image: the posteriors'
-    Mahalanobis form as [x², x] (nd, 2d) against [1/var; -2 mu/var], and
-    both moments as q' [x, x²]: the (nd, k) arrays are written and read
-    once each where four products made it twice."""
+    FisherVector.scala:33-53), from the posterior-weighted moments:
+    on a TPU, for an image of at least one tile of descriptors and k a
+    multiple of 128 (d and k as wide as its VMEM holds), one Pallas
+    kernel that keeps the posteriors in VMEM (`use_fisher_kernel`); the
+    jnp form everywhere else."""
     with jax.named_scope("ks.fisher"), \
             jax.default_matmul_precision("highest"):
         nd, d = X.shape[1:]
-        moments_in = jnp.concatenate([X, X * X], axis=2)  # (b, nd, 2d)
-        inv = 1.0 / variances  # (k, d)
-        # ||x-m||²_inv = x²·inv - 2x·(m·inv) + m²·inv
-        quad = (
-            moments_in @ jnp.concatenate([-2.0 * means * inv, inv], axis=1).T
-            + jnp.sum(means * means * inv, axis=1)
-        )
-        logp = jnp.log(weights) - 0.5 * (
-            quad + jnp.sum(jnp.log(variances), axis=1)
-            + d * jnp.log(2.0 * jnp.pi))
-        q = jax.nn.softmax(logp, axis=2)  # (b, nd, k)
-        sigma = jnp.sqrt(variances)  # (k, d)
-        # S0_k = sum_i q_ik ; S1_k = sum_i q_ik x_i ; S2_k = sum_i q_ik x_i²
-        S0 = jnp.sum(q, axis=1)[:, :, None]  # (b, k, 1)
-        S = jnp.einsum("bnk,bnd->bkd", q, moments_in)
-        S1, S2 = S[:, :, :d], S[:, :, d:]
-        w = weights[:, None]
+        if use_fisher_kernel(nd, d, means.shape[0]):
+            S0, S1, S2 = fisher_moments_pallas(X, means, variances, weights)
+        else:
+            S0, S1, S2 = _fisher_moments_reference(
+                X, means, variances, weights)
+        S0 = S0[:, None, :]  # (b, 1, k)
+        means, variances = means.T, variances.T  # (d, k)
+        sigma = jnp.sqrt(variances)
         # gradient wrt means:   (S1 - mu*S0) / (sigma * sqrt(w) * nd)
-        g_mu = (S1 - means * S0) / (sigma * jnp.sqrt(w) * nd)
+        g_mu = (S1 - means * S0) / (sigma * jnp.sqrt(weights) * nd)
         # gradient wrt sigmas:  (S2 - 2 mu S1 + (mu²-sigma²) S0) / (sigma² sqrt(2w) nd)
         g_sig = (
             S2 - 2.0 * means * S1 + (means**2 - variances) * S0
-        ) / (variances * jnp.sqrt(2.0 * w) * nd)
-        return jnp.concatenate(
-            [g_mu.transpose(0, 2, 1), g_sig.transpose(0, 2, 1)], axis=2)
+        ) / (variances * jnp.sqrt(2.0 * weights) * nd)
+        return jnp.concatenate([g_mu, g_sig], axis=2)
 
 
 @jax.jit
@@ -98,10 +116,21 @@ class FisherVector(Transformer):
         return (("FisherVector",), (g.means, g.variances, g.weights),
                 lambda p, xb: _fisher_batch(xb, *p))
 
+    def rows_one_pass(self, nd: int) -> int:
+        """Descriptors of an nd-descriptor image that `_fisher_batch`
+        hands to the one-pass kernel (`use_fisher_kernel`): all of them
+        or none."""
+        d, k = self.gmm.means.shape[1], self.gmm.k
+        return nd if use_fisher_kernel(nd, d, k) else 0
+
     def count_rows(self, elem, rows: int):
+        """`fisher.images` and `fisher.rows_one_pass`: what one dispatch
+        of a program holding this stage encodes, from the shapes."""
         from ...telemetry import counter
 
         counter("fisher.images").inc(rows)
+        counter("fisher.rows_one_pass").inc(
+            rows * self.rows_one_pass(int(elem.shape[0])))
 
     def apply_batch(self, data):
         if isinstance(data, HostDataset):

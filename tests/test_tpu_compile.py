@@ -417,16 +417,15 @@ def test_the_kernel_apply_fits_the_chip_at_cifar_kernel_fit_s_size(
         assert _metric_pattern(metric).search(_module_name(compiled))
 
 
-def test_voc_fit_s_fisher_program_fits_beside_what_a_fit_keeps(one_chip, topo):
+VOC_N = 5011  # voc_fit's training images
+
+
+def _voc_fisher_program(one_chip, topo, chunk=None):
     """`voc_fit`'s heaviest program as the optimizer builds it: SIFT, the
     PCA projection, the Fisher encoding and the three normalizations
     over the 5,011 cached grayscale images (375 x 500), one fused
-    program at the microbatch the rule derives (8 images: 605 MB of
-    posteriors). Its output is the (5,011, 40,960) features; its
-    temporaries stay a microbatch's (under 1.5 GB where a copy of the
-    cached images in a layout of the compiler's liking was 3.76 GB more:
-    the v5e keeps (5011, 375, 500) with the images on the lanes), so the
-    images, the samples, the features and the program fit 16 GB."""
+    program, compiled at ``chunk`` images a microbatch, or at the one
+    the rule derives. (derived chunk, compiled program)."""
     from keystone_tpu.nodes.images.fisher_vector import FisherVector
     from keystone_tpu.nodes.images.sift import SIFTExtractor
     from keystone_tpu.nodes.learning.gmm import GaussianMixtureModel
@@ -436,7 +435,7 @@ def test_voc_fit_s_fisher_program_fits_beside_what_a_fit_keeps(one_chip, topo):
     from keystone_tpu.nodes.util.fusion import FusedBatchTransformer
     from keystone_tpu.workflow.env import ExecutionConfig, set_execution_config
 
-    n, h, w = 5011, 375, 500
+    n, h, w = VOC_N, 375, 500
     pca = PCATransformer.__new__(PCATransformer)
     pca.components = _aval((128, 80), jnp.float32, one_chip)
     gmm = GaussianMixtureModel.__new__(GaussianMixtureModel)
@@ -449,21 +448,90 @@ def test_voc_fit_s_fisher_program_fits_beside_what_a_fit_keeps(one_chip, topo):
     decomposition = statics, flat, treedef, fns = op._decompose()
     set_execution_config(ExecutionConfig(hbm_budget_bytes=16 << 30))
     try:
-        chunk = op._chunk_rows(decomposition, (n, h, w), "float32", n)
+        derived = op._chunk_rows(decomposition, (n, h, w), "float32", n)
     finally:
         set_execution_config(None)
-    assert chunk == 8
     mesh = Mesh(np.asarray(topo.devices[:1]), ("data",))
     program = op._build_program(mesh, 1, n, treedef, fns, statics=statics,
-                                chunk=chunk)
-    compiled = program.lower(
+                                chunk=chunk or derived)
+    return derived, program.lower(
         flat, _aval((n, h, w), jnp.float32, one_chip),
         _aval((n,), jnp.bool_, one_chip)).compile()
+
+
+def test_voc_fit_s_fisher_program_fits_beside_what_a_fit_keeps(one_chip, topo):
+    """`voc_fit`'s heaviest program (`_voc_fisher_program`) at the
+    microbatch the rule derives (8 images: 605 MB of posteriors). Its
+    output is the (5,011, 40,960) features; its temporaries stay a
+    microbatch's (under 1.5 GB where a copy of the cached images in a
+    layout of the compiler's liking was 3.76 GB more: the v5e keeps
+    (5011, 375, 500) with the images on the lanes), so the images, the
+    samples, the features and the program fit 16 GB."""
+    chunk, compiled = _voc_fisher_program(one_chip, topo)
+    assert chunk == 8
     memory = compiled.memory_analysis()
-    assert memory.output_size_in_bytes >= 4 * n * 40960
+    assert memory.output_size_in_bytes >= 4 * VOC_N * 40960
     assert memory.temp_size_in_bytes < 1.5e9
     hlo = compiled.as_text()
     assert "ks.sift" in hlo and "ks.pca.apply" in hlo and "ks.fisher" in hlo
+
+
+def test_voc_fit_s_fisher_program_encodes_in_one_kernel(
+        one_chip, topo, monkeypatch):
+    """The same program with the gates as the chip takes them: SIFT's
+    normalization and the Fisher encoding each one Mosaic call. At the
+    parent's microbatch of 8 no (8, 73,866, 256) float32 array is left
+    and the temporaries fall from the parent's 1,127,940,096 bytes to
+    725,953,536; the encoding's input is the PCA product's output laid
+    out (8, 80, 73,866), with no copy in front of the call. The rule
+    then derives 16 images a microbatch (the largest value a row makes
+    is SIFT's (73,866, 128) descriptors), and that program fits too."""
+    import re
+
+    from benchmark.trace_reduce import op_name
+    from keystone_tpu.nodes.images import fisher_vector, sift
+    from keystone_tpu.nodes.util import fusion
+    from keystone_tpu.ops import pallas_kernels as pk
+
+    # the derived microbatch is remembered by the chain's structure, not
+    # by the gates: the test above may have left the parent form's
+    monkeypatch.setattr(fusion, "_MICROBATCH_CACHE", {})
+    monkeypatch.setattr(
+        sift, "use_sift_normalize",
+        lambda rows: rows >= pk.SIFT_NORMALIZE_TILE)
+    monkeypatch.setattr(
+        fisher_vector, "use_fisher_kernel",
+        lambda nd, d, k: 0 < pk.fisher_tile(d, k) <= nd)
+    chunk, compiled = _voc_fisher_program(one_chip, topo, chunk=8)
+    assert chunk == 16
+    hlo = compiled.as_text()
+    calls = sorted(op_name(line.strip()) for line in hlo.splitlines()
+                   if "tpu_custom_call" in line and " = " in line)
+    assert [c.split(".")[0] for c in calls] == \
+        ["ks_fisher", "ks_sift_normalize"], calls
+    assert not re.search(r"f32\[8,73866,256\]", hlo)
+    made = re.findall(r"= f32\[8,80,73866\]\{[^}]*\} ([\w-]+)\(", hlo)
+    assert made and not {"copy", "transpose"} & set(made), made
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 1_127_940_096
+    assert temp < 0.75e9
+    memory = _voc_fisher_program(one_chip, topo)[1].memory_analysis()
+    assert memory.temp_size_in_bytes < 1.5e9
+
+
+@pytest.mark.parametrize("d,k", [(128, 128), (128, 1024)])
+def test_the_fisher_kernel_compiles_at_the_widest_its_gate_takes(
+        one_chip, d, k):
+    """`fisher_moments_pallas` at its gate's widest widths (d = 128, k
+    up to 1,024), at the tile `fisher_tile` gives them, over a masked
+    last tile: the step's VMEM is what the tile rule is for."""
+    from keystone_tpu.ops import pallas_kernels as pk
+
+    tile = pk.fisher_tile(d, k)
+    f32 = lambda *shape: _aval(shape, jnp.float32, one_chip)
+    compiled = pk.fisher_moments_pallas.lower(
+        f32(2, 3 * tile + 5, d), f32(k, d), f32(k, d), f32(k)).compile()
+    assert "ks_fisher" in compiled.as_text()
 
 
 def test_sift_s_full_pass_takes_its_exact_products_in_three_passes(
