@@ -15,7 +15,7 @@ a compiler-level safety pass in the spirit of arXiv 2206.14148):
          (or promised through an estimator's ``fusable_fit``) whose
          fused-program key path is id-keyed ("opaque") — detected by
          running the SAME decomposition the fusion builder uses
-         (`nodes.util.fusion._stage_fuse`) and inspecting the static
+         (`nodes.util.fusion.stage_fuse`) and inspecting the static
          key, not by naming convention. Opaque keys mean every fused
          program containing the stage is cached per-instance and
          re-traced on every rebuilt pipeline — the PR-6 silent-retrace
@@ -132,8 +132,7 @@ def _probe_factories() -> Dict[str, Any]:
             np.ones((2, 3, 3, 3), np.float32), 8, 8, 3), [(8, 8, 3)]
 
     def conv_rect_pool():
-        from ..nodes.images.core import Convolver
-        from ..nodes.util.fusion import _ConvRectifyPoolStage
+        from ..nodes.images.core import Convolver, _ConvRectifyPoolStage
 
         c = Convolver(np.ones((2, 3, 3, 3), np.float32), 8, 8, 3)
         return _ConvRectifyPoolStage(c, 0.0, 0.0, 2, 2), [(8, 8, 3)]
@@ -151,7 +150,7 @@ def _probe_factories() -> Dict[str, Any]:
         "Convolver": conv,
         "_ConvRectifyPoolStage": conv_rect_pool,
         "_RectifyPoolStage": lambda: (
-            _cls("keystone_tpu.nodes.util.fusion", "_RectifyPoolStage")(
+            _cls("keystone_tpu.nodes.images.core", "_RectifyPoolStage")(
                 0.0, 0.0, 2, 2), [(8, 8, 2)]),
         "Pooler": lambda: (
             _cls("keystone_tpu.nodes.images.core", "Pooler")(2, 2),
@@ -198,8 +197,8 @@ def _probe_factories() -> Dict[str, Any]:
                       "SignedHellingerMapper")()]), [(6,)]),
         "FusedBatchTransformer": fused_chain("FusedBatchTransformer"),
         "MegafusedBatchTransformer": fused_chain("MegafusedBatchTransformer"),
-        "_GatherConcatStage": lambda: (
-            _cls("keystone_tpu.nodes.util.fusion", "_GatherConcatStage")(
+        "GatherConcatStage": lambda: (
+            _cls("keystone_tpu.nodes.util.fusion", "GatherConcatStage")(
                 [_cls("keystone_tpu.nodes.stats.normalization",
                       "SignedHellingerMapper")()]), [(6,)]),
     }
@@ -384,10 +383,10 @@ def _decompose(op) -> Tuple[Optional[Any], Any, Any, Optional[str]]:
     ``(None, None, None, reason)`` when the decomposition itself fails.
     Computed once per audit and shared by KP501 (key inspection) and
     KP502 (distributivity trace)."""
-    from ..nodes.util.fusion import _stage_fuse
+    from ..nodes.util.fusion import stage_fuse
 
     try:
-        key, params, fn = _stage_fuse(op)
+        key, params, fn = stage_fuse(op)
         return key, params, fn, None
     except Exception as e:
         return None, None, None, f"{type(e).__name__}: {e}"
@@ -395,7 +394,7 @@ def _decompose(op) -> Tuple[Optional[Any], Any, Any, Optional[str]]:
 
 def _kp501_instance(op, label: str, decomp=None,
                     vertex=None) -> List[Diagnostic]:
-    from ..nodes.util.fusion import _contains_opaque
+    from ..nodes.util.fusion import contains_opaque
 
     if not getattr(op, "fusable", False):
         return []
@@ -406,7 +405,7 @@ def _kp501_instance(op, label: str, decomp=None,
             f"fusable stage's fuse() decomposition failed ({err}); fused "
             "programs containing it cannot build",
             vertex=vertex, label=label)]
-    if _contains_opaque(key):
+    if contains_opaque(key):
         how = ("declares fusable but implements no fuse() decomposition"
                if not _defines_fuse(type(op))
                else "fuse() returns an id-keyed (opaque) component")
@@ -567,15 +566,15 @@ def _mask_aware_fuse(op) -> bool:
     """A fuse() decomposition carrying the mask-aware sentinel threads
     the padded-row mask through its inner stages by construction — it
     cannot corrupt padded rows, so KP504 does not apply (the fusion
-    machinery classes: FusedBatchTransformer, _GatherConcatStage)."""
+    machinery classes: FusedBatchTransformer, GatherConcatStage)."""
     f = getattr(op, "fuse", None)
     if f is None:
         return False
     try:
-        from ..nodes.util.fusion import _MASK_AWARE
+        from ..nodes.util.fusion import MASK_AWARE
 
         res = f()
-        return len(res) == 4 and res[3] == _MASK_AWARE
+        return len(res) == 4 and res[3] == MASK_AWARE
     except Exception:
         return False
 

@@ -202,7 +202,7 @@ def check_vmem_budget(bn, io_bytes, inter_bytes, param_bytes, ladder, *,
 def check_mask_discipline(declared_positions, consumed_positions,
                           streams_mask) -> List[str]:
     """KP1004: every `fuse_masks_output` stage (declared via its
-    `_stage_fuse` static's ``(key, "masked")`` wrapping) must re-zero
+    `stage_fuse` static's ``(key, "masked")`` wrapping) must re-zero
     padded rows at its ORIGINAL chain position inside the kernel body,
     from a streamed mask operand — a mask applied late, early, or not
     at all lets padded garbage flow through downstream reductions."""
@@ -440,7 +440,7 @@ def verify_lowering(stages, item_shape, dtype=None, *, vertex=None,
     """
     import jax.numpy as jnp
 
-    from ..nodes.util.fusion import _peephole, _stage_fuse
+    from ..nodes.util.fusion import fused_stages, stage_fuse
     from ..ops import chain_kernels as ck
 
     dtype = jnp.float32 if dtype is None else dtype
@@ -458,7 +458,7 @@ def verify_lowering(stages, item_shape, dtype=None, *, vertex=None,
         proof["rules"][rule] = f"REFUTED: {msg}"
 
     try:
-        fused = [_stage_fuse(s) for s in _peephole(list(stages))]
+        fused = [stage_fuse(s) for s in fused_stages(stages)]
     except Exception as e:
         err("KP1005", f"stage decomposition failed: "
                       f"{type(e).__name__}: {e}")
@@ -639,14 +639,13 @@ def _element_at_slice(graph, specs, cand):
         return None
     elem = spec.element
     if cand.get("kind") == "fused_trail" and cand.get("stage_slice"):
-        from ..nodes.util.fusion import _peephole
+        from ..nodes.util.fusion import fused_stages
         from ..workflow.fusion_rule import FusedChainOperator
 
         op = graph.get_operator(vid)
-        stage_list = (list(op.stage_specs)
-                      if isinstance(op, FusedChainOperator)
-                      else list(op.stages))
-        stages = list(_peephole(stage_list))
+        stage_list = (op.stage_specs if isinstance(op, FusedChainOperator)
+                      else op.stages)
+        stages = fused_stages(stage_list)
         i, _ = cand["stage_slice"]
         for s in stages[:i]:
             elem = jax.eval_shape(

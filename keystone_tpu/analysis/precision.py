@@ -637,20 +637,18 @@ def plan_stage_precision(
     program operator: ``(storage_names, savings_bytes, menu)`` where
     ``storage_names[i]`` is the dtype name stage ``i``'s output is cast
     to inside the program (None = untouched), aligned with the
-    operator's PEEPHOLED stage list (the list `_build_program`
+    operator's `fused_stages` list (the list `_build_program`
     executes), and ``menu`` is the `stage_policy_menu` of priced
     candidate runs the chain DP scored (kept and rejected — the
     decision ledger's alternatives). The program's final output
     boundary always stays untouched so downstream consumers see
     exactly the PR-9 dtypes. Returns None when the trail cannot be
     priced (unknown elements)."""
-    from ..nodes.util.fusion import _peephole
+    from ..nodes.util.fusion import fused_stages
     from ..workflow.fusion_rule import _FitSlot
 
-    stage_specs = getattr(op, "stage_specs", None)
-    if stage_specs is None:
-        stage_specs = list(getattr(op, "stages", []))
-    stages = _peephole(stage_specs)
+    stages = fused_stages(getattr(op, "stage_specs", None)
+                          or getattr(op, "stages", []))
     deps = graph.get_dependencies(vid)
     if not deps:
         return None

@@ -65,7 +65,7 @@ Lints (all advisory — the roofline informs, placement/precision decide):
     below the dispatch/loop overhead floor cannot amortize its trips —
     raise ``chunk_size``.
   - **KP805** (INFO): a KP801 candidate that actually LOWERS — its
-    `_stage_fuse` statics match a chain-kernel family in
+    `stage_fuse` statics match a chain-kernel family in
     `ops/chain_kernels.py` — and whose one-HBM-pass kernel pricing
     beats the XLA chain's predicted seconds; the unified planner's
     kernel axis prices the scored pair and records the decision.
@@ -452,8 +452,8 @@ def _stage_trail(graph: Graph, vid: NodeId, op, specs: Dict[GraphId, Any]):
     ``[(label, in_elem, out_elem, flops_per_item, movement_per_item,
     source)]``, or None when nothing can be priced.
 
-    A `FusedChainOperator`/`MegafusedPlanOperator` walks its PEEPHOLED
-    stage list (the list `_build_program` executes) with `_FitSlot`s
+    A `FusedChainOperator`/`MegafusedPlanOperator` walks its
+    `fused_stages` list (the list `_build_program` executes) with `_FitSlot`s
     modeled as dense maps; a `FusedBatchTransformer` walks its fitted
     ``stages`` the same way; a `DelegatingOperator` is one modeled
     dense map; a plain transformer with a traceable per-item body is
@@ -467,7 +467,7 @@ def _stage_trail(graph: Graph, vid: NodeId, op, specs: Dict[GraphId, Any]):
         return None
 
     if isinstance(op, (FusedChainOperator, FusedBatchTransformer)):
-        from ..nodes.util.fusion import _peephole
+        from ..nodes.util.fusion import fused_stages
 
         data_spec = specs.get(deps[-1])
         if not isinstance(data_spec, DataSpec) or not is_known(
@@ -476,15 +476,14 @@ def _stage_trail(graph: Graph, vid: NodeId, op, specs: Dict[GraphId, Any]):
         t_specs = [specs.get(d) for d in deps[:-1]]
         elem = data_spec.element
         trail = []
-        stage_list = (list(op.stage_specs)
-                      if isinstance(op, FusedChainOperator)
-                      else list(op.stages))
+        stage_list = (op.stage_specs if isinstance(op, FusedChainOperator)
+                      else op.stages)
         # any unpriceable internal stage makes the WHOLE vertex
         # unpriced: a partial prefix silently recorded as the full
         # stage would undercount KP803 plan seconds, corrupt KP801
         # boundary bytes, and hand reconcile a prediction covering
         # less work than the span it joins (spurious residual)
-        for s in _peephole(stage_list):
+        for s in fused_stages(stage_list):
             if not is_known(elem):
                 return None
             if isinstance(s, _FitSlot):
@@ -874,21 +873,20 @@ def _candidate_stage_objects(graph: Graph, cand: Dict[str, Any]):
     """The actual stage objects a KP801 candidate's kernel would
     replace, or None when the chain has no static fuse bodies
     (`_FitSlot`s — the decomposition depends on a fit that has not
-    happened). A fused_trail candidate slices the operator's PEEPHOLED
-    stage list (the list `_build_program` executes, which the trail
+    happened). A fused_trail candidate slices the operator's
+    `fused_stages` list (the list `_build_program` executes, which the trail
     indices address); a graph_chain candidate concatenates its member
     stages — the list the fusion rules WILL collapse."""
-    from ..nodes.util.fusion import FusedBatchTransformer, _peephole
+    from ..nodes.util.fusion import FusedBatchTransformer, fused_stages
     from ..workflow.fusion_rule import FusedChainOperator, _FitSlot
 
     stages: List[Any] = []
     if cand["kind"] == "fused_trail":
         op = graph.get_operator(cand["vertices"][0])
-        stage_list = (list(op.stage_specs)
-                      if isinstance(op, FusedChainOperator)
-                      else list(op.stages))
+        stage_list = (op.stage_specs if isinstance(op, FusedChainOperator)
+                      else op.stages)
         i, j = cand["stage_slice"]
-        stages = list(_peephole(stage_list))[i:j]
+        stages = fused_stages(stage_list)[i:j]
     else:
         for vid in cand["vertices"]:
             op = graph.get_operator(vid)
@@ -909,7 +907,7 @@ def _annotate_kernel_lowering(graph: Graph, cand: Dict[str, Any],
     """Attach the chain-kernel verdict to one KP801 candidate:
 
     - ``lowerable``: the `ops.chain_kernels.lowerability` verdict on
-      the candidate's `_stage_fuse` statics — family when it lowers,
+      the candidate's `stage_fuse` statics — family when it lowers,
       the blocking stages (and any NAMED suppression) when it doesn't;
     - ``kernel_seconds``: the kernel side of the planner's
       kernel-vs-XLA axis — ONE HBM pass of in+out bytes (the chain's

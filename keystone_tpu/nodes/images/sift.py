@@ -426,6 +426,14 @@ class SIFTExtractor(SIFTExtractorInterface):
         return (("SIFT", self.step, self.bin_size, self.num_scales,
                  self.scale_step), (), lambda p, xb: self._batch(xb))
 
+    def absorb(self, followers):
+        """A `ColumnSampler` behind it: the sampled stage."""
+        from ..stats.normalization import ColumnSampler
+
+        if followers and type(followers[0]) is ColumnSampler:
+            return _SampledSIFTStage(self, followers[0]), 1
+        return None
+
     def split_products(self, h: int, w: int, rows=None) -> int:
         """Products an (h, w) image takes in the three-pass form
         (`_exact_operand_product`) in `_batch`, or with ``rows`` in
@@ -443,19 +451,21 @@ class SIFTExtractor(SIFTExtractorInterface):
         n = self.num_descriptors(h, w) if rows is None else len(rows)
         return n if use_sift_normalize(n) else 0
 
-    def count_rows(self, elem, rows: int):
+    def count_rows(self, elem, rows: int, mine=None):
         """`sift.images`, `sift.descriptors`, `sift.split_products`,
         `sift.rows_normalized_one_pass`: what one dispatch of a program
-        holding this stage extracts, from the shapes."""
+        holding this stage extracts, from the shapes; ``mine`` numbers
+        the descriptors a sampler behind it keeps (`_SampledSIFTStage`)."""
         from ...telemetry import counter
 
         h, w = elem.shape[:2]
+        kept = self.num_descriptors(h, w) if mine is None else len(mine)
         counter("sift.images").inc(rows)
-        counter("sift.descriptors").inc(
-            rows * self.abstract_apply(elem).shape[0])
-        counter("sift.split_products").inc(rows * self.split_products(h, w))
+        counter("sift.descriptors").inc(rows * kept)
+        counter("sift.split_products").inc(
+            rows * self.split_products(h, w, mine))
         counter("sift.rows_normalized_one_pass").inc(
-            rows * self.rows_normalized_one_pass(h, w))
+            rows * self.rows_normalized_one_pass(h, w, mine))
 
     def _jitted_batch(self):
         fn = self.__dict__.get("_jitted")
@@ -484,3 +494,60 @@ class SIFTExtractor(SIFTExtractorInterface):
 
         return batching.map_host_batched_stream(
             data.items, self._jitted_batch())
+
+
+class _SampledSIFTStage(Transformer):
+    """SIFTExtractor >> ColumnSampler as one stage
+    (`SIFTExtractor.absorb`): the rows the sampler keeps are known
+    before a descriptor is made (a seeded choice by the matrix's
+    height), so only those frames' bins are gathered from the
+    aggregated maps, normalized and quantized: a sampling pass over a
+    set of images costs the stencils and never holds, transposes or
+    normalizes the 73,866 descriptors an image it would throw away. The
+    values are the unfused pair's."""
+
+    fusable = True
+    chunkable = True
+    precision_tolerance = "exact"  # SIFT's
+
+    def __init__(self, sift, sampler):
+        self.sift = sift
+        self.sampler = sampler
+
+    @property
+    def label(self) -> str:
+        return f"{self.sift.label}>>{self.sampler.label}"
+
+    def abstract_apply(self, elem):
+        return self.sampler.abstract_apply(self.sift.abstract_apply(elem))
+
+    def apply(self, x):
+        return self.sampler.apply(self.sift.apply(x))
+
+    def _rows(self, h: int, w: int):
+        """The descriptors the sampler keeps, or None where it keeps all
+        and the stage is `SIFTExtractor._batch`."""
+        from ..stats.normalization import sample_rows
+
+        nd, num = self.sift.num_descriptors(h, w), self.sampler.num_cols
+        return None if nd <= num else sample_rows(nd, num, self.sampler.seed)
+
+    def fuse(self):
+        sift = self.sift
+
+        def fn(p, xb):
+            rows = self._rows(*xb.shape[1:3])
+            if rows is None:
+                return sift._batch(xb)
+            with jax.named_scope("ks.sift.sample"):
+                return sift._batch_rows(xb, rows)
+
+        return (("SampledSIFT", sift.fuse()[0], self.sampler.num_cols,
+                 self.sampler.seed), (), fn)
+
+    def count_rows(self, elem, rows: int):
+        from ...telemetry import counter
+
+        self.sift.count_rows(elem, rows, self._rows(*elem.shape[:2]))
+        counter("sampler.rows_kept").inc(
+            rows * self.abstract_apply(elem).shape[0])

@@ -155,6 +155,12 @@ class ColumnSampler(Transformer):
 
         return (("ColumnSampler", num, seed), (), fn)
 
+    def moves_ahead_of(self, stage) -> bool:
+        """Ahead of the PCA projection, a map of each row."""
+        from ..learning.pca import PCATransformer
+
+        return isinstance(stage, PCATransformer)
+
     def count_rows(self, elem, rows: int):
         from ...telemetry import counter
 

@@ -52,213 +52,23 @@ def _stage_batch_fn(stage: Transformer):
     return jax.vmap(stage.apply)
 
 
-class _RectifyPoolStage(Transformer):
-    """Peephole-fused SymmetricRectifier >> Pooler(sum): lowers to the
-    Pallas one-pass kernel on TPU (ops/pallas_kernels.py), XLA elsewhere."""
-
-    fusable = True
-    precision_tolerance = "tolerant"  # both fused members are tolerant
-
-    def __init__(self, alpha: float, max_val: float, pool: int, stride: int):
-        self.alpha = alpha
-        self.max_val = max_val
-        self.pool = pool
-        self.stride = stride
-
-    def apply(self, x):
-        from ...ops import rectify_pool_reference
-
-        return rectify_pool_reference(
-            x[None], self.alpha, self.max_val, self.pool, self.stride
-        )[0]
-
-    def fuse(self):
-        from ...ops import use_rectify_pallas
-
-        a, mv, p, s = self.alpha, self.max_val, self.pool, self.stride
-        pal = use_rectify_pallas()  # part of the key: flag flips must
-        # not reuse the other path's cached program
-
-        def fn(params, x):
-            # the dispatcher picks the VMEM-safe block size
-            from ...ops import rectify_pool, rectify_pool_reference
-
-            if pal:
-                return rectify_pool(x, a, mv, p, s)
-            return rectify_pool_reference(x, a, mv, p, s)
-
-        return (("RectifyPool", a, mv, p, s, pal), (), fn)
-
-
-class _ConvRectifyPoolStage(Transformer):
-    """Peephole-fused Convolver >> SymmetricRectifier >> Pooler(sum):
-    the Pallas one-pass kernel keeps the conv output and the
-    channel-doubled rectified tensor in VMEM, writing only the pooled
-    grid, at any filter count (a bank too wide for VMEM runs as filter
-    blocks). ops/pallas_kernels.py; measured 3.7x the XLA path at
-    10,000 filters on a TPU v5 lite, kernel alone (PERF.md section 6,
-    PR 27). Default-on for TPU; XLA elsewhere."""
-
-    fusable = True
-    precision_tolerance = "tolerant"  # all three fused members are
-
-    def __init__(self, conv, alpha: float, max_val: float, pool: int, stride: int):
-        self.alpha = alpha
-        self.max_val = max_val
-        self.pool = pool
-        self.stride = stride
-        self.patch = conv.patch
-        self.normalize = conv.normalize_patches
-        self.kernel_hwio = conv.kernel
-        self.colsum = conv.colsum
-        self.bias = conv.bias
-
-    def apply(self, x):
-        from ...ops import conv_rectify_pool_reference
-
-        return conv_rectify_pool_reference(
-            x[None], self.kernel_hwio, self.colsum, self.bias,
-            self.alpha, self.max_val, self.pool, self.stride, self.normalize,
-        )[0]
-
-    def fuse(self):
-        from ...ops import use_fused_conv
-
-        a, mv, p, s = self.alpha, self.max_val, self.pool, self.stride
-        normalize = self.normalize
-        fused = use_fused_conv()  # part of the key (see _RectifyPoolStage)
-
-        def fn(params, x):
-            (kern, cs, bs) = params
-            from ...ops import conv_rectify_pool
-
-            return conv_rectify_pool(
-                x, kern, cs, bs, a, mv, p, s, normalize
-            )
-
-        return (
-            ("ConvRectifyPool", a, mv, p, s, self.patch, normalize, fused),
-            (self.kernel_hwio, self.colsum, self.bias),
-            fn,
-        )
-
-
-class _SampledSIFTStage(Transformer):
-    """Peephole-fused SIFTExtractor >> ColumnSampler: the rows the
-    sampler keeps are known before a descriptor is made (a seeded choice
-    by the matrix's height), so only those frames' bins are gathered
-    from the aggregated maps, normalized and quantized: a sampling pass
-    over a set of images costs the stencils and never holds, transposes
-    or normalizes the 73,866 descriptors an image it would throw away.
-    The values are the unfused pair's."""
-
-    fusable = True
-    chunkable = True
-    precision_tolerance = "exact"  # SIFT's
-
-    def __init__(self, sift, sampler):
-        self.sift = sift
-        self.sampler = sampler
-
-    @property
-    def label(self) -> str:
-        return f"{self.sift.label}>>{self.sampler.label}"
-
-    def abstract_apply(self, elem):
-        return self.sampler.abstract_apply(self.sift.abstract_apply(elem))
-
-    def apply(self, x):
-        return self.sampler.apply(self.sift.apply(x))
-
-    def _rows(self, h: int, w: int):
-        """The descriptors the sampler keeps, or None where it keeps all
-        and the stage is `SIFTExtractor._batch`."""
-        from ..stats.normalization import sample_rows
-
-        nd, num = self.sift.num_descriptors(h, w), self.sampler.num_cols
-        return None if nd <= num else sample_rows(nd, num, self.sampler.seed)
-
-    def fuse(self):
-        sift = self.sift
-
-        def fn(p, xb):
-            rows = self._rows(*xb.shape[1:3])
-            if rows is None:
-                return sift._batch(xb)
-            with jax.named_scope("ks.sift.sample"):
-                return sift._batch_rows(xb, rows)
-
-        return (("SampledSIFT", sift.fuse()[0], self.sampler.num_cols,
-                 self.sampler.seed), (), fn)
-
-    def count_rows(self, elem, rows: int):
-        from ...telemetry import counter
-
-        h, w = elem.shape[:2]
-        kept = self.abstract_apply(elem).shape[0]
-        mine = self._rows(h, w)
-        counter("sift.images").inc(rows)
-        counter("sift.descriptors").inc(rows * kept)
-        counter("sift.split_products").inc(
-            rows * self.sift.split_products(h, w, mine))
-        counter("sift.rows_normalized_one_pass").inc(
-            rows * self.sift.rows_normalized_one_pass(h, w, mine))
-        counter("sampler.rows_kept").inc(rows * kept)
-
-
-def _peephole(stages):
-    """Merge adjacent (Convolver?, SymmetricRectifier, Pooler[sum])
-    stages so the conv output and the channel-doubled rectified tensor
-    never materialize (see ops/); move a ColumnSampler in front of the
-    PCA projection it follows (a choice of rows commutes with a map of
-    each row) and merge it into the SIFTExtractor it then follows
-    (`_SampledSIFTStage`)."""
-    from ..images.core import Convolver, Pooler, SymmetricRectifier
-    from ..images.sift import SIFTExtractor
-    from ..learning.pca import PCATransformer
-    from ..stats.normalization import ColumnSampler
-
+def fused_stages(stages) -> list:
+    """The stages a fused chain of ``stages`` runs: each row selection
+    moved ahead where it may (`Transformer.moves_ahead_of`), then each
+    stage merged with the followers it absorbs (`Transformer.absorb`).
+    The program's functions and the planners' casts and slices index it."""
     stages = list(stages)
     for i in range(len(stages) - 1):
-        if isinstance(stages[i], PCATransformer) \
-                and type(stages[i + 1]) is ColumnSampler:
+        ahead = getattr(stages[i + 1], "moves_ahead_of", None)
+        if ahead is not None and ahead(stages[i]):
             stages[i], stages[i + 1] = stages[i + 1], stages[i]
     out, i = [], 0
     while i < len(stages):
-        s = stages[i]
-        if (
-            type(s) is SIFTExtractor
-            and i + 1 < len(stages)
-            and type(stages[i + 1]) is ColumnSampler
-        ):
-            out.append(_SampledSIFTStage(s, stages[i + 1]))
-            i += 2
-        elif (
-            isinstance(s, Convolver)
-            and i + 2 < len(stages)
-            and isinstance(stages[i + 1], SymmetricRectifier)
-            and isinstance(stages[i + 2], Pooler)
-            and stages[i + 2].pool_fn == "sum"
-            and stages[i + 2].pixel_fn is None
-        ):
-            r, p = stages[i + 1], stages[i + 2]
-            out.append(
-                _ConvRectifyPoolStage(s, r.alpha, r.max_val, p.pool_size, p.stride)
-            )
-            i += 3
-        elif (
-            isinstance(s, SymmetricRectifier)
-            and i + 1 < len(stages)
-            and isinstance(stages[i + 1], Pooler)
-            and stages[i + 1].pool_fn == "sum"
-            and stages[i + 1].pixel_fn is None
-        ):
-            p = stages[i + 1]
-            out.append(_RectifyPoolStage(s.alpha, s.max_val, p.pool_size, p.stride))
-            i += 2
-        else:
-            out.append(s)
-            i += 1
+        absorb = getattr(stages[i], "absorb", None)
+        merged = absorb(stages[i + 1:]) if absorb is not None else None
+        stage, taken = merged if merged is not None else (stages[i], 0)
+        out.append(stage)
+        i += 1 + taken
     return out
 
 
@@ -269,11 +79,11 @@ def _mask_rows(y, mb):
 
 #: sentinel 4th element marking a fuse() whose fn already takes
 #: (params, xb, mask_b) — produced by composing decompositions
-#: (`FusedBatchTransformer.fuse`, `_GatherConcatStage.fuse`).
-_MASK_AWARE = "mask-aware"
+#: (`FusedBatchTransformer.fuse`, `GatherConcatStage.fuse`).
+MASK_AWARE = "mask-aware"
 
 
-def _stage_fuse(stage: Transformer):
+def stage_fuse(stage: Transformer):
     """Decompose a stage into (static_key, params_pytree, pure_fn) where
     ``pure_fn(params, xb, mask_b) -> yb`` (``mask_b`` is the chunk's
     valid-row mask).
@@ -294,7 +104,7 @@ def _stage_fuse(stage: Transformer):
     f = getattr(stage, "fuse", None)
     if f is not None:
         res = f()
-        if len(res) == 4 and res[3] == _MASK_AWARE:
+        if len(res) == 4 and res[3] == MASK_AWARE:
             key, params, fn = res[:3]
         else:
             key, params, fn2 = res
@@ -398,16 +208,16 @@ class _AotProgram:
         return self._jitted(flat, xs, ms)
 
 
-def _contains_opaque(key) -> bool:
+def contains_opaque(key) -> bool:
     """True when a (possibly nested — composed FusedChain keys) static
     key contains an id-keyed "opaque" entry, which must never enter the
     global program cache (see the opaque comment in `apply_batch`)."""
     if isinstance(key, tuple):
-        return any(_contains_opaque(k) for k in key)
+        return any(contains_opaque(k) for k in key)
     return key == "opaque"
 
 
-class _GatherConcatStage(Transformer):
+class GatherConcatStage(Transformer):
     """N fusable branches over ONE input, concatenated along the last
     axis — a ``Pipeline.gather`` fan-out plus its `VectorCombiner`
     collapsed into a single traceable stage, so the whole
@@ -456,7 +266,7 @@ class _GatherConcatStage(Transformer):
             [jnp.asarray(b.apply(x)) for b in self.branches], axis=-1)
 
     def fuse(self):
-        fused = [_stage_fuse(b) for b in self.branches]
+        fused = [stage_fuse(b) for b in self.branches]
         statics = tuple(f[0] for f in fused)
         params = tuple(f[1] for f in fused)
         fns = tuple(f[2] for f in fused)
@@ -465,7 +275,7 @@ class _GatherConcatStage(Transformer):
             return jnp.concatenate(
                 [f(p, xb, mb) for f, p in zip(fns, ps)], axis=-1)
 
-        return (("GatherConcat",) + statics, params, fn, _MASK_AWARE)
+        return (("GatherConcat",) + statics, params, fn, MASK_AWARE)
 
 
 #: the most rows a microbatch takes however small a row is: what every
@@ -526,7 +336,7 @@ class FusedBatchTransformer(Transformer):
     (exposed via ``batch_fn()`` or vmap of ``apply``).
     microbatch: rows processed per step per shard. None (the default)
     derives it from the bytes a row makes in this chain under the
-    planner's HBM budget (`analysis.plan_ir.microbatch_rows`), at most
+    planner's HBM budget (`workflow.budget.microbatch_rows`), at most
     `MAX_MICROBATCH`; a number is taken as given.
     """
 
@@ -555,15 +365,13 @@ class FusedBatchTransformer(Transformer):
 
     #: the precision planner's chosen per-stage storage dtypes (set by
     #: `PrecisionPlannerRule` on a tagged copy): a tuple of dtype names
-    #: or None, one per PEEPHOLED stage output. `_build_program` bakes
+    #: or None, one per `fused_stages` output. `_build_program` bakes
     #: each entry into the traced chunk body as a
     #: ``convert_element_type`` cast after that stage — jaxpr-visible,
     #: AOT-warmable, and part of the program cache key, so a planned
     #: program never collides with the unplanned form's entry. The LAST
-    #: entry RESTORES the unplanned trail's output dtype (the program's
-    #: visible output dtype never changes — downstream consumers see
-    #: exactly the PR-9 dtypes). None (the default) compiles exactly
-    #: the PR-9 program.
+    #: entry RESTORES the unplanned trail's output dtype, which the
+    #: consumers see. None (the default): no casts.
     planned_precision = None
 
     #: the precision planner's matmul-precision scope (e.g.
@@ -575,7 +383,7 @@ class FusedBatchTransformer(Transformer):
 
     #: the unified planner's chain-megakernel tag (set by
     #: `UnifiedPlannerRule` on a tagged copy): ``(start, stop, family)``
-    #: over the PEEPHOLED stage list. `_build_program` swaps that stage
+    #: over the `fused_stages` list. `_build_program` swaps that stage
     #: sub-trail for ONE `pl.pallas_call` (ops/chain_kernels.py) that
     #: streams batch blocks HBM→VMEM double-buffered and applies every
     #: stage body in VMEM — the chain boundaries inside the slice never
@@ -626,23 +434,21 @@ class FusedBatchTransformer(Transformer):
         chains) keeps structural program caching instead of degrading to
         an id-keyed opaque closure. Mask-aware: inner masking stages
         keep re-zeroing padded rows at their original chain position."""
-        fused = [_stage_fuse(s) for s in _peephole(self.stages)]
-        statics = tuple(f[0] for f in fused)
-        params = tuple(f[1] for f in fused)
-        fns = tuple(f[2] for f in fused)
+        statics, flat, treedef, fns = self._decompose()
+        params = jax.tree_util.tree_unflatten(treedef, flat)
 
         def fn(ps, xb, mb):
             for f, p in zip(fns, ps):
                 xb = f(p, xb, mb)
             return xb
 
-        return (("FusedChain",) + statics, params, fn, _MASK_AWARE)
+        return (("FusedChain",) + statics, params, fn, MASK_AWARE)
 
     def _decompose(self):
         """The chain's fused decomposition plus the flattened params:
         (statics, flat_params, treedef, fns). Shared by `apply_batch`
         and `warmup` so both derive the SAME program cache key."""
-        fused = [_stage_fuse(s) for s in _peephole(self.stages)]
+        fused = [stage_fuse(s) for s in fused_stages(self.stages)]
         statics = tuple(f[0] for f in fused)
         params = tuple(f[1] for f in fused)
         fns = tuple(f[2] for f in fused)
@@ -690,7 +496,7 @@ class FusedBatchTransformer(Transformer):
         (`chain_row_bytes`) and remembered."""
         if self.microbatch is not None:
             return max(1, min(self.microbatch, local_n))
-        from ...analysis.plan_ir import hbm_budget_bytes, microbatch_rows
+        from ...workflow.budget import hbm_budget_bytes, microbatch_rows
 
         statics, flat, treedef, fns = decomposition
         budget = hbm_budget_bytes()
@@ -698,7 +504,7 @@ class FusedBatchTransformer(Transformer):
                tuple((tuple(p.shape), _leaf_dtype_name(p)) for p in flat),
                tuple(array_shape[1:]), dtype_name, budget)
         cache = (self.__dict__.setdefault("_instance_microbatch", {})
-                 if _contains_opaque(statics) else _MICROBATCH_CACHE)
+                 if contains_opaque(statics) else _MICROBATCH_CACHE)
         rows = cache.get(key)
         if rows is None:
             params = jax.tree_util.tree_unflatten(
@@ -736,7 +542,7 @@ class FusedBatchTransformer(Transformer):
         globally would pin the stage (and its captured arrays) forever
         and make the id-keyed entry unsafe after GC reuses the id. Keep
         such programs on THIS instance instead."""
-        if _contains_opaque(statics):
+        if contains_opaque(statics):
             return self.__dict__.setdefault("_instance_programs", {})
         return _PROGRAM_CACHE
 
@@ -812,7 +618,7 @@ class FusedBatchTransformer(Transformer):
         section 6, PR 28). Counted from shapes, per call."""
         stages = list(self._flat_stages())
         last = max((i for i, s in enumerate(stages)
-                    if isinstance(s, _GatherConcatStage)), default=-1)
+                    if isinstance(s, GatherConcatStage)), default=-1)
         if last < 0:
             return
         from ...telemetry import counter
@@ -822,7 +628,7 @@ class FusedBatchTransformer(Transformer):
         per_row = 0
         for s in stages[:last + 1]:  # what follows the last gather is not priced
             elem = fitted_elem_fn(s)(elem)
-            if isinstance(s, _GatherConcatStage):
+            if isinstance(s, GatherConcatStage):
                 per_row += math.prod(elem.shape) * elem.dtype.itemsize
         counter("gather.concat_bytes").inc(data.padded_count * per_row)
 
@@ -834,7 +640,7 @@ class FusedBatchTransformer(Transformer):
         stages = list(self._flat_stages())
         if not any(hasattr(s, "count_rows") for s in stages):
             return
-        stages = _peephole(stages)  # as the program runs them
+        stages = fused_stages(stages)  # as the program runs them
         last = max(i for i, s in enumerate(stages)
                    if hasattr(s, "count_rows"))
         from ...workflow.operators import fitted_elem_fn
@@ -933,9 +739,9 @@ class FusedBatchTransformer(Transformer):
         padded_local = n_chunks * chunk
 
         # the precision planner's chosen per-stage storage dtypes: one
-        # entry per fused stage (aligned with `fns` — both derive from
-        # the same `_peephole` pass); a stale/misaligned tag is ignored
-        # rather than mis-cast
+        # entry per fused stage (aligned with `fns`: both index
+        # `fused_stages`); a stale/misaligned tag is ignored rather than
+        # mis-cast
         planned_prec = self.planned_precision
         if planned_prec is not None and len(planned_prec) != len(fns):
             planned_prec = None

@@ -11,10 +11,10 @@ blocked operands), applies every stage body in VMEM, and writes only
 the chain's final output — one HBM pass of in+out bytes instead of a
 round-trip per boundary.
 
-Two candidate families, matched on the same `_stage_fuse` static keys
+Two candidate families, matched on the same `stage_fuse` static keys
 the fusion builder and the KP501 auditor use:
 
-- ``rectify_pool_vectorize``: the post-peephole ``RectifyPool >>
+- ``rectify_pool_vectorize``: the merged ``RectifyPool >>
   ImageVectorizer`` trail of the conv pipelines. Reuses the proven
   rectify+pool kernel body (ops/pallas_kernels.py, 1.1-1.54x live) and
   appends the vectorize as a free contiguous reshape of the pooled
@@ -141,13 +141,13 @@ SUPPRESSED_STAGES = {
     "ConvRectifyPool": "already ONE fused Pallas kernel "
                        "(ops.conv_rectify_pool, PR 11)",
     "PaddedFFT": "rfft has no Mosaic lowering; stays on the XLA path",
-    "Pooler": "non-sum/pixel_fn pooling (the sum form peepholes into "
+    "Pooler": "non-sum/pixel_fn pooling (the sum form merges into "
               "RectifyPool) stays on lax.reduce_window",
     "opaque": "id-keyed opaque stage: no static body to lower",
 }
 
 #: per-stage VMEM body builders for the elementwise family, keyed on
-#: the `_stage_fuse` static-key head. Each entry:
+#: the `stage_fuse` static-key head. Each entry:
 #: ``prep(params) -> tuple of >=2-D operand arrays`` and
 #: ``body(x, ops) -> y`` — pure jnp, used verbatim inside the kernel
 #: and by the reference oracle (bit-identical bodies by construction).
@@ -251,7 +251,7 @@ def _std(key, params):
 
 
 def _unwrap(key):
-    """Strip `_stage_fuse`'s ``(key, "masked")`` wrapping; returns
+    """Strip `stage_fuse`'s ``(key, "masked")`` wrapping; returns
     (inner_key, masked)."""
     masked = False
     while (isinstance(key, tuple) and len(key) == 2 and key[1] == "masked"):
@@ -267,12 +267,12 @@ def _head(key):
 
 
 def stage_statics(stages):
-    """The peepholed chain's fuse static keys — the matcher's input.
-    Same decomposition the fusion builder derives its program key from;
-    never builds or compiles a program."""
-    from ..nodes.util.fusion import _peephole, _stage_fuse
+    """The fuse static keys of the chain's `fused_stages` — the
+    matcher's input. Same decomposition a fused program derives its
+    key from; never builds or compiles a program."""
+    from ..nodes.util.fusion import fused_stages, stage_fuse
 
-    return tuple(_stage_fuse(s)[0] for s in _peephole(list(stages)))
+    return tuple(stage_fuse(s)[0] for s in fused_stages(stages))
 
 
 def lowerability(statics) -> dict:
@@ -446,7 +446,7 @@ def _run_bodies(bodies, ops, x, mask):
 def elementwise_chain_reference(statics, params, x, mask=None):
     """Pure-jnp oracle: the SAME stage bodies the kernel traces,
     applied outside Pallas. ``params``: one pytree per stage (the
-    `_stage_fuse` params slice); ``mask``: bool (n,) or None."""
+    `stage_fuse` params slice); ``mask``: bool (n,) or None."""
     bodies = _compile_bodies(statics)
     if bodies is None:
         raise ChainKernelIneligibleError(
@@ -690,12 +690,12 @@ def chain_feasible(stages, item_shape, dtype=jnp.float32):
     """(ok, reason): probe the chain kernel's VMEM geometry at the
     per-item input shape without compiling anything. Used by the
     planner to price VMEM-infeasible tile geometries INF (clean
-    demotion, never a crash). ``stages``: the raw (pre-peephole) stage
+    demotion, never a crash). ``stages``: the raw (unmerged) stage
     objects of the candidate chain."""
-    from ..nodes.util.fusion import _peephole, _stage_fuse
+    from ..nodes.util.fusion import fused_stages, stage_fuse
 
     try:
-        fused = [_stage_fuse(s) for s in _peephole(list(stages))]
+        fused = [stage_fuse(s) for s in fused_stages(stages)]
     except _STATIC_PROBE_ERRORS as e:
         return False, f"stage decomposition failed: {type(e).__name__}"
     statics = tuple(f[0] for f in fused)

@@ -347,7 +347,7 @@ def folded_conv_reference(images, kernel_hwio, colsum, bias, normalize: bool):
     """The folded conv: filter bank with ZCA pre-applied, patch-mean
     subtraction as a rank-1 correction via a uniform conv, plus bias.
     Single source of truth — nodes/images/core.py's Convolver and the
-    fused peephole's fallback both call this.
+    fused stage's fallback both call this.
 
     Mixed-precision contract: `lax.conv_general_dilated` requires both
     operands to share a dtype, so when the precision planner stores the
@@ -482,7 +482,7 @@ def conv_rectify_pool(
     """Dispatcher: fused Pallas kernel on TPU (default on), XLA
     elsewhere or when the block geometry cannot fit VMEM (the canary's
     one designed demotion). The single entry point for
-    Convolver>>Rectifier>>Pooler semantics — the fusion peephole and
+    Convolver>>Rectifier>>Pooler semantics — the fused stage and
     the driver graft entry both route through it."""
     # precision-planner boundaries may hand bf16 activations to an f32
     # filter bank: the kernel follows the activation dtype here so BOTH

@@ -317,6 +317,10 @@ def _sync_compile_cache(cfg: ExecutionConfig) -> None:
     from jax.experimental.compilation_cache import compilation_cache
 
     jax.config.update("jax_enable_compilation_cache", path is not None)
+    # a Mosaic call's key holds its kernel's body, each op located by the
+    # stack that traced it: past the kernel's frame, a warm-up thread's or
+    # the dispatch's. One frame keeps the key whoever traces the kernel
+    jax.config.update("jax_traceback_in_locations_limit", 1)
     if path is not None:
         os.makedirs(path, exist_ok=True)
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)

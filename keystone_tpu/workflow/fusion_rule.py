@@ -20,7 +20,7 @@ operator:
     fused closure *params* and the whole chain runs as one program;
   - also with ``fuse_apply``, fusable ``Pipeline.gather`` diamonds
     (N traceable branches over one source + VectorCombiner) collapse
-    into one `_GatherConcatStage` program (`_fuse_gathers`).
+    into one `GatherConcatStage` program (`_fuse_gathers`).
 
 A node with two children terminates the chain (fusing across fan-out
 would duplicate work for one consumer and starve the other's memo), and
@@ -161,7 +161,7 @@ class FusedChainOperator(Operator):
 
     #: the precision planner's chosen per-stage storage dtypes (set by
     #: `PrecisionPlannerRule` on a tagged copy: one dtype name or None
-    #: per PEEPHOLED stage output) and its matmul-precision scope;
+    #: per `fused_stages` output) and its matmul-precision scope;
     #: `materialize` hands both to the built fused transformer, whose
     #: program builder bakes the casts (and the
     #: jax.default_matmul_precision scope) into the traced program
@@ -169,7 +169,7 @@ class FusedChainOperator(Operator):
     planned_matmul_precision = None
 
     #: the unified planner's chain-megakernel tag ``(start, stop,
-    #: family)`` over the peepholed stage list (set by
+    #: family)`` over the `fused_stages` list (set by
     #: `UnifiedPlannerRule` on a tagged copy) plus its predicted
     #: seconds; `materialize` hands both to the built fused transformer,
     #: whose program builder swaps the tagged sub-trail for ONE
@@ -647,7 +647,7 @@ def _refuse_oversized(rule: "NodeFusionRule", plan: Plan) -> Plan:
     """Keep off the device what cannot lie on it. A dataset a plan would
     hold whole is priced from the shapes its stages declare
     (`_declared_output`) against the planner's HBM budget
-    (`analysis.plan_ir.resident_fits`):
+    (`workflow.budget.resident_fits`):
 
       - a `Cacher` whose output does not fit is refused: it is taken out
         of the plan and its consumers read what fed it
@@ -661,7 +661,7 @@ def _refuse_oversized(rule: "NodeFusionRule", plan: Plan) -> Plan:
 
     From the sinks up, so a stage that feeds an oversized stage sees its
     new consumers."""
-    from ..analysis.plan_ir import resident_fits
+    from .budget import resident_fits
     from ..analysis.propagate import toposort
     from ..nodes.util.basic import Cacher
     from ..telemetry import counter
@@ -768,12 +768,12 @@ class NodeFusionRule(Rule):
         fusable branches over ONE source, zipped by a
         GatherTransformerOperator whose sole consumer is a
         VectorCombiner — into one `FusedBatchTransformer` wrapping a
-        `_GatherConcatStage`. The branch fan-out, the zip, and the
+        `GatherConcatStage`. The branch fan-out, the zip, and the
         concat all become one XLA program; the linear pass below can
         then chain it with whatever follows (MnistRandomFFT's whole
         apply path collapses to a single program)."""
         from ..nodes.util.basic import VectorCombiner
-        from ..nodes.util.fusion import FusedBatchTransformer, _GatherConcatStage
+        from ..nodes.util.fusion import FusedBatchTransformer, GatherConcatStage
         from .operators import GatherTransformerOperator
 
         graph, prefixes = plan
@@ -814,7 +814,7 @@ class NodeFusionRule(Rule):
                 + [graph.get_operator(g).label,
                    graph.get_operator(kid).label],
                 "gather_concat_program", len(deps) + 1, graph=graph)
-            stage = _GatherConcatStage([graph.get_operator(b) for b in deps])
+            stage = GatherConcatStage([graph.get_operator(b) for b in deps])
             graph = graph.set_operator(
                 kid, FusedBatchTransformer([stage], microbatch=self.microbatch))
             graph = graph.set_dependencies(kid, (src,))

@@ -880,7 +880,7 @@ class PrecisionPlannerRule(Rule):
     Enforcement of a winning policy: each fused/megafused program
     operator whose internal stage trail admits a priced bf16 win is
     replaced with a tagged copy carrying ``planned_precision`` (one
-    storage dtype per peepholed stage output); the program builder
+    storage dtype per `fused_stages` output); `_build_program`
     lowers that into ``convert_element_type`` casts between stages —
     cache-keyed like ``planned_out_spec``, AOT-warmable, and visible in
     the compiled jaxpr. When every stage of the program tolerates
@@ -1044,7 +1044,7 @@ class DefaultOptimizer(Optimizer):
     once) plus the TPU-native stage-fusion pass (see fusion_rule.py).
     ``fusion_microbatch`` None (the default) leaves every fused program's
     microbatch to the bytes a row makes in it under the HBM budget
-    (`analysis.plan_ir.microbatch_rows`); a number overrides that."""
+    (`workflow.budget.microbatch_rows`); a number overrides that."""
 
     def __init__(self, samples_per_shard: int = 3, fuse: bool = True,
                  fusion_microbatch: Optional[int] = None,

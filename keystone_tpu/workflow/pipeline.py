@@ -456,6 +456,17 @@ class Transformer(TransformerOperator, Chainable):
     def apply(self, x: Any) -> Any:
         raise NotImplementedError
 
+    def absorb(self, followers: Sequence[Any]) -> Optional[Tuple]:
+        """Fusion hook (`nodes.util.fusion.fused_stages`): the one stage
+        this stage and the first of ``followers`` make in a fused program
+        and how many of them it takes in; None (the default) for none."""
+        return None
+
+    def moves_ahead_of(self, stage: Any) -> bool:
+        """Fusion hook: whether this stage, a choice of rows, gives the
+        same rows run ahead of ``stage``, the one before it."""
+        return False
+
     def apply_batch_stream(self, data: Any):
         """Optional streaming batch path over a HostDataset; None means
         'no streaming implementation' (the operator layer falls back to

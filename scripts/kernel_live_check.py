@@ -107,7 +107,7 @@ def check_chain_elementwise(interpret=False, timing=True):
         ImageVectorizer,
         PixelScaler,
     )
-    from keystone_tpu.nodes.util.fusion import _peephole, _stage_fuse
+    from keystone_tpu.nodes.util.fusion import fused_stages, stage_fuse
     from keystone_tpu.ops.chain_kernels import (
         _compile_bodies,
         _elementwise_geometry,
@@ -123,7 +123,7 @@ def check_chain_elementwise(interpret=False, timing=True):
         print(f"elementwise_chain SKIPPED (statically refuted {code}): "
               f"{msg}", flush=True)
         return
-    fused = [_stage_fuse(s) for s in _peephole(stages)]
+    fused = [stage_fuse(s) for s in fused_stages(stages)]
     statics = tuple(f[0] for f in fused)
     params = [f[1] for f in fused]
 
@@ -177,12 +177,16 @@ def check_chain_rectify_pool(interpret=False, timing=True):
 
     h = w = 27
     k, pool, stride, alpha = 256, 14, 13, 0.25
-    from keystone_tpu.nodes.images import ImageVectorizer
-    from keystone_tpu.nodes.util.fusion import _RectifyPoolStage
+    from keystone_tpu.nodes.images import (
+        ImageVectorizer,
+        Pooler,
+        SymmetricRectifier,
+    )
 
+    # the verifier's stage walk merges the rectifier and the pooler
     refuted = _static_refutation(
-        [_RectifyPoolStage(alpha, 0.0, pool, stride), ImageVectorizer()],
-        (h, w, k))
+        [SymmetricRectifier(alpha=alpha), Pooler(stride, pool),
+         ImageVectorizer()], (h, w, k))
     if refuted:
         code, msg = refuted
         print(f"rectify_pool_vectorize SKIPPED (statically refuted "

@@ -40,8 +40,8 @@ from keystone_tpu.nodes.images.core import (
 from keystone_tpu.nodes.stats.scalers import StandardScalerModel
 from keystone_tpu.nodes.util.fusion import (
     FusedBatchTransformer,
-    _peephole,
-    _stage_fuse,
+    fused_stages,
+    stage_fuse,
 )
 from keystone_tpu.ops import chain_kernels as ck
 from keystone_tpu.telemetry import ledger
@@ -54,7 +54,7 @@ def _elementwise_trail():
     """The LinearPixels featurizer trail — the planner's flagship
     elementwise-chain candidate."""
     stages = [PixelScaler(), GrayScaler(), ImageVectorizer()]
-    fused = [_stage_fuse(s) for s in _peephole(stages)]
+    fused = [stage_fuse(s) for s in fused_stages(stages)]
     return tuple(f[0] for f in fused), [f[1] for f in fused]
 
 
@@ -147,7 +147,7 @@ def test_masked_stage_padded_rows_exact():
     stages = [PixelScaler(), ImageVectorizer(),
               StandardScalerModel(np.full((192,), 0.5, np.float32),
                                   np.full((192,), 2.0, np.float32))]
-    fused = [_stage_fuse(s) for s in _peephole(stages)]
+    fused = [stage_fuse(s) for s in fused_stages(stages)]
     statics = tuple(f[0] for f in fused)
     params = [f[1] for f in fused]
     rng = np.random.RandomState(2)

@@ -52,9 +52,9 @@ from keystone_tpu.nodes.images.core import (
     GrayScaler,
     ImageVectorizer,
     PixelScaler,
+    _RectifyPoolStage,
 )
 from keystone_tpu.nodes.stats.scalers import StandardScalerModel
-from keystone_tpu.nodes.util.fusion import _RectifyPoolStage
 from keystone_tpu.ops import chain_kernels as ck
 
 pytestmark = pytest.mark.lint

@@ -79,17 +79,20 @@ def test_dispatchers_fall_back_off_tpu():
 def test_fusion_peephole_matches_stagewise():
     from keystone_tpu.data.dataset import Dataset
     from keystone_tpu.nodes.images.core import Pooler, SymmetricRectifier
-    from keystone_tpu.nodes.util.fusion import FusedBatchTransformer, _peephole
+    from keystone_tpu.nodes.util.fusion import (
+        FusedBatchTransformer,
+        fused_stages,
+    )
 
     rng = np.random.default_rng(3)
     imgs = rng.normal(size=(16, 27, 27, 8)).astype(np.float32)
     rect = SymmetricRectifier(alpha=0.25)
     pool = Pooler(13, 14, pool_fn="sum")
 
-    stages = _peephole([rect, pool])
+    stages = fused_stages([rect, pool])
     assert len(stages) == 1 and type(stages[0]).__name__ == "_RectifyPoolStage"
     # max-pool / pixel_fn poolers must NOT be fused
-    assert len(_peephole([rect, Pooler(13, 14, pool_fn="max")])) == 2
+    assert len(fused_stages([rect, Pooler(13, 14, pool_fn="max")])) == 2
 
     data = Dataset(imgs)
     fused_out = FusedBatchTransformer([rect, pool], microbatch=8).apply_batch(data)
@@ -420,7 +423,10 @@ def test_conv_fusion_peephole_matches_stagewise():
         Pooler,
         SymmetricRectifier,
     )
-    from keystone_tpu.nodes.util.fusion import FusedBatchTransformer, _peephole
+    from keystone_tpu.nodes.util.fusion import (
+        FusedBatchTransformer,
+        fused_stages,
+    )
 
     rng = np.random.default_rng(2)
     imgs = rng.random(size=(6, 16, 16, 3)).astype(np.float32)
@@ -430,7 +436,7 @@ def test_conv_fusion_peephole_matches_stagewise():
     pool = Pooler(4, 5, pool_fn="sum")  # distinct stride/size: catches transposition
 
     stages = [conv, rect, pool]
-    merged = _peephole(stages)
+    merged = fused_stages(stages)
     assert len(merged) == 1, [type(s).__name__ for s in merged]
 
     fused = FusedBatchTransformer(stages, microbatch=4)
@@ -448,7 +454,7 @@ def test_conv_fused_stage_ineligible_fallback_reconstructs_hwio(monkeypatch):
     fall back to the reference conv with a correctly reconstructed HWIO
     kernel (inverse of the channel-major packing)."""
     from keystone_tpu.nodes.images.core import Convolver, Pooler, SymmetricRectifier
-    from keystone_tpu.nodes.util.fusion import _ConvRectifyPoolStage
+    from keystone_tpu.nodes.images.core import _ConvRectifyPoolStage
 
     rng = np.random.default_rng(3)
     imgs = jnp.asarray(rng.random(size=(4, 16, 16, 3)).astype(np.float32))

@@ -598,12 +598,12 @@ def test_intolerant_solver_boundary_stays_f32():
     tagged = _tagged_ops(g_on)
     assert tagged
     from keystone_tpu.analysis.precision import stage_tolerance
-    from keystone_tpu.nodes.util.fusion import _peephole
+    from keystone_tpu.nodes.util.fusion import fused_stages
 
     for op in tagged:
         stage_specs = getattr(op, "stage_specs", None)
-        stages = _peephole(stage_specs if stage_specs is not None
-                           else list(op.stages))
+        stages = fused_stages(stage_specs if stage_specs is not None
+                              else list(op.stages))
         storage = op.planned_precision
         assert len(storage) == len(stages)
         vid = next(v for v in g_on.operators if g_on.get_operator(v) is op)

@@ -162,13 +162,13 @@ def test_the_reference_rounds_the_operands_the_configuration_states():
 
 def test_the_gather_s_label_counts_equal_branches():
     from keystone_tpu.nodes.stats import CosineRandomFeatures, RandomSignNode
-    from keystone_tpu.nodes.util.fusion import _GatherConcatStage
+    from keystone_tpu.nodes.util.fusion import GatherConcatStage
 
     cos = [CosineRandomFeatures(8, 4, seed=i) for i in range(3)]
-    assert _GatherConcatStage(cos).label == "Gather[3 x CosineRandomFeatures]"
-    mixed = _GatherConcatStage([RandomSignNode(8), cos[0], cos[1]])
+    assert GatherConcatStage(cos).label == "Gather[3 x CosineRandomFeatures]"
+    mixed = GatherConcatStage([RandomSignNode(8), cos[0], cos[1]])
     assert mixed.label == "Gather[RandomSignNode | 2 x CosineRandomFeatures]"
-    out = _GatherConcatStage(cos).abstract_apply(
+    out = GatherConcatStage(cos).abstract_apply(
         jax.ShapeDtypeStruct((8,), jnp.float32))
     assert out.shape == (12,) and out.dtype == jnp.float32
 
@@ -185,13 +185,13 @@ def test_the_scopes_reach_the_programs_of_both_paths(split):
     from keystone_tpu.nodes.util.basic import _concat_last
     from keystone_tpu.nodes.util.fusion import (
         FusedBatchTransformer,
-        _GatherConcatStage,
+        GatherConcatStage,
     )
 
     _, mesh, train, _ = split
     branches = cosine_branches(TimitConfig(
         num_cosines=3, num_cosine_features=64, gamma=0.2), 32)
-    fused = FusedBatchTransformer([_GatherConcatStage(branches)])
+    fused = FusedBatchTransformer([GatherConcatStage(branches)])
     statics, flat, treedef, fns = fused._decompose()
     program = fused._build_program(
         mesh, train.data.n_shards, 2048, treedef, fns, statics=statics)

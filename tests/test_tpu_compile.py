@@ -192,10 +192,10 @@ def test_elementwise_chain_compiles(one_chip):
         ImageVectorizer,
         PixelScaler,
     )
-    from keystone_tpu.nodes.util.fusion import _peephole, _stage_fuse
+    from keystone_tpu.nodes.util.fusion import fused_stages, stage_fuse
     from keystone_tpu.ops.chain_kernels import elementwise_chain_pallas
 
-    fused = [_stage_fuse(s) for s in _peephole(
+    fused = [stage_fuse(s) for s in fused_stages(
         [PixelScaler(), GrayScaler(), ImageVectorizer()])]
     statics = tuple(f[0] for f in fused)
     params = [f[1] for f in fused]
@@ -264,7 +264,7 @@ def _compiled_gather(n, rows, whole, mesh=None, shards=1):
     from keystone_tpu.nodes.stats import CosineRandomFeatures
     from keystone_tpu.nodes.util.fusion import (
         FusedBatchTransformer,
-        _GatherConcatStage,
+        GatherConcatStage,
     )
 
     dim, branches, width = 440, 4, 4096
@@ -273,7 +273,7 @@ def _compiled_gather(n, rows, whole, mesh=None, shards=1):
     for node in nodes:  # shapes stand in for the random parameters
         node.W = _aval((dim, width), jnp.float32, whole)
         node.b = _aval((width,), jnp.float32, whole)
-    op = FusedBatchTransformer([_GatherConcatStage(nodes)])
+    op = FusedBatchTransformer([GatherConcatStage(nodes)])
     statics, flat, treedef, fns = op._decompose()
     program = op._build_program(mesh, shards, n, treedef, fns, statics=statics)
     compiled = program.lower(
@@ -603,3 +603,52 @@ def test_sift_s_full_pass_normalizes_its_descriptors_in_one_kernel(
     precisions = re.findall(r"operand_precision=\{(\w+),(\w+)\}", hlo)
     assert sorted(precisions) == \
         8 * [("highest", "default")] + 8 * [("highest", "highest")]
+
+
+def test_a_kernel_s_body_is_the_same_whoever_traces_it(
+        one_chip, topo, monkeypatch):
+    """The persistent compile cache keys a program by its text, and a
+    Mosaic call carries its kernel's serialized body, with a source
+    location on every op. A process traces a kernel once and keeps that
+    trace, so which stack traced it first decides the key: the executor's
+    AOT warm-up thread in one process, the dispatch in the next. The
+    fused SIFT program is lowered here through the plain chunk loop,
+    then, its traces dropped as a new process would not have them, on a
+    worker thread through the megafused scan. The `ks_sift_normalize`
+    call's body is the same text both times. Lowered only: nothing is
+    compiled."""
+    import re
+    import threading
+
+    from keystone_tpu.nodes.images import sift
+    from keystone_tpu.nodes.util.fusion import (
+        FusedBatchTransformer,
+        MegafusedBatchTransformer,
+    )
+    from keystone_tpu.ops import pallas_kernels as pk
+
+    monkeypatch.setattr(
+        sift, "use_sift_normalize",
+        lambda rows: rows >= pk.SIFT_NORMALIZE_TILE)
+    n, h, w = 2, 96, 128  # 3,248 descriptors an image: one tile and more
+    mesh = Mesh(np.asarray(topo.devices[:1]), ("data",))
+
+    def body(chain_type):
+        op = chain_type([sift.SIFTExtractor(3, 4, 4, 0)], microbatch=n)
+        statics, flat, treedef, fns = op._decompose()
+        program = op._build_program(mesh, 1, n, treedef, fns, statics=statics)
+        text = program.lower(flat, _aval((n, h, w), jnp.float32, one_chip),
+                             _aval((n,), jnp.bool_, one_chip)).as_text()
+        (call,) = [line for line in text.splitlines()
+                   if "tpu_custom_call" in line]
+        assert "ks_sift_normalize" in call
+        return re.search(r'backend_config = "([^"]*)"', call).group(1)
+
+    mine = body(FusedBatchTransformer)
+    jax.clear_caches()
+    theirs = []
+    worker = threading.Thread(
+        target=lambda: theirs.append(body(MegafusedBatchTransformer)))
+    worker.start()
+    worker.join()
+    assert theirs == [mine]

@@ -249,7 +249,7 @@ def test_the_mixture_is_textbook_em_on_every_row_from_a_device_start():
     (1 << 40, 16 << 30, 1)])          # never under one
 def test_microbatch_rows_from_bytes_a_row_under_a_budget(
         row_bytes, budget, want):
-    from keystone_tpu.analysis.plan_ir import microbatch_rows
+    from keystone_tpu.workflow.budget import microbatch_rows
 
     assert microbatch_rows(row_bytes, budget) == want
 
@@ -267,7 +267,7 @@ def test_the_rule_gives_timit_2048_and_the_descriptor_chain_8():
     from keystone_tpu.nodes.stats import CosineRandomFeatures
     from keystone_tpu.nodes.util.fusion import (
         FusedBatchTransformer,
-        _GatherConcatStage,
+        GatherConcatStage,
         chain_row_bytes,
     )
 
@@ -275,7 +275,7 @@ def test_the_rule_gives_timit_2048_and_the_descriptor_chain_8():
     try:
         branches = [CosineRandomFeatures(440, 4096, 0.05555, seed=i)
                     for i in range(4)]
-        gather = FusedBatchTransformer([_GatherConcatStage(branches)])
+        gather = FusedBatchTransformer([GatherConcatStage(branches)])
         assert gather._chunk_rows(
             gather._decompose(), (65536, 440), "float32", 65536) == 2048
 
